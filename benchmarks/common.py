@@ -60,7 +60,10 @@ def run_dist_script(script: str, smoke: bool = False, n_devices: int = 8,
 def run_devices_subprocess(script: str, n_devices: int = 8,
                            timeout: int = 1800) -> str:
     """Run a python snippet under a forced host-device count; returns
-    stdout.  Keeps the parent process single-device."""
+    stdout.  Keeps the parent process single-device.  Refuses where JAX
+    found a chip: the forced devices would run the mesh on the CPU."""
+    from repro.runtime import require_host_platform
+    require_host_platform("benchmarks.common.run_devices_subprocess")
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = str(ROOT / "src")

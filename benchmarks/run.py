@@ -36,6 +36,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 from benchmarks import common  # noqa: E402
 
 from repro import obs  # noqa: E402
+from repro.runtime import enable_compile_cache  # noqa: E402
 
 MODULES = {
     "fig14": "benchmarks.bench_e2e",
@@ -95,6 +96,7 @@ def main() -> None:
                     help="tiny shapes, all benches, no bench.csv write "
                          "(CI liveness check)")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = None
     if args.config:
         from repro.api import DealConfig

@@ -33,6 +33,8 @@ def main():
         run(args.dataset, args.model, p=1, m=1, distributed=False)
         return
     # the mesh needs P*M host devices — respawn with the forced count
+    from repro.runtime import require_host_platform
+    require_host_platform("examples/allnode_inference.py")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{args.p * args.m}")

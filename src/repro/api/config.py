@@ -124,7 +124,9 @@ class ExecutorSpec:
         if len(jax.devices()) < p * m:
             raise ConfigError(
                 f"executor.name: \"dist\" needs p*m = {p * m} devices "
-                f"(found {len(jax.devices())}); run under XLA_FLAGS="
+                f"(found {len(jax.devices())} {jax.default_backend()}); "
+                f"on a chip host, run on one with {p * m} chips; on the "
+                f"CPU, run under JAX_PLATFORMS=cpu XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={p * m}")
         if n_nodes is not None and n_nodes % p != 0:
             raise ConfigError(

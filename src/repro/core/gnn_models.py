@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api.registry import MODELS, register_model
+from repro.kernels.ref import F32_MATMUL
 
 
 def init_gcn(rng, dims: List[int]) -> Dict[str, Any]:
@@ -84,7 +85,8 @@ def gat_head_scores(q, kf, nbr, mask, heads: int):
     kh = kf.reshape(-1, heads, dh)
     kn = jnp.take(kh, nbr.reshape(-1), axis=0).reshape(
         nbr.shape + (heads, dh))
-    s = jnp.einsum("nhd,nfhd->nfh", qh, kn) / jnp.sqrt(jnp.float32(dh))
+    s = jnp.einsum("nhd,nfhd->nfh", qh, kn, precision=F32_MATMUL) / jnp.sqrt(
+        jnp.float32(dh))
     return s
 
 

@@ -46,7 +46,8 @@ from repro.core.gnn_models import (LayerSpec, ModelSpec, gat_head_scores,
 from repro.core.partition import build_plan, build_subset_plan_cached
 from repro.core.sampler import LayerGraph
 from repro.kernels import ops as kops
-from repro.kernels.spmm import auto_block_n
+from repro.kernels.ref import F32_MATMUL
+from repro.kernels.spmm import LANES, auto_block_n, lane_width, pad_lanes
 
 
 # ----------------------------------------------------------------------
@@ -238,13 +239,22 @@ class RefExecutor:
         vn = jnp.take(v.reshape(-1, heads, dh),
                       io.nbr_resolved.reshape(-1),
                       axis=0).reshape(io.nbr.shape + (heads, dh))
-        return jnp.einsum("nfh,nfhd->nhd", alpha, vn).reshape(
-            alpha.shape[0], D)
+        return jnp.einsum("nfh,nfhd->nhd", alpha, vn,
+                          precision=F32_MATMUL).reshape(alpha.shape[0], D)
 
 
 # ----------------------------------------------------------------------
 # PallasExecutor — the kernels in kernels/ (compiled on TPU)
 # ----------------------------------------------------------------------
+
+def lane_block(D: int, block_d: int) -> int:
+    """Column block of a kernel call over D feature columns, which run
+    padded to ``lane_width(D)``.  Blocks are whole 128-lane tiles, since
+    the TPU refuses a narrower block of a wider array, so an awkward
+    width (ogbn-products' D=100) pads up to the next tile multiple."""
+    bd = math.gcd(lane_width(D), block_d)
+    return bd if bd % LANES == 0 else LANES
+
 
 def pad_to_blocks(block_n: int, nbr, mask, *row_arrays):
     """Pad the leading (row) axis of graph-shaped arrays to the next
@@ -270,8 +280,8 @@ class PallasExecutor(RefExecutor):
     """Routes spmm/sddmm through the Pallas kernels (``kernels.ops``
     dispatch: compiled on TPU, interpret mode elsewhere).  GEMM stays on
     XLA's MXU path — a hand-written matmul kernel would only lose.
-    Rows are padded to block multiples and feature columns to a block
-    that divides them, then sliced back — non-aligned shapes just work.
+    Rows are padded to block multiples and feature columns to whole
+    128-lane tiles, then sliced back — non-aligned shapes just work.
 
     ``fused_gather``: consume ``DenseIO.table`` via the fused
     gather+spmm kernel instead of materializing ``nbr_resolved``
@@ -332,12 +342,8 @@ class PallasExecutor(RefExecutor):
         bn, bd0 = self._pick_blocks(kernel, R, D, H_src.dtype)
         pad_n, block_n = self._row_block(bn, R)
         _, nbr, mask, w_edge = pad_to_blocks(pad_n, nbr, mask, w_edge)
-        bd = math.gcd(D, bd0)
-        Dp = D
-        if bd < 8:                       # awkward width: pad columns
-            Dp = -(-D // 8) * 8
-            bd = math.gcd(Dp, bd0)
-            H_src = jnp.pad(H_src, ((0, 0), (0, Dp - D)))
+        bd = lane_block(D, bd0)
+        H_src = pad_lanes(H_src)
         if table is not None:
             out = kops.gather_spmm(H_src, table, w_edge, nbr, mask,
                                    use_kernel=self.use_kernel,

@@ -30,10 +30,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.partition import LayerPlan
+from repro.kernels.ref import F32_MATMUL
 from repro.kernels.ref import gemm_ref as ref_gemm
 from repro.kernels.ref import sddmm_ref as ref_sddmm
 from repro.kernels.ref import spmm_ref as ref_spmm
-from repro.sharding.compat import shard_map
 
 __all__ = [
     "make_gemm", "make_spmm", "make_spmm_p", "make_sddmm", "make_sddmm_p",
@@ -50,7 +50,8 @@ def _gemm_deal_local(H, W):
     all-to-all, multiply with the replicated W, reshard back."""
     full = jax.lax.all_to_all(H, "model", split_axis=0, concat_axis=1,
                               tiled=True)              # (n/M, D)
-    out = jnp.dot(full, W, preferred_element_type=jnp.float32)
+    out = jnp.dot(full, W, preferred_element_type=jnp.float32,
+                  precision=F32_MATMUL)
     out = out.astype(H.dtype)
     return jax.lax.all_to_all(out, "model", split_axis=1, concat_axis=0,
                               tiled=True)              # (n, D_out/M)
@@ -62,7 +63,8 @@ def _gemm_cagnet_local(H, W):
     m = jax.lax.axis_index("model")
     d_loc = H.shape[1]
     w_slice = jax.lax.dynamic_slice_in_dim(W, m * d_loc, d_loc, 0)
-    partial = jnp.dot(H, w_slice, preferred_element_type=jnp.float32)
+    partial = jnp.dot(H, w_slice, preferred_element_type=jnp.float32,
+                      precision=F32_MATMUL)
     out = jax.lax.psum_scatter(partial, "model", scatter_dimension=1,
                                tiled=True)
     return out.astype(H.dtype)
@@ -81,13 +83,14 @@ def _gemm_deal_ring_local(H, W, *, M: int):
         return jax.lax.dynamic_slice_in_dim(W, j * d_loc, d_loc, 0)
 
     acc = jnp.dot(jnp.take(blocks, m, axis=0), w_slice(m),
-                  preferred_element_type=jnp.float32)
+                  preferred_element_type=jnp.float32, precision=F32_MATMUL)
     for k in range(1, M):
         send = jnp.take(blocks, (m + k) % M, axis=0)
         perm = [(i, (i + k) % M) for i in range(M)]
         recv = jax.lax.ppermute(send, "model", perm)
         acc = acc + jnp.dot(recv, w_slice((m - k) % M),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=F32_MATMUL)
     out = acc.astype(H.dtype)                       # (n/M, D_out)
     return jax.lax.all_to_all(out, "model", split_axis=1, concat_axis=0,
                               tiled=True)           # (n, D_out/M)
@@ -100,7 +103,7 @@ def make_gemm(mesh, variant: str = "deal"):
     else:
         fn = (_gemm_deal_local if variant == "deal"
               else _gemm_cagnet_local)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(P("data", "model"), P(None, None)),
         out_specs=P("data", "model")))
 
@@ -203,7 +206,7 @@ def make_spmm_p(mesh, P_: int, variant: str = "deal",
     if variant == "allgather":
         def fn(H, w, nbr, mask):
             return _spmm_allgather_local(H, w, nbr[0], mask[0], P_=P_)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("data", "model"), P("data", None),
                       P("data", None, None), P("data", None, None)),
@@ -214,7 +217,7 @@ def make_spmm_p(mesh, P_: int, variant: str = "deal",
             return _spmm_graph_exchange_local(
                 H, w, mirror_src[0], edge_dst[0], edge_slot[0],
                 edge_mask[0], P_=P_)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("data", "model"), P("data", None)) +
             (plan_spec,) * 4,
@@ -224,7 +227,7 @@ def make_spmm_p(mesh, P_: int, variant: str = "deal",
         return _spmm_deal_local(
             H, w, send_local[0], edge_dst[0], edge_slot[0], edge_pos[0],
             edge_mask[0], P_=P_, grouped=grouped)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P("data", "model"), P("data", None)) + (plan_spec,) * 5,
         out_specs=P("data", "model")))
@@ -297,7 +300,7 @@ def make_sddmm_p(mesh, P_: int, fanout: int, variant: str = "deal"):
                      edge_pos[0], edge_mask[0], P_=P_, fanout=fanout)
     # approach (i) duplicates the computation, so its output is replicated
     # over `model` by construction — not statically inferable (check_vma).
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P("data", "model"), P("data", "model")) + (plan_spec,) * 5,
         out_specs=P("data", None), check_vma=(variant == "deal")))

@@ -60,6 +60,23 @@ def read_heartbeat(path: str):
         return None, "?"
 
 
+def _refuse_chip_host(n_shards: int) -> None:
+    """Every worker builds a full world with JAX, and a chip serves one
+    process.  This process already holds its JAX devices (the Session
+    built its world here), so on a chip host no worker could get one:
+    refuse before spawning rather than run the workers on the CPU."""
+    import jax
+
+    from repro.api.config import ConfigError
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ConfigError(
+            f"cluster.n_shards: {n_shards} shard workers would each need "
+            f"a {backend} chip of their own, but this process holds the "
+            f"chips; serve in one process (cluster.n_shards = 0) or run "
+            f"the cluster on the CPU with JAX_PLATFORMS=cpu")
+
+
 class WorkerWedged(RuntimeError):
     """A worker's main thread stopped stamping its heartbeat; the
     message names the stage it wedged in."""
@@ -70,6 +87,7 @@ class ClusterDeployment:
                  env: Optional[Dict[str, str]] = None):
         spec = cfg.cluster
         assert spec.n_shards > 0, "ClusterSpec.n_shards must be > 0"
+        _refuse_chip_host(spec.n_shards)
         self.cfg = cfg
         self.n_shards = int(spec.n_shards)
         self.host = spec.host
@@ -81,6 +99,9 @@ class ClusterDeployment:
         self._env = dict(os.environ if env is None else env)
         self._env["PYTHONPATH"] = os.pathsep.join(
             p for p in (_src_root(), self._env.get("PYTHONPATH")) if p)
+        # the parent is on the CPU (checked above): so are the workers,
+        # whatever env they were handed — never one stray worker on a chip
+        self._env["JAX_PLATFORMS"] = "cpu"
         self.procs: List[Optional[subprocess.Popen]] = [None] * self.n_shards
         self.n_restarts = 0
         self.ready_wait_s = 0.0

@@ -514,6 +514,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     hb = Heartbeat(args.heartbeat)
     hb.beat("startup")
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     from repro.api.config import DealConfig
     cfg = DealConfig.load(args.config)
     core = WorkerCore(cfg, args.shard, args.n_shards, args.dir,

@@ -12,47 +12,69 @@ AND the row-index table directly:
     out[i] = sum_f w[i,f] * mask[i,f] * h[table[nbr[i,f]]]
 
 i.e. the reorder disappears into layer-1's gather: one extra scalar
-load per edge (the table entry) replaces an (N, D) HBM round-trip.
-``nbr``/``w`` tiles are staged per node block; ``h`` and ``table`` stay
-HBM-resident (memory_space ANY) and are gathered per edge — on real TPU
-these become scalar-prefetch-driven DMAs.  Validated with
-interpret=True against ``ref.gather_spmm_ref`` (which is itself bitwise
-equal to ``spmm_ref`` over a materialized reorder).
+DMA per edge (the table entry) replaces an (N, D) HBM round-trip.
+Per grid step the ``nbr`` tile is staged in SMEM; ``h`` and ``table``
+stay in HBM (memory_space ANY).  A DMA moves whole 128-lane tiles, so
+the table is laid out (ceil(N/128), 128) and each edge copies the tile
+holding its entry into SMEM, a few rows of the block at a time, and
+reads the entry from it as a scalar.  Then each edge's ``h`` row slice
+is copied into VMEM exactly as in ``spmm``, and the weighted sum is
+``spmm``'s, so the fused kernel is bitwise equal to ``spmm`` over a
+materialized reorder.  Validated in interpret mode against
+``ref.gather_spmm_ref``.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.spmm import auto_block_n
+from repro.kernels.spmm import (LANES, auto_block_n, clamp_id,
+                                edge_specs, gather_rows, pad_lanes,
+                                weighted_sum)
 
 
-def _gather_spmm_kernel(nbr_ref, w_ref, table_ref, h_ref, o_ref, *,
-                        block_d: int, fanout: int, block_n: int):
-    j = pl.program_id(1)
-    d0 = j * block_d
+def _gather_spmm_kernel(nbr_ref, w_ref, table_hbm, h_hbm, o_ref, tbl_ref,
+                        idx_ref, rows_ref, sem, *, block_d: int,
+                        fanout: int, block_n: int, n_ids: int, chunk: int):
+    d0 = pl.program_id(1) * block_d
 
-    def body(i, acc):
-        r = i // fanout
-        f = i % fanout
-        gid = nbr_ref[r, f]
-        idx = table_ref[pl.dslice(gid, 1)][0]        # fused indirection
-        coef = w_ref[r, f].astype(jnp.float32)
-        row = h_ref[pl.dslice(idx, 1), pl.dslice(d0, block_d)]   # (1, bd)
-        return acc.at[r].add(coef * row[0].astype(jnp.float32))
+    def gid(r, f):
+        return clamp_id(nbr_ref[r, f], n_ids)
 
-    acc = jnp.zeros((block_n, block_d), jnp.float32)
-    acc = jax.lax.fori_loop(0, block_n * fanout, body, acc)
-    o_ref[...] = acc.astype(o_ref.dtype)
+    def copy(j, f, g):      # the lane tile holding table[g]
+        return pltpu.make_async_copy(
+            table_hbm.at[pl.ds(g // LANES, 1)],
+            tbl_ref.at[pl.ds(f * chunk + j, 1)], sem)
+
+    def resolve(c, carry):  # fused indirection, ``chunk`` rows at a time
+        r0 = c * chunk
+        for j in range(chunk):
+            for f in range(fanout):
+                copy(j, f, gid(r0 + j, f)).start()
+        for j in range(chunk):
+            for f in range(fanout):
+                copy(j, f, 0).wait()
+        for j in range(chunk):
+            for f in range(fanout):
+                idx_ref[r0 + j, f] = tbl_ref[f * chunk + j,
+                                             gid(r0 + j, f) % LANES]
+        return carry
+
+    jax.lax.fori_loop(0, block_n // chunk, resolve, 0)
+    gather_rows(h_hbm, rows_ref, sem, lambda r, f: idx_ref[r, f],
+                block_n=block_n, fanout=fanout, col0=d0, width=block_d)
+    o_ref[...] = weighted_sum(w_ref, rows_ref, fanout=fanout)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d",
                                              "interpret"))
 def gather_spmm(h, table, w, nbr, mask, *, block_n: int = None,
-                block_d: int = 128, interpret: bool = True):
+                block_d: int = 128, interpret: bool):
     """out[i] = sum_f w[i,f]*mask[i,f]*h[table[nbr[i,f]]].
 
     h: (U, D) source-row table in ARBITRARY order; table: (N,) int map
@@ -67,19 +89,25 @@ def gather_spmm(h, table, w, nbr, mask, *, block_n: int = None,
     if block_n is None:
         block_n = auto_block_n(R)
     assert R % block_n == 0 and D % block_d == 0, (R, D, block_n, block_d)
-    wm = (w * mask).astype(h.dtype)
-    grid = (R // block_n, D // block_d)
+    wm = (w * mask).astype(h.dtype).astype(jnp.float32)
+    table = jnp.asarray(table, jnp.int32)
+    n_ids = table.shape[0]
+    chunk = math.gcd(block_n, 8)
     return pl.pallas_call(
         functools.partial(_gather_spmm_kernel, block_d=block_d, fanout=F,
-                          block_n=block_n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, F), lambda i, j: (i, 0)),
+                          block_n=block_n, n_ids=n_ids, chunk=chunk),
+        grid=(R // block_n, D // block_d),
+        in_specs=edge_specs(block_n, F) + [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, D), h.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, D), jnp.float32),
+        scratch_shapes=[pltpu.SMEM((F * chunk, LANES), jnp.int32),
+                        pltpu.SMEM((block_n, F), jnp.int32),
+                        pltpu.VMEM((F, block_n, block_d), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(nbr, wm, jnp.asarray(table, jnp.int32), h)
+    )(jnp.asarray(nbr, jnp.int32), wm,
+      pad_lanes(table[None]).reshape(-1, LANES),
+      h.astype(jnp.float32)).astype(h.dtype)

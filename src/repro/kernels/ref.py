@@ -13,10 +13,16 @@ import math
 import jax
 import jax.numpy as jnp
 
+# f32 operands stay f32 on the MXU.  The TPU's default matmul precision
+# rounds them to bf16 (about three significant digits), which would make
+# every f32 model a bf16 one on the chip; the CPU ignores the setting.
+F32_MATMUL = jax.lax.Precision.HIGHEST
+
 
 def gemm_ref(h, w):
     """out = h @ w, accumulated in f32, cast back to h.dtype."""
-    return jnp.dot(h, w, preferred_element_type=jnp.float32).astype(h.dtype)
+    return jnp.dot(h, w, preferred_element_type=jnp.float32,
+                   precision=F32_MATMUL).astype(h.dtype)
 
 
 def spmm_ref(h, w, nbr, mask):
@@ -66,7 +72,8 @@ def gat_attention_ref(q, k, nbr, mask, heads: int):
     kh = k.reshape(-1, heads, dh).astype(jnp.float32)
     kn = jnp.take(kh, nbr.reshape(-1), axis=0).reshape(
         nbr.shape + (heads, dh))
-    s = jnp.einsum("nhd,nfhd->nfh", qh, kn) / jnp.sqrt(jnp.float32(dh))
+    s = jnp.einsum("nhd,nfhd->nfh", qh, kn, precision=F32_MATMUL) / jnp.sqrt(
+        jnp.float32(dh))
     m = mask[:, :, None]
     p = jax.nn.softmax(jnp.where(m, s, -1e30), axis=1)
     return p * m

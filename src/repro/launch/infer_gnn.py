@@ -18,6 +18,7 @@ import argparse
 
 from repro.api import (ConfigError, DealConfig, ExecutorSpec, GraphSpec,
                        ModelSpec, PartitionSpec, Session)
+from repro.runtime import enable_compile_cache
 
 
 def _run_session(cfg: DealConfig):
@@ -110,6 +111,7 @@ def main():
             cfg.dump(args.dump_config)
             print(f"[config] wrote {args.dump_config}")
         return
+    enable_compile_cache()
     _run_session(cfg)
 
 

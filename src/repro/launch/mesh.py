@@ -2,7 +2,14 @@
 device state; callers (dryrun) are responsible for the 512-device env."""
 from __future__ import annotations
 
-from repro.sharding.compat import make_mesh
+import jax
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axis types (sharding propagated by the
+    compiler, as every call site here expects)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,5 +19,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):
-    """Small mesh over host devices (tests / benchmarks subprocesses)."""
+    """Mesh over this process's devices: host devices in tests and bench
+    subprocesses, chips on a TPU host."""
     return make_mesh((n_data, n_model), ("data", "model"))

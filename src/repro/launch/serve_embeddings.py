@@ -43,6 +43,7 @@ from repro.api import (ClusterSpec, ConfigError, DealConfig,
                        QoSSpec, RefreshSpec, Session, StoreSpec,
                        tenants_from_string)
 from repro.gnnserve import EmbeddingServeEngine, Query, TenantRegistry
+from repro.runtime import enable_compile_cache
 
 
 def _tenant_dicts(tenants: TenantRegistry):
@@ -293,6 +294,7 @@ def main():
     if args.nodes_per_tick and cfg.store.onboarding != "tail":
         raise SystemExit("--nodes-per-tick needs --onboarding tail "
                          "(or store.onboarding=\"tail\" in --config)")
+    enable_compile_cache()
     if args.trace:
         cfg.telemetry.enabled = True
     if args.cluster_shards:

@@ -12,7 +12,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.compat import shard_map
 from repro.sharding.context import constrain
 
 _NEG_INF = -1e30
@@ -214,7 +213,7 @@ def cp_decode_attention(q, k_cache, v_cache, *, cache_len, mesh,
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out.reshape(B, 1, H, hd).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, "data", None, None),
                   P(None, "data", None, None), P()),

@@ -14,7 +14,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.compat import shard_map
 from repro.sharding.context import constrain
 
 
@@ -175,7 +174,7 @@ def _moe_block_ep(x, p, cfg, mesh):
     in_specs = (P(b_ax, None, None), P(None, None),
                 P("model", None, None), P("model", None, None),
                 P("model", None, None))
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(b_ax, None), P()),

@@ -601,6 +601,22 @@ def test_cluster_bitwise_on_accelerated_executor(executor):
         assert digs[0]["digests"] == digs[1]["digests"]
 
 
+def test_cluster_refused_on_chip_host(monkeypatch, tmp_path):
+    """Where this process holds chips, a cluster config is refused
+    before any worker spawns, naming cluster.n_shards: no worker may
+    fall back to the CPU."""
+    import jax
+
+    from repro.api import ConfigError
+    from repro.gnnserve.cluster import ClusterDeployment
+    cfg = DealConfig.from_dict({**_cfg_dict(n=128),
+                                "cluster": {"n_shards": 2}})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ConfigError, match=r"cluster\.n_shards"):
+        ClusterDeployment(cfg, run_dir=str(tmp_path))
+    assert list(tmp_path.iterdir()) == []       # nothing spawned
+
+
 # ----------------------------------------------------------------------
 # QoS + telemetry cluster: merged attribution, aggregated /healthz
 # ----------------------------------------------------------------------

@@ -37,7 +37,7 @@ def test_spmm_sweep(N, D, F, bn, bd, dtype, rng):
     w = jnp.asarray(rng.standard_normal((N, F)), dtype)
     nbr = jnp.asarray(rng.integers(0, N, (N, F)), jnp.int32)
     mask = jnp.asarray(rng.random((N, F)) > 0.25)
-    got = spmm(h, w, nbr, mask, block_n=bn, block_d=bd)
+    got = spmm(h, w, nbr, mask, block_n=bn, block_d=bd, interpret=True)
     want = ref.spmm_ref(h, w, nbr, mask)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -51,7 +51,7 @@ def test_sddmm_sweep(N, D, F, dtype, rng):
     k = jnp.asarray(rng.standard_normal((N, D)), dtype)
     nbr = jnp.asarray(rng.integers(0, N, (N, F)), jnp.int32)
     mask = jnp.asarray(rng.random((N, F)) > 0.25)
-    got = sddmm(q, k, nbr, mask, block_n=8)
+    got = sddmm(q, k, nbr, mask, block_n=8, interpret=True)
     want = ref.sddmm_ref(q, k, nbr, mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=ATOL[dtype] * np.sqrt(D), rtol=3e-2)
@@ -91,7 +91,8 @@ def test_gather_spmm_sweep(R, U, D, F, bn, bd, dtype, rng):
     w = jnp.asarray(rng.standard_normal((R, F)), dtype)
     nbr = jnp.asarray(rng.integers(0, U, (R, F)), jnp.int32)
     mask = jnp.asarray(rng.random((R, F)) > 0.25)
-    got = gather_spmm(h, table, w, nbr, mask, block_n=bn, block_d=bd)
+    got = gather_spmm(h, table, w, nbr, mask, block_n=bn, block_d=bd,
+                      interpret=True)
     want = ref.gather_spmm_ref(h, table, w, nbr, mask)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -107,9 +108,10 @@ def test_gather_spmm_bitwise_vs_materialized(rng):
     w = jnp.asarray(rng.standard_normal((R, F)).astype(np.float32))
     nbr = jnp.asarray(rng.integers(0, U, (R, F)), jnp.int32)
     mask = jnp.asarray(rng.random((R, F)) > 0.25)
-    fused = gather_spmm(h, table, w, nbr, mask, block_n=8, block_d=128)
+    fused = gather_spmm(h, table, w, nbr, mask, block_n=8, block_d=128,
+                        interpret=True)
     materialized = spmm(jnp.take(h, table, axis=0), w, nbr, mask,
-                        block_n=8, block_d=128)
+                        block_n=8, block_d=128, interpret=True)
     np.testing.assert_array_equal(np.asarray(fused),
                                   np.asarray(materialized))
 
@@ -123,7 +125,7 @@ def test_gather_spmm_quantized_strict(rng):
     w = jnp.asarray(_quantized(rng, (R, F)))
     nbr = jnp.asarray(rng.integers(0, U, (R, F)), jnp.int32)
     mask = jnp.asarray(rng.random((R, F)) > 0.25)
-    got = np.asarray(gather_spmm(h, table, w, nbr, mask))
+    got = np.asarray(gather_spmm(h, table, w, nbr, mask, interpret=True))
     want = np.asarray(ref.gather_spmm_ref(h, table, w, nbr, mask))
     assert np.abs(got - want).max() < 5e-7
 
@@ -143,7 +145,7 @@ def test_gat_attention_sweep(N, U, D, F, heads, dtype, rng):
     k = jnp.asarray(rng.standard_normal((U, D)), dtype)
     nbr = jnp.asarray(rng.integers(0, U, (N, F)), jnp.int32)
     mask = jnp.asarray(rng.random((N, F)) > 0.25)
-    got = gat_attention(q, k, nbr, mask, heads=heads)
+    got = gat_attention(q, k, nbr, mask, heads=heads, interpret=True)
     want = ref.gat_attention_ref(q, k, nbr, mask, heads)
     assert got.shape == (N, F, heads)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -161,7 +163,8 @@ def test_gat_attention_strict_f32(rng):
     k = jnp.asarray(rng.standard_normal((U, D)).astype(np.float32))
     nbr = jnp.asarray(rng.integers(0, U, (N, F)), jnp.int32)
     mask = jnp.asarray(rng.random((N, F)) > 0.25)
-    got = np.asarray(gat_attention(q, k, nbr, mask, heads=heads))
+    got = np.asarray(gat_attention(q, k, nbr, mask, heads=heads,
+                                  interpret=True))
     want = np.asarray(ref.gat_attention_ref(q, k, nbr, mask, heads))
     assert np.abs(got - want).max() < 5e-7
 
