@@ -14,7 +14,8 @@ One chip (default), two phases in this one process:
          config seed).  Run with ``executor: "pallas"`` (the Pallas
          kernels, compiled), then with ``executor: "ref"`` (the plain f32
          jnp reference) on the same chip; the max abs difference relative
-         to max|H_ref| must stay within TOL.
+         to max|H_ref| must stay within TOL, and so must the shared
+         f32 GEMM against a float64 product on the host.
   serve  ``Session.serve()`` on the pallas world: a few lookups through
          ``engine.submit``/``step`` (bitwise equal to the epoch's rows),
          one mutation batch, ``refresh()``, and every row of every level
@@ -48,8 +49,9 @@ SEED = 0
 # pallas (and dist) vs ref: f32 throughout (matmuls at HIGHEST precision),
 # so the executors differ only in summation order and in the exp/divide
 # of the softmax, a few f32 ulps (~1e-7) per op.  Three layers of 128-wide
-# dots keep that near 1e-6 of max|H_ref|; 1e-5 leaves a decade of margin,
-# while a wrong neighbor, head or mask moves the output by O(1e-1).
+# dots keep that near 1e-6 of max|H_ref|; 1e-5 leaves a decade of margin.
+# The other side: the same ref epoch with its matmuls at the TPU's
+# DEFAULT precision (bf16 passes) reads far above 1e-5 (PERF.md).
 TOL = 1e-5
 
 
@@ -142,6 +144,17 @@ def compare(name: str, H, H_ref) -> float:
     return err
 
 
+def gemm_precision(ex) -> float:
+    """The executors share one f32 GEMM, so pallas vs ref cannot see its
+    precision: hold it to a float64 product made on the host."""
+    import numpy as np
+    rng = np.random.default_rng(SEED + 2)
+    x = rng.standard_normal((4096, 128), dtype=np.float32)
+    w = rng.standard_normal((128, 128), dtype=np.float32)
+    return compare("gemm_vs_f64", np.asarray(ex.gemm(x, w)),
+                   x.astype(np.float64) @ w)
+
+
 def epoch_phase(clock, *, scale: float = 32):
     """All-node GAT epoch: pallas vs ref on the same chip.  Returns the
     pallas Session (kept for the serve phase) and its output."""
@@ -155,6 +168,7 @@ def epoch_phase(clock, *, scale: float = 32):
     with build(smoke_config("ref", scale=scale), clock) as s_ref:
         with Phase(clock, "epoch.ref"):
             H_ref = s_ref.infer_all()
+        gemm_precision(s_ref.executor)
     compare("epoch.pallas_vs_ref", H, H_ref)
     return s, H
 

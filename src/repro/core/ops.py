@@ -29,7 +29,6 @@ gathered universe) — see ``gnnserve.delta``.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -47,7 +46,7 @@ from repro.core.partition import build_plan, build_subset_plan_cached
 from repro.core.sampler import LayerGraph
 from repro.kernels import ops as kops
 from repro.kernels.ref import F32_MATMUL
-from repro.kernels.spmm import LANES, auto_block_n, lane_width, pad_lanes
+from repro.kernels.spmm import auto_block_n
 
 
 # ----------------------------------------------------------------------
@@ -247,15 +246,6 @@ class RefExecutor:
 # PallasExecutor — the kernels in kernels/ (compiled on TPU)
 # ----------------------------------------------------------------------
 
-def lane_block(D: int, block_d: int) -> int:
-    """Column block of a kernel call over D feature columns, which run
-    padded to ``lane_width(D)``.  Blocks are whole 128-lane tiles, since
-    the TPU refuses a narrower block of a wider array, so an awkward
-    width (ogbn-products' D=100) pads up to the next tile multiple."""
-    bd = math.gcd(lane_width(D), block_d)
-    return bd if bd % LANES == 0 else LANES
-
-
 def pad_to_blocks(block_n: int, nbr, mask, *row_arrays):
     """Pad the leading (row) axis of graph-shaped arrays to the next
     ``block_n`` multiple — the ONE pad-to-block helper every Pallas
@@ -280,8 +270,9 @@ class PallasExecutor(RefExecutor):
     """Routes spmm/sddmm through the Pallas kernels (``kernels.ops``
     dispatch: compiled on TPU, interpret mode elsewhere).  GEMM stays on
     XLA's MXU path — a hand-written matmul kernel would only lose.
-    Rows are padded to block multiples and feature columns to whole
-    128-lane tiles, then sliced back — non-aligned shapes just work.
+    Rows are padded to block multiples here and feature columns to whole
+    lane tiles inside the kernels, then sliced back — non-aligned shapes
+    just work.
 
     ``fused_gather``: consume ``DenseIO.table`` via the fused
     gather+spmm kernel instead of materializing ``nbr_resolved``
@@ -339,11 +330,9 @@ class PallasExecutor(RefExecutor):
         R, F = nbr.shape
         D = H_src.shape[1]
         kernel = "gather_spmm" if table is not None else "spmm"
-        bn, bd0 = self._pick_blocks(kernel, R, D, H_src.dtype)
+        bn, bd = self._pick_blocks(kernel, R, D, H_src.dtype)
         pad_n, block_n = self._row_block(bn, R)
         _, nbr, mask, w_edge = pad_to_blocks(pad_n, nbr, mask, w_edge)
-        bd = lane_block(D, bd0)
-        H_src = pad_lanes(H_src)
         if table is not None:
             out = kops.gather_spmm(H_src, table, w_edge, nbr, mask,
                                    use_kernel=self.use_kernel,
@@ -352,7 +341,7 @@ class PallasExecutor(RefExecutor):
             out = kops.spmm(H_src, w_edge, nbr, mask,
                             use_kernel=self.use_kernel,
                             block_n=block_n, block_d=bd)
-        return out[:R, :D]
+        return out[:R]
 
     def spmm(self, H_src, w_edge, io: DenseIO):
         if self.fused_gather and io.table is not None:

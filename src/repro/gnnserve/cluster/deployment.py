@@ -39,8 +39,10 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.api.config import ConfigError
 from repro.gnnserve.cluster.protocol import Channel
 from repro.gnnserve.cluster.router import Router, RouterEndpoint
+from repro.runtime import require_host_platform
 
 
 def _src_root() -> str:
@@ -60,23 +62,6 @@ def read_heartbeat(path: str):
         return None, "?"
 
 
-def _refuse_chip_host(n_shards: int) -> None:
-    """Every worker builds a full world with JAX, and a chip serves one
-    process.  This process already holds its JAX devices (the Session
-    built its world here), so on a chip host no worker could get one:
-    refuse before spawning rather than run the workers on the CPU."""
-    import jax
-
-    from repro.api.config import ConfigError
-    backend = jax.default_backend()
-    if backend != "cpu":
-        raise ConfigError(
-            f"cluster.n_shards: {n_shards} shard workers would each need "
-            f"a {backend} chip of their own, but this process holds the "
-            f"chips; serve in one process (cluster.n_shards = 0) or run "
-            f"the cluster on the CPU with JAX_PLATFORMS=cpu")
-
-
 class WorkerWedged(RuntimeError):
     """A worker's main thread stopped stamping its heartbeat; the
     message names the stage it wedged in."""
@@ -87,7 +72,17 @@ class ClusterDeployment:
                  env: Optional[Dict[str, str]] = None):
         spec = cfg.cluster
         assert spec.n_shards > 0, "ClusterSpec.n_shards must be > 0"
-        _refuse_chip_host(spec.n_shards)
+        # every worker builds a full world with JAX and a chip serves one
+        # process; this one already holds the chips (the Session built its
+        # world here), so refuse before spawning rather than run the
+        # workers on the CPU
+        require_host_platform(
+            f"cluster.n_shards={spec.n_shards}",
+            why="spawns shard workers that would each need a chip of "
+                "their own, while this process holds the chips",
+            fix="serve in one process (cluster.n_shards = 0) or run the "
+                "cluster on the CPU with JAX_PLATFORMS=cpu",
+            error=ConfigError)
         self.cfg = cfg
         self.n_shards = int(spec.n_shards)
         self.host = spec.host

@@ -73,7 +73,7 @@ def gat_attention(q, k, nbr, mask, *, heads: int = 1, block_n: int = None,
     if block_n is None:
         block_n = auto_block_n(N)
     assert N % block_n == 0, (N, block_n)
-    mask_f = mask.astype(q.dtype).astype(jnp.float32)
+    mask_f = mask.astype(jnp.float32)
     q = pad_lanes(q.astype(jnp.float32))
     k = pad_lanes(k.astype(jnp.float32))
     Dp = q.shape[1]

@@ -34,8 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.spmm import (LANES, auto_block_n, clamp_id,
-                                edge_specs, gather_rows, pad_lanes,
-                                weighted_sum)
+                                edge_specs, gather_rows, lane_block,
+                                pad_lanes, weighted_sum)
 
 
 def _gather_spmm_kernel(nbr_ref, w_ref, table_hbm, h_hbm, o_ref, tbl_ref,
@@ -80,34 +80,37 @@ def gather_spmm(h, table, w, nbr, mask, *, block_n: int = None,
     h: (U, D) source-row table in ARBITRARY order; table: (N,) int map
     from the id space ``nbr`` uses onto h's rows; w/mask/nbr: (R, F).
     Same R/U decoupling as ``spmm`` (row-subset mode), with the id
-    translation fused into the gather.  R % block_n == 0,
-    D % block_d == 0; masked slots may map anywhere in-range (their
-    coefficient is 0.0 exactly).
+    translation fused into the gather.  R % block_n == 0; any D, padded
+    to lane tiles as in ``spmm``.  Masked slots may map anywhere in-range
+    (their coefficient is 0.0 exactly).
     """
     U, D = h.shape
     R, F = nbr.shape
     if block_n is None:
         block_n = auto_block_n(R)
-    assert R % block_n == 0 and D % block_d == 0, (R, D, block_n, block_d)
+    assert R % block_n == 0, (R, block_n)
     wm = (w * mask).astype(h.dtype).astype(jnp.float32)
     table = jnp.asarray(table, jnp.int32)
     n_ids = table.shape[0]
     chunk = math.gcd(block_n, 8)
-    return pl.pallas_call(
+    hp = pad_lanes(h.astype(jnp.float32))
+    Dp = hp.shape[1]
+    block_d = lane_block(D, block_d)
+    out = pl.pallas_call(
         functools.partial(_gather_spmm_kernel, block_d=block_d, fanout=F,
                           block_n=block_n, n_ids=n_ids, chunk=chunk),
-        grid=(R // block_n, D // block_d),
+        grid=(R // block_n, Dp // block_d),
         in_specs=edge_specs(block_n, F) + [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, Dp), jnp.float32),
         scratch_shapes=[pltpu.SMEM((F * chunk, LANES), jnp.int32),
                         pltpu.SMEM((block_n, F), jnp.int32),
                         pltpu.VMEM((F, block_n, block_d), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
     )(jnp.asarray(nbr, jnp.int32), wm,
-      pad_lanes(table[None]).reshape(-1, LANES),
-      h.astype(jnp.float32)).astype(h.dtype)
+      pad_lanes(table[None]).reshape(-1, LANES), hp)
+    return out[:, :D].astype(h.dtype)

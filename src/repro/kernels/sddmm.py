@@ -47,7 +47,7 @@ def sddmm(q, k, nbr, mask, *, block_n: int = None, interpret: bool):
     if block_n is None:
         block_n = auto_block_n(N)
     assert N % block_n == 0, (N, block_n)
-    mask_f = mask.astype(q.dtype).astype(jnp.float32)
+    mask_f = mask.astype(jnp.float32)
     # zero lanes add exactly 0.0 to every dot
     q = pad_lanes(q.astype(jnp.float32))
     k = pad_lanes(k.astype(jnp.float32))

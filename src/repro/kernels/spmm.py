@@ -22,6 +22,7 @@ described v5e in ``tests/test_tpu_compile.py``.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,15 @@ def pad_lanes(x):
     if lane_width(d) == d:
         return x
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lane_width(d) - d)])
+
+
+def lane_block(d: int, block_d: int) -> int:
+    """Column block of a kernel over ``d`` columns padded to
+    ``lane_width(d)``.  Blocks are whole lane tiles, since the TPU refuses
+    a narrower block of a wider array, so an awkward width (ogbn-products'
+    D=100) runs padded to the next tile multiple."""
+    bd = math.gcd(lane_width(d), block_d)
+    return bd if bd % LANES == 0 else LANES
 
 
 def clamp_id(idx, n: int):
@@ -141,25 +151,29 @@ def spmm(h, w, nbr, mask, *, block_n: int = None, block_d: int = 128,
     h: (N, D) source-row table; w/mask/nbr: (R, F).  The output has R rows
     — R and N are decoupled so the layer-op executors can gather from a
     universe table while producing only the target rows (row-subset mode).
-    R % block_n == 0 (block_n=None picks the largest divisor <=64),
-    D % block_d == 0 and block_d % 128 == 0 (``core.ops.lane_blocks``).
+    R % block_n == 0 (block_n=None picks the largest divisor <=64).  Any
+    D: columns run padded to whole lane tiles in ``lane_block`` column
+    blocks, and the output is sliced back to D.
     """
     N, D = h.shape
     R, F = nbr.shape
     if block_n is None:
         block_n = auto_block_n(R)
-    assert R % block_n == 0 and D % block_d == 0, (R, D, block_n, block_d)
+    assert R % block_n == 0, (R, block_n)
     wm = (w * mask).astype(h.dtype).astype(jnp.float32)
-    return pl.pallas_call(
+    hp = pad_lanes(h.astype(jnp.float32))
+    Dp = hp.shape[1]
+    block_d = lane_block(D, block_d)
+    out = pl.pallas_call(
         functools.partial(_spmm_kernel, block_d=block_d, fanout=F,
                           block_n=block_n),
-        grid=(R // block_n, D // block_d),
+        grid=(R // block_n, Dp // block_d),
         in_specs=edge_specs(block_n, F) + [
             pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, Dp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((F, block_n, block_d), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(jnp.asarray(nbr, jnp.int32), wm, h.astype(jnp.float32)).astype(
-        h.dtype)
+    )(jnp.asarray(nbr, jnp.int32), wm, hp)
+    return out[:, :D].astype(h.dtype)

@@ -6,10 +6,11 @@
     the checkout, derived from this file, so every process of the
     checkout finds it again: cluster workers run in a temp directory,
     which makes a cwd-relative path miss.
-``require_host_platform``  forced host devices
-    (``--xla_force_host_platform_device_count``) are CPU devices, so a
-    mesh that respawns onto them refuses to run where JAX found a chip
-    instead of running on the CPU unannounced.
+``require_host_platform``  the refusal of a path that must run on the
+    CPU where JAX found a chip: a mesh respawned onto forced host devices
+    (``--xla_force_host_platform_device_count``, which are CPU devices),
+    or cluster workers, which cannot get a chip this process holds.  It
+    refuses instead of running on the CPU unannounced.
 """
 from __future__ import annotations
 
@@ -30,13 +31,17 @@ def enable_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
-def require_host_platform(what: str) -> None:
-    """Raise unless JAX's backend is the CPU (``what`` names the caller
-    in the message)."""
+FORCED_HOST = "runs a mesh of forced host devices, which are CPU devices"
+
+
+def require_host_platform(what: str, *, why: str = FORCED_HOST,
+                          fix: str = "run it with JAX_PLATFORMS=cpu, or "
+                                     "run the chips' own path",
+                          error: type = RuntimeError) -> None:
+    """Raise ``error`` unless JAX's backend is the CPU.  The message
+    reads "<what> <why>, but JAX found <backend> devices here; <fix>"."""
     import jax
     backend = jax.default_backend()
     if backend != "cpu":
-        raise RuntimeError(
-            f"{what} runs a mesh of forced host devices, which are CPU "
-            f"devices, but JAX found {backend} devices here; run it with "
-            f"JAX_PLATFORMS=cpu, or run the chips' own path")
+        raise error(f"{what} {why}, but JAX found {backend} devices here; "
+                    f"{fix}")
