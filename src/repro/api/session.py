@@ -62,6 +62,7 @@ class Session:
         self._plan_cache_counters = install_plan_cache_counters()
         self._build_pipeline()
         self._H: Optional[np.ndarray] = None
+        self._n_epochs = 0           # infer_all epochs run, for span attrs
         self._engine = None
         self._endpoint = None
         self._cluster = None
@@ -137,20 +138,29 @@ class Session:
         lgs = self.layer_graphs[:len(spec.layers)]
         ex = self.executor
         t0 = time.perf_counter()
-        with obs.span("session.infer_all",
-                      {"model": self.cfg.model.name}) as sp:
-            if isinstance(ex, DistExecutor):
-                need_sddmm = any(op.kind == "attn_scores"
-                                 for layer in spec.layers
-                                 for op in layer.ops)
-                ios = ex.bind(lgs, need_sddmm=need_sddmm)
-            else:
-                ios = [DenseIO.from_layer_graph(lg) for lg in lgs]
-            self._H = np.asarray(run_model(ex, spec, ios, self.X))
+        self._n_epochs += 1
+        # phases by name, so a profiler trace tells host staging,
+        # dispatch and the copy back apart (see ``obs.trace``)
+        with obs.span("session.infer_all") as sp:
+            if sp:
+                sp.set(model=self.cfg.model.name, epoch=self._n_epochs)
+            with obs.span("infer.bind"):
+                if isinstance(ex, DistExecutor):
+                    need_sddmm = any(op.kind == "attn_scores"
+                                     for layer in spec.layers
+                                     for op in layer.ops)
+                    ios = ex.bind(lgs, need_sddmm=need_sddmm)
+                else:
+                    ios = [DenseIO.from_layer_graph(lg) for lg in lgs]
+            with obs.span("infer.forward"):
+                H = run_model(ex, spec, ios, self.X)
+            with obs.span("infer.fetch"):
+                self._H = np.asarray(H)
+            with obs.span("infer.check"):
+                assert not np.isnan(self._H).any()
             if sp:
                 sp.set(rows=int(self._H.shape[0]))
         self.timings["infer_s"] = time.perf_counter() - t0
-        assert not np.isnan(self._H).any()
         return self._H
 
     # -- online: store + serving engine ---------------------------------

@@ -96,7 +96,8 @@ class DenseIO:
         """Mean-aggregation edge weights (computed lazily: gat never
         reads them)."""
         if self._mean_w is None:
-            self._mean_w = jnp.asarray(mean_weights(self.mask_np))
+            with obs.span("infer.mean_w"):
+                self._mean_w = jnp.asarray(mean_weights(self.mask_np))
         return self._mean_w
 
 
@@ -182,8 +183,10 @@ def run_layer(ex, layer: LayerSpec, io, h_tgt, h_src, heads: int = 1):
             else:
                 raise ValueError(f"unknown layer op {kind!r}")
             if sp:
-                # make the span honest under async dispatch; value-neutral
-                out = jax.block_until_ready(out)
+                if not obs.profiling():
+                    # make the span's host time stand for device time;
+                    # under a profiler the trace has the device's own
+                    out = jax.block_until_ready(out)
                 sp.set(executor=getattr(ex, "name", type(ex).__name__),
                        rows=int(out.shape[0]))
         env[out_slot] = out
@@ -195,12 +198,14 @@ def run_model(ex, spec: ModelSpec, ios: Sequence, X,
     """Full forward pass: layer l reads/writes the same row set
     (h_src == h_tgt == H), activation between layers."""
     act = activation or spec.activation
-    H = ex.prepare(X)
+    with obs.span("model.prepare"):
+        H = ex.prepare(X)
     L = len(spec.layers)
     for l, layer in enumerate(spec.layers):
         H = run_layer(ex, layer, ios[l], H, H, spec.heads)
         if l < L - 1:
-            H = act(H)
+            with obs.span("ops.activation"):
+                H = act(H)
     return H
 
 

@@ -90,5 +90,6 @@ def gat_attention(q, k, nbr, mask, *, heads: int = 1, block_n: int = None,
         scratch_shapes=[pltpu.VMEM((F, block_n, Dp), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="gat_attention",  # the op name traces and rooflines match on
     )(jnp.asarray(nbr, jnp.int32), mask_f, q, k)
     return alpha.transpose(1, 2, 0)

@@ -111,6 +111,7 @@ def gather_spmm(h, table, w, nbr, mask, *, block_n: int = None,
                         pltpu.VMEM((F, block_n, block_d), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="gather_spmm",  # the op name traces and rooflines match on
     )(jnp.asarray(nbr, jnp.int32), wm,
       pad_lanes(table[None]).reshape(-1, LANES), hp)
     return out[:, :D].astype(h.dtype)

@@ -64,4 +64,5 @@ def sddmm(q, k, nbr, mask, *, block_n: int = None, interpret: bool):
         scratch_shapes=[pltpu.VMEM((F, block_n, D), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="sddmm",  # the op name traces and rooflines match on
     )(jnp.asarray(nbr, jnp.int32), mask_f, q, k)

@@ -175,5 +175,6 @@ def spmm(h, w, nbr, mask, *, block_n: int = None, block_d: int = 128,
         scratch_shapes=[pltpu.VMEM((F, block_n, block_d), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="spmm",  # the op name traces and rooflines match on
     )(jnp.asarray(nbr, jnp.int32), wm, hp)
     return out[:, :D].astype(h.dtype)

@@ -23,7 +23,10 @@ provider" pattern):
 The process default is a DISABLED singleton: every helper is a true
 no-op whose cost is one attribute check (``tel.enabled``) and which
 allocates nothing — hot paths stay instrumented at all times without a
-perf tax.  ``api.Session`` builds a ``Telemetry`` from its config's
+perf tax.  The one exception is a span under an active ``jax.profiler``
+trace: it then enters a ``TraceAnnotation`` of its name (still falsy),
+so the program's phases show in the device trace (``obs.trace``).
+``api.Session`` builds a ``Telemetry`` from its config's
 ``TelemetrySpec`` and ``install``s it for the session's lifetime;
 tests use the ``use(tel)`` context manager.  Only ONE telemetry is
 current per process at a time (sessions that overlap share the last
@@ -37,7 +40,8 @@ from typing import Optional
 from repro.obs.export import (chrome_trace, dump_chrome_trace,
                               prometheus_text)
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry)
-from repro.obs.trace import NOOP_SPAN, FakeClock, NoopSpan, Tracer
+from repro.obs.trace import (NOOP_SPAN, FakeClock, NoopSpan, ProfilerSpan,
+                             Tracer, profiling)
 
 
 class Telemetry:
@@ -51,24 +55,17 @@ class Telemetry:
         self.tracer = Tracer(clock=clock, capacity=capacity)
         self.metrics = MetricsRegistry()
         # every completed span also feeds a per-name duration histogram
-        # (``ops.spmm`` span -> ``ops.spmm_ms`` metric), with a second
-        # executor-attributed series when the span carries an
-        # ``executor`` attr (``ops.spmm.pallas_ms``) — the pallas-vs-ref
-        # breakdown falls out of the same instrumentation site
+        # (``ops.spmm`` span -> ``ops.spmm_ms`` metric)
         self.tracer.on_record = self._span_metric
 
-    def _span_metric(self, name, dur_ns, attrs) -> None:
-        ms = dur_ns / 1e6
-        self.metrics.histogram(name + "_ms").observe(ms)
-        if attrs:
-            ex = attrs.get("executor")
-            if ex:
-                self.metrics.histogram(f"{name}.{ex}_ms").observe(ms)
+    def _span_metric(self, name, dur_ns, _attrs) -> None:
+        self.metrics.histogram(name + "_ms").observe(dur_ns / 1e6)
 
     # -- spans ----------------------------------------------------------
     def span(self, name: str, attrs: Optional[dict] = None):
         if not self.enabled:
-            return NOOP_SPAN
+            # NOOP_SPAN, or a falsy annotation while a profiler traces
+            return ProfilerSpan(name) if profiling() else NOOP_SPAN
         return self.tracer.span(name, attrs)
 
     # -- metrics --------------------------------------------------------
@@ -124,12 +121,12 @@ def use(tel: Optional[Telemetry]):
 
 
 # -- module-level hot-path helpers (single attribute check, zero
-#    allocation when disabled) ------------------------------------------
+#    allocation when disabled and no profiler trace runs) ---------------
 
 def span(name: str, attrs: Optional[dict] = None):
     tel = _CURRENT
     if not tel.enabled:
-        return NOOP_SPAN
+        return ProfilerSpan(name) if profiling() else NOOP_SPAN
     return tel.tracer.span(name, attrs)
 
 
@@ -153,6 +150,7 @@ def observe(name: str, v: float) -> None:
 
 __all__ = ["Telemetry", "Tracer", "FakeClock", "MetricsRegistry",
            "Counter", "Gauge", "Histogram", "NoopSpan", "NOOP_SPAN",
+           "ProfilerSpan", "profiling",
            "DISABLED", "chrome_trace", "dump_chrome_trace",
            "prometheus_text", "current", "enabled", "install", "use",
            "span", "add", "gauge", "observe"]

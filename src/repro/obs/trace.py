@@ -19,11 +19,18 @@ span layout is bit-for-bit deterministic (golden exporter files).
 
 The no-op story lives one level up (``obs.Telemetry.span`` /
 ``obs.span``): when telemetry is disabled those return the shared
-``NOOP_SPAN`` singleton after a single attribute check — no ``_Span``
-allocation, no clock read, nothing recorded.
+``NOOP_SPAN`` singleton after an attribute check and ``profiling()`` —
+no ``_Span`` allocation, no clock read, nothing recorded.
+
+Profiler: while a ``jax.profiler`` trace is being collected, every span
+also enters a ``TraceAnnotation`` of its exact name, so it lands in the
+device trace on the profiler's clock.  With telemetry disabled that is
+a ``ProfilerSpan``: falsy like ``NOOP_SPAN`` (call sites build no attrs
+and never sync), but annotated.
 """
 from __future__ import annotations
 
+import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -69,16 +76,55 @@ class NoopSpan:
 
 NOOP_SPAN = NoopSpan()
 
+# ``jax.profiler.TraceAnnotation``, bound on the first ``profiling()``
+# call after jax is imported (``import repro.obs`` stays jax-free)
+_annotation = None
+
+
+def profiling() -> bool:
+    """True while a ``jax.profiler`` trace is being collected (one
+    ``is_enabled()`` call; no profiler can run before jax is imported)."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation.is_enabled()
+
+
+class ProfilerSpan(NoopSpan):
+    """Disabled-telemetry span under an active profiler trace: records
+    nothing of its own, enters a ``TraceAnnotation`` named exactly as
+    the span (no metadata), and stays falsy."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = _annotation(name)
+
+    def __enter__(self) -> "ProfilerSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
 
 class _Span:
-    __slots__ = ("_tr", "name", "attrs", "_t0", "_depth")
+    __slots__ = ("_tr", "name", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[dict]):
         self._tr = tracer
         self.name = name
         self.attrs = attrs
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        if profiling():
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
         tr = self._tr
         self._depth = tr.depth
         tr.depth += 1
@@ -91,6 +137,9 @@ class _Span:
         tr.depth -= 1
         tr.record(self.name, self._t0, t1 - self._t0, self._depth,
                   self.attrs)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         return False
 
     def __bool__(self) -> bool:

@@ -65,13 +65,15 @@ def test_span_ring_buffer_drops_oldest():
 
 
 def test_span_feeds_duration_histogram_with_executor_attribution():
+    # one histogram per span name: a session runs one executor, so the
+    # ``executor`` attr adds no second, attributed series
     tel = _tel()
     with tel.span("ops.spmm") as sp:
         sp.set(executor="pallas")
     d = tel.metrics.to_dict()
     assert d["ops.spmm_ms.count"] == 1
-    assert d["ops.spmm.pallas_ms.count"] == 1
     assert d["ops.spmm_ms.sum"] == pytest.approx(1e-3)   # 1000ns
+    assert {k.rsplit(".", 1)[0] for k in d} == {"ops.spmm_ms"}
 
 
 def test_coverage_interval_union():

@@ -15,6 +15,7 @@ compile in this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +98,34 @@ def test_gat_attention_compiles(one_chip, no_persistent_cache):
     c = _compile(fn, [((N, 128), jnp.float32), ((2 * N, 128), jnp.float32),
                       IDS, MASK], one_chip)
     assert "tpu_custom_call" in c.as_text()
+
+
+# The names a device trace shows for each kernel: the jitted wrapper's
+# module (``jit_<name>``) and the Pallas custom call inside it (``<name>``,
+# set by ``pallas_call(name=...)``).  The benchmark's roofline metrics
+# match on these, so a rename must be deliberate.
+PINNED = {
+    "spmm": (spmm, [((N, 128), jnp.float32), W, IDS, MASK],
+             dict(block_n=64, block_d=128)),
+    "gather_spmm": (gather_spmm, [((N, 128), jnp.float32),
+                                  ((N,), jnp.int32), W, IDS, MASK],
+                    dict(block_n=64, block_d=128)),
+    "sddmm": (sddmm, [((N, 128), jnp.float32), ((N, 128), jnp.float32),
+                      IDS, MASK], dict(block_n=64)),
+    "gat_attention": (gat_attention, [((N, 128), jnp.float32),
+                                      ((2 * N, 128), jnp.float32), IDS,
+                                      MASK], dict(heads=HEADS, block_n=64)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_kernel_names_are_pinned(name, one_chip, no_persistent_cache):
+    kernel, shapes, kw = PINNED[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = kernel.lower(*args, interpret=False, **kw).compile().as_text()
+    assert re.search(rf"^HloModule jit_{name}\b", text, re.M)
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(
+        re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln) for ln in calls), \
+        calls
