@@ -338,6 +338,8 @@ class PallasExecutor(RefExecutor):
         bn, bd = self._pick_blocks(kernel, R, D, H_src.dtype)
         pad_n, block_n = self._row_block(bn, R)
         _, nbr, mask, w_edge = pad_to_blocks(pad_n, nbr, mask, w_edge)
+        if w_edge.ndim == 3:        # (R, F, heads) -> head-major
+            w_edge = w_edge.transpose(2, 0, 1)
         if table is not None:
             out = kops.gather_spmm(H_src, table, w_edge, nbr, mask,
                                    use_kernel=self.use_kernel,
@@ -396,17 +398,11 @@ class PallasExecutor(RefExecutor):
         return alpha[:R]
 
     def attend(self, alpha, v, io: DenseIO, heads: int):
-        D = v.shape[-1]
-        dh = D // heads
-        nbr = io.nbr if (self.fused_gather and io.table is not None) \
-            else io.nbr_resolved
-        table = io.table if (self.fused_gather and io.table is not None) \
-            else None
-        outs = [self._spmm_kernel(v[:, h * dh:(h + 1) * dh],
-                                  alpha[..., h], nbr, io.mask,
-                                  table=table)
-                for h in range(heads)]
-        return jnp.concatenate(outs, axis=-1)
+        """One kernel call for all heads: each edge's full row of ``v`` is
+        gathered once and lane c weighted by head c // dh's alpha."""
+        obs.add("pallas.attend_calls")
+        obs.add("pallas.attend_kernel_calls")
+        return self.spmm(v, alpha[..., 0] if heads == 1 else alpha, io)
 
 
 # ----------------------------------------------------------------------

@@ -34,13 +34,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.spmm import (LANES, auto_block_n, clamp_id,
-                                edge_specs, gather_rows, lane_block,
-                                pad_lanes, weighted_sum)
+                                edge_specs, edge_weights, gather_rows,
+                                lane_block, pad_lanes, weighted_sum)
 
 
 def _gather_spmm_kernel(nbr_ref, w_ref, table_hbm, h_hbm, o_ref, tbl_ref,
                         idx_ref, rows_ref, sem, *, block_d: int,
-                        fanout: int, block_n: int, n_ids: int, chunk: int):
+                        fanout: int, block_n: int, n_ids: int, chunk: int,
+                        dh: int):
     d0 = pl.program_id(1) * block_d
 
     def gid(r, f):
@@ -68,7 +69,8 @@ def _gather_spmm_kernel(nbr_ref, w_ref, table_hbm, h_hbm, o_ref, tbl_ref,
     jax.lax.fori_loop(0, block_n // chunk, resolve, 0)
     gather_rows(h_hbm, rows_ref, sem, lambda r, f: idx_ref[r, f],
                 block_n=block_n, fanout=fanout, col0=d0, width=block_d)
-    o_ref[...] = weighted_sum(w_ref, rows_ref, fanout=fanout)
+    o_ref[...] = weighted_sum(w_ref, rows_ref, fanout=fanout, col0=d0,
+                              dh=dh)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d",
@@ -81,15 +83,16 @@ def gather_spmm(h, table, w, nbr, mask, *, block_n: int = None,
     from the id space ``nbr`` uses onto h's rows; w/mask/nbr: (R, F).
     Same R/U decoupling as ``spmm`` (row-subset mode), with the id
     translation fused into the gather.  R % block_n == 0; any D, padded
-    to lane tiles as in ``spmm``.  Masked slots may map anywhere in-range
-    (their coefficient is 0.0 exactly).
+    to lane tiles as in ``spmm``, and head-major (heads, R, F) weights
+    as in ``spmm``.  Masked slots may map anywhere in-range (their
+    coefficient is 0.0 exactly).
     """
     U, D = h.shape
     R, F = nbr.shape
     if block_n is None:
         block_n = auto_block_n(R)
     assert R % block_n == 0, (R, block_n)
-    wm = (w * mask).astype(h.dtype).astype(jnp.float32)
+    wm, dh = edge_weights(w, mask, h)
     table = jnp.asarray(table, jnp.int32)
     n_ids = table.shape[0]
     chunk = math.gcd(block_n, 8)
@@ -98,9 +101,10 @@ def gather_spmm(h, table, w, nbr, mask, *, block_n: int = None,
     block_d = lane_block(D, block_d)
     out = pl.pallas_call(
         functools.partial(_gather_spmm_kernel, block_d=block_d, fanout=F,
-                          block_n=block_n, n_ids=n_ids, chunk=chunk),
+                          block_n=block_n, n_ids=n_ids, chunk=chunk,
+                          dh=dh),
         grid=(R // block_n, Dp // block_d),
-        in_specs=edge_specs(block_n, F) + [
+        in_specs=edge_specs(block_n, F, wm.shape[1]) + [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],

@@ -26,10 +26,18 @@ def gemm_ref(h, w):
 
 
 def spmm_ref(h, w, nbr, mask):
-    """out[i] = sum_f w[i,f] * mask[i,f] * h[nbr[i,f]].  h:(N,D) nbr:(N,F)."""
+    """out[i] = sum_f w[i,f] * mask[i,f] * h[nbr[i,f]].  h:(N,D) nbr:(N,F).
+
+    Head-major w (heads, N, F) weights column c by head c // (D // heads):
+    out[i, c] = sum_f w[c // dh, i, f] * mask[i, f] * h[nbr[i, f], c]."""
     vals = jnp.take(h, nbr.reshape(-1), axis=0).astype(jnp.float32)
     vals = vals.reshape(nbr.shape + (h.shape[-1],))
-    coef = (w * mask).astype(jnp.float32)[..., None]
+    coef = (w * mask).astype(jnp.float32)
+    if coef.ndim == 3:
+        coef = jnp.repeat(jnp.moveaxis(coef, 0, -1),
+                          h.shape[-1] // coef.shape[0], axis=-1)
+    else:
+        coef = coef[..., None]
     return (vals * coef).sum(axis=1).astype(h.dtype)
 
 

@@ -12,6 +12,8 @@ bn*F copies in flight on one semaphore before the first wait.  The
 weighted sum then runs on whole (bn, bd) tiles, adding the F neighbor
 slots in order, so every row accumulates in the same per-row order at
 any block size or row position (bitwise-stable across row subsets).
+Head-major weights (heads, R, F) select each lane's head weight, so a
+GAT attend gathers every row once for all heads (``weighted_sum``).
 
 The kernels compute in f32 whatever the table dtype (the cast is exact
 and the output is cast back), so the VMEM tiles keep the f32 (8, 128)
@@ -114,32 +116,71 @@ def column(tile, f: int):
     return jnp.sum(jnp.where(lane == f, tile, 0.0), axis=1, keepdims=True)
 
 
-def weighted_sum(w_ref, rows_ref, *, fanout: int):
-    """sum_f w[:, f] * rows[f], adding slot f = 0, 1, ... in order."""
+def weighted_sum(w_ref, rows_ref, *, fanout: int, col0=0, dh: int = 0):
+    """sum_f w[:, f] * rows[f], adding slot f = 0, 1, ... in order.
+
+    With ``dh`` the (bn, heads * F) tile holds head k's slot-f weight in
+    lane k * F + f (``edge_weights``), and lane c of the block takes head
+    ``(col0 + c) // dh``'s: the weight is *selected* per lane, so every
+    output element sees the same f32 multiply and add as a one-head call
+    over its head's columns.  Lanes past heads * dh (lane padding) get
+    no head and weight 0.0."""
     w = w_ref[...]
-    acc = jnp.zeros(rows_ref.shape[1:], jnp.float32)
+    shape = rows_ref.shape[1:]
+    if not dh:
+        def coef(f):
+            return column(w, f)
+    else:
+        heads = w.shape[1] // fanout
+        lane = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        owns = [(lane >= k * dh) & (lane < (k + 1) * dh)
+                for k in range(heads)]
+
+        def coef(f):
+            tile = jnp.zeros(shape, jnp.float32)
+            for k in range(heads):
+                tile = jnp.where(owns[k], column(w, k * fanout + f), tile)
+            return tile
+    acc = jnp.zeros(shape, jnp.float32)
     for f in range(fanout):
-        acc = acc + column(w, f) * rows_ref[f]
+        acc = acc + coef(f) * rows_ref[f]
     return acc
 
 
 def _spmm_kernel(nbr_ref, w_ref, h_hbm, o_ref, rows_ref, sem, *,
-                 block_d: int, fanout: int, block_n: int):
+                 block_d: int, fanout: int, block_n: int, dh: int):
     d0 = pl.program_id(1) * block_d
     gather_rows(h_hbm, rows_ref, sem, lambda r, f: nbr_ref[r, f],
                 block_n=block_n, fanout=fanout, col0=d0, width=block_d)
-    o_ref[...] = weighted_sum(w_ref, rows_ref, fanout=fanout)
+    o_ref[...] = weighted_sum(w_ref, rows_ref, fanout=fanout, col0=d0,
+                              dh=dh)
 
 
-def edge_specs(block_n: int, fanout: int):
+def edge_specs(block_n: int, fanout: int, w_width: int = None):
     """BlockSpecs of the (R, F) neighbor-id tile (SMEM, scalars that
-    address DMAs) and of a (R, F) per-edge f32 tile (VMEM), for a grid
-    whose first dim walks row blocks."""
+    address DMAs) and of the (R, ``w_width`` or F) per-edge f32 weight
+    tile (VMEM), for a grid whose first dim walks row blocks."""
     def index(i, *_):
         return (i, 0)
     return [pl.BlockSpec((block_n, fanout), index,
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_n, fanout), index)]
+            pl.BlockSpec((block_n, w_width or fanout), index)]
+
+
+def edge_weights(w, mask, h):
+    """(wm, dh): the kernels' f32 per-edge weights w * mask, rounded
+    through the table's dtype, and the head width over ``h``'s columns,
+    0 for (R, F) weights.  Head-major (heads, R, F) weights go in as one
+    (R, heads * F) array, head k's slot f in column k * F + f: as a
+    (heads, R, F) operand every head's F slots would fill a 128-lane
+    tile of HBM."""
+    wm = (w * mask).astype(h.dtype).astype(jnp.float32)
+    if w.ndim == 2:
+        return wm, 0
+    heads, R, F = w.shape
+    D = h.shape[1]
+    assert D % heads == 0, (D, heads)
+    return wm.transpose(1, 0, 2).reshape(R, heads * F), D // heads
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d",
@@ -154,21 +195,26 @@ def spmm(h, w, nbr, mask, *, block_n: int = None, block_d: int = 128,
     R % block_n == 0 (block_n=None picks the largest divisor <=64).  Any
     D: columns run padded to whole lane tiles in ``lane_block`` column
     blocks, and the output is sliced back to D.
+
+    Head-major weights w: (heads, R, F) give every head its own edge
+    weights over its dh = D // heads columns in one call:
+    out[i, c] = sum_f w[c // dh, i, f]*mask[i,f]*h[nbr[i,f], c], bitwise
+    the concatenation of per-head calls, with each row gathered once.
     """
     N, D = h.shape
     R, F = nbr.shape
     if block_n is None:
         block_n = auto_block_n(R)
     assert R % block_n == 0, (R, block_n)
-    wm = (w * mask).astype(h.dtype).astype(jnp.float32)
+    wm, dh = edge_weights(w, mask, h)
     hp = pad_lanes(h.astype(jnp.float32))
     Dp = hp.shape[1]
     block_d = lane_block(D, block_d)
     out = pl.pallas_call(
         functools.partial(_spmm_kernel, block_d=block_d, fanout=F,
-                          block_n=block_n),
+                          block_n=block_n, dh=dh),
         grid=(R // block_n, Dp // block_d),
-        in_specs=edge_specs(block_n, F) + [
+        in_specs=edge_specs(block_n, F, wm.shape[1]) + [
             pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, Dp), jnp.float32),

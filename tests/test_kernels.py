@@ -131,6 +131,58 @@ def test_gather_spmm_quantized_strict(rng):
 
 
 # ----------------------------------------------------------------------
+# head-major weights: one call for every head of a GAT attend
+# ----------------------------------------------------------------------
+
+def _head_major_case(rng, heads, D, dtype, R=24, U=37, F=4):
+    h = jnp.asarray(rng.standard_normal((U, D)), dtype)
+    table = jnp.asarray(rng.permutation(U), jnp.int32)
+    w = jnp.asarray(rng.random((heads, R, F)).astype(np.float32))
+    nbr = jnp.asarray(rng.integers(0, U, (R, F)), jnp.int32)
+    mask = jnp.asarray(rng.random((R, F)) > 0.25)
+    return h, table, w, nbr, mask
+
+
+def _head_major_call(kernel, table, nbr, mask):
+    if kernel == "spmm":
+        return lambda h, w: spmm(h, w, nbr, mask, interpret=True)
+    return lambda h, w: gather_spmm(h, table, w, nbr, mask, interpret=True)
+
+
+@pytest.mark.parametrize("kernel", ["spmm", "gather_spmm"])
+@pytest.mark.parametrize("heads", [1, 2, 4])
+@pytest.mark.parametrize("D", [128, 100, 256])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_head_major_bitwise_vs_per_head(kernel, heads, D, dtype, rng):
+    """(heads, R, F) weights in one call == the concatenation of per-head
+    (R, F) calls over each head's columns, BITWISE.  R=24 is no multiple
+    of the 64-row block (the kernel takes 8); D=100 is one padded lane
+    tile (25-wide heads), D=256 two column blocks."""
+    h, table, w, nbr, mask = _head_major_case(rng, heads, D, dtype)
+    call = _head_major_call(kernel, table, nbr, mask)
+    dh = D // heads
+    per_head = jnp.concatenate(
+        [call(h[:, k * dh:(k + 1) * dh], w[k]) for k in range(heads)],
+        axis=-1)
+    np.testing.assert_array_equal(np.asarray(call(h, w)),
+                                  np.asarray(per_head))
+
+
+@pytest.mark.parametrize("kernel", ["spmm", "gather_spmm"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_head_major_matches_ref(kernel, dtype, rng):
+    """Head-major weights against the oracle's, at the sweep tolerances."""
+    F = 4
+    h, table, w, nbr, mask = _head_major_case(rng, 4, 100, dtype, F=F)
+    got = _head_major_call(kernel, table, nbr, mask)(h, w)
+    want = (ref.gather_spmm_ref(h, table, w, nbr, mask)
+            if kernel == "gather_spmm" else ref.spmm_ref(h, w, nbr, mask))
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=ATOL[dtype] * F, rtol=3e-2)
+
+
+# ----------------------------------------------------------------------
 # fused SDDMM + masked softmax (GAT attention)
 # ----------------------------------------------------------------------
 
@@ -236,6 +288,56 @@ def test_executor_fused_attention_layer(N, D, heads, rng):
     want = np.asarray(run_layer(RefExecutor(), layer, io, H, H, heads))
     np.testing.assert_allclose(got, unfused, atol=1e-6, rtol=1e-6)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=3e-3)
+
+
+def _per_head_attend(ex, alpha, v, io, heads):
+    """The per-head attend this repo ran before the fused call: one
+    kernel call per head over its column slice, then a concatenate."""
+    dh = v.shape[-1] // heads
+    fused = ex.fused_gather and io.table is not None
+    nbr = io.nbr if fused else io.nbr_resolved
+    table = io.table if fused else None
+    return jnp.concatenate(
+        [ex._spmm_kernel(v[:, k * dh:(k + 1) * dh], alpha[..., k], nbr,
+                         io.mask, table=table) for k in range(heads)],
+        axis=-1)
+
+
+@pytest.mark.parametrize("table", [False, True])
+def test_executor_attend_bitwise_vs_per_head(table, rng):
+    """PallasExecutor.attend (one call, head-major weights) == the
+    per-head loop, bitwise, on R not a block multiple and D=100, with
+    and without the fused ``DenseIO.table`` gather."""
+    from repro.core.ops import PallasExecutor
+    R, U, D, F, heads = 50, 61, 100, 6, 4
+    io = _dense_io(rng, R, U, F, table=table)
+    v = jnp.asarray(rng.standard_normal((U, D)).astype(np.float32))
+    alpha = jnp.asarray(rng.random((R, F, heads)).astype(np.float32))
+    ex = PallasExecutor(use_kernel=True)
+    got = np.asarray(ex.attend(alpha, v, io, heads))
+    assert got.shape == (R, D)
+    np.testing.assert_array_equal(
+        got, np.asarray(_per_head_attend(ex, alpha, v, io, heads)))
+
+
+def test_executor_attend_one_kernel_call(rng):
+    """Over a 4-head GAT forward every attend launches one kernel."""
+    import jax
+
+    from repro import obs
+    from repro.core.gnn_models import init_gat, model_spec
+    from repro.core.ops import PallasExecutor, run_model
+    N, D, F = 40, 32, 4
+    spec = model_spec("gat", init_gat(jax.random.PRNGKey(0), [D, D, D],
+                                      heads=4))
+    ios = [_dense_io(rng, N, N, F, table=False) for _ in spec.layers]
+    H = jnp.asarray(rng.standard_normal((N, D)).astype(np.float32))
+    tel = obs.Telemetry(enabled=True)
+    with obs.use(tel):
+        run_model(PallasExecutor(use_kernel=True), spec, ios, H)
+    calls = tel.metrics.counter("pallas.attend_calls").value
+    assert calls == len(spec.layers) == 2
+    assert tel.metrics.counter("pallas.attend_kernel_calls").value == calls
 
 
 # ----------------------------------------------------------------------

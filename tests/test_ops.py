@@ -92,9 +92,11 @@ def test_spec_single_definition(model):
                              "edge_softmax", "attend"]}[model]
 
 
-def test_delta_refresh_pallas_bitwise(world):
+@pytest.mark.parametrize("model", ["gcn", "gat"])
+def test_delta_refresh_pallas_bitwise(world, model):
     """Delta refresh through the pallas executor == full epoch through
-    the pallas executor, bitwise (mirrors the ref-executor guarantee)."""
+    the pallas executor, bitwise (mirrors the ref-executor guarantee);
+    GAT's attend takes the fused head-major ``gather_spmm`` route."""
     from repro.gnnserve import (DeltaReinference, MutationLog,
                                 apply_edge_mutations, store_from_inference)
     src, dst = rmat_edges(128, 128 * 8, seed=5)
@@ -102,8 +104,9 @@ def test_delta_refresh_pallas_bitwise(world):
     lgs = sample_layer_graphs(g, fanout=4, n_layers=2, seed=2)
     rng = np.random.default_rng(0)
     X = rng.standard_normal((128, 32)).astype(np.float32)
-    params = init_gcn(jax.random.PRNGKey(1), [32, 32, 32])
-    ri = DeltaReinference([copy.deepcopy(l) for l in lgs], "gcn", params,
+    params = {"gcn": init_gcn, "gat": init_gat}[model](
+        jax.random.PRNGKey(1), [32, 32, 32])
+    ri = DeltaReinference([copy.deepcopy(l) for l in lgs], model, params,
                           executor="pallas")
     levels = ri.full_levels(X)
     store = store_from_inference(X, levels[1:], n_shards=4)
@@ -113,7 +116,7 @@ def test_delta_refresh_pallas_bitwise(world):
     g2 = apply_edge_mutations(g, batch)
     ri.refresh(store, g2, batch.feat_ids, batch.feat_rows,
                batch.affected_dsts())
-    oracle = DeltaReinference(ri.layer_graphs, "gcn", params,
+    oracle = DeltaReinference(ri.layer_graphs, model, params,
                               executor="pallas").full_levels(X)
     for lvl in range(1, 3):
         np.testing.assert_array_equal(store.lookup(np.arange(128), lvl),

@@ -62,6 +62,7 @@ def _compile(fn, shapes, sharding):
 W = ((N, F), jnp.float32)           # per-edge weights
 IDS = ((N, F), jnp.int32)           # neighbor ids
 MASK = ((N, F), jnp.bool_)
+HEAD_W = ((HEADS, N, F), jnp.float32)   # head-major per-edge weights
 
 
 @pytest.mark.parametrize("D,dtype", [(128, jnp.float32), (100, jnp.float32),
@@ -84,6 +85,25 @@ def test_gather_spmm_compiles(D, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("D", [128, 100])
+def test_spmm_head_major_compiles(D, one_chip, no_persistent_cache):
+    def fn(h, w, nbr, mask):
+        return spmm(h, w, nbr, mask, block_n=64, block_d=128,
+                    interpret=False)
+    c = _compile(fn, [((N, D), jnp.float32), HEAD_W, IDS, MASK], one_chip)
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("D", [128, 100])
+def test_gather_spmm_head_major_compiles(D, one_chip, no_persistent_cache):
+    def fn(h, table, w, nbr, mask):
+        return gather_spmm(h, table, w, nbr, mask, block_n=64,
+                           block_d=128, interpret=False)
+    c = _compile(fn, [((N, D), jnp.float32), ((N,), jnp.int32), HEAD_W,
+                      IDS, MASK], one_chip)
+    assert "tpu_custom_call" in c.as_text()
+
+
 @pytest.mark.parametrize("D", [128, 128 // HEADS])
 def test_sddmm_compiles(D, one_chip, no_persistent_cache):
     fn = functools.partial(sddmm, block_n=64, interpret=False)
@@ -103,13 +123,16 @@ def test_gat_attention_compiles(one_chip, no_persistent_cache):
 # The names a device trace shows for each kernel: the jitted wrapper's
 # module (``jit_<name>``) and the Pallas custom call inside it (``<name>``,
 # set by ``pallas_call(name=...)``).  The benchmark's roofline metrics
-# match on these, so a rename must be deliberate.
+# match on these, so a rename must be deliberate.  A case ``<name>.<how>``
+# pins ``<name>`` for another call of the same kernel.
 PINNED = {
     "spmm": (spmm, [((N, 128), jnp.float32), W, IDS, MASK],
              dict(block_n=64, block_d=128)),
     "gather_spmm": (gather_spmm, [((N, 128), jnp.float32),
                                   ((N,), jnp.int32), W, IDS, MASK],
                     dict(block_n=64, block_d=128)),
+    "spmm.head_major": (spmm, [((N, 128), jnp.float32), HEAD_W, IDS, MASK],
+                        dict(block_n=64, block_d=128)),
     "sddmm": (sddmm, [((N, 128), jnp.float32), ((N, 128), jnp.float32),
                       IDS, MASK], dict(block_n=64)),
     "gat_attention": (gat_attention, [((N, 128), jnp.float32),
@@ -118,9 +141,10 @@ PINNED = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(PINNED))
-def test_kernel_names_are_pinned(name, one_chip, no_persistent_cache):
-    kernel, shapes, kw = PINNED[name]
+@pytest.mark.parametrize("case", sorted(PINNED))
+def test_kernel_names_are_pinned(case, one_chip, no_persistent_cache):
+    kernel, shapes, kw = PINNED[case]
+    name = case.split(".")[0]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = kernel.lower(*args, interpret=False, **kw).compile().as_text()
     assert re.search(rf"^HloModule jit_{name}\b", text, re.M)
