@@ -10,12 +10,18 @@
                   with ``UNIT`` and ``Job(session, traffic, seed, spans)``
                   that has ``warm(compiles)``, ``step()``, ``counters``
                   and ``outputs()`` (what the comparison needs)
+  model           ``models/<name>.py``, by the configuration's
+                  ``deal.model.name``: the inputs drawn from the seed
+                  (``make_inputs``), the reference's layer equations
+                  (``GATHERED``, ``layer``, ``operands``, ``block``,
+                  ``activation``; see ``reference.py``) and the work of
+                  one epoch (``epoch_calls``, ``epoch_min_bytes``)
   metric          ``metrics/<name>.py``: a reader with ``UNIT``,
                   ``LAYER``, ``MOVES`` and ``read(run)``, which returns
                   the value or None where it finds nothing to read
 
-A new cell, configuration, mix, job kind or metric is a new file and a
-new entry; nothing here changes.
+A new cell, configuration, mix, job kind, model or metric is a new file
+and a new entry; nothing here changes.
 """
 from __future__ import annotations
 
@@ -93,6 +99,16 @@ def load_job(cell: Cell):
         raise ValueError(f"traffic {cell.traffic_name!r} names job kind "
                          f"{kind!r}, and there is no {path}")
     return _load_module(path, "job_" + kind)
+
+
+def load_model(cell: Cell):
+    """The model module the cell's configuration names."""
+    name = cell.config["deal"]["model"]["name"]
+    path = cell.bench_dir / "models" / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"config {cell.config_name!r} names model "
+                         f"{name!r}, and there is no {path}")
+    return _load_module(path, "model_" + name)
 
 
 def load_reader(metric: Dict, root: Path = ROOT):

@@ -1,6 +1,6 @@
 """The epoch's share of the chip's bf16 peak: the algorithmic FLOPs of
-one epoch (``work.epoch_calls``, logical shapes) over the run's seconds
-per epoch, over the peak."""
+one epoch (the model file's ``epoch_calls``, logical shapes) over the
+run's seconds per epoch, over the peak."""
 import work
 
 UNIT = "%"

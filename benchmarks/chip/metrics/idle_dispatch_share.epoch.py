@@ -8,8 +8,9 @@ The trace reduction labels an idle gap ``<harness span>/<innermost host
 event>``, so a runtime event inside a program span hides the span.  A
 runtime event counts under the one phase that issues it on the
 single-chip Pallas path: here the jitted calls (``PjitFunction(*)``,
-``ParseArguments``, ``PJRT_LoadedExecutable_Execute*``) and the plain
-``DevicePut`` that GAT's per-head attend issues.  None where the trace
+``ParseArguments``, ``PJRT_LoadedExecutable_Execute*``) and a plain
+``DevicePut``, which comes from an op's dispatch (the staged uploads go
+through ``shard_args``, counted as staging).  None where the trace
 holds none of the program's spans (a program that does not write
 them)."""
 UNIT = "%"

@@ -9,8 +9,9 @@ event>``, so a runtime event inside a program span hides the span.  A
 runtime event counts under the one phase that issues it on the
 single-chip Pallas path: here the array uploads (``shard_args`` and the
 ``DevicePutWithSharding`` inside it).  The plain ``DevicePut`` is not
-among them: GAT's attend issues it.  None where the trace holds none of
-the program's spans (a program that does not write them)."""
+among them: it comes from an op's dispatch.  None where the trace
+holds none of the program's spans (a program that does not write
+them)."""
 UNIT = "%"
 LAYER = "Epoch host path: api/session.py infer_all"
 MOVES = "epoch_s"

@@ -1,20 +1,24 @@
 """The plain reference the benchmark holds the program to.
 
-Everything here is written from the model's equations and imports
-nothing of the program: the inputs (features, weights) come from
-``make_inputs``, drawn from the run's seed in one jitted call on the
-device; the sampled layer graphs are the run's input data, read from the
-program but first checked edge by edge against the edge list
+Everything here and under ``models/`` is written from the models'
+equations and imports nothing of the program: the inputs (features,
+weights) come from the model file's ``make_inputs``, drawn from the
+run's seed through ``draw`` in one jitted call on the device; the
+sampled layer graphs are the run's input data, read from the program
+but first checked edge by edge against the edge list
 (``graph_violations``).
 
-Models (the paper's 3-layer GCN and dot-product GAT; rows head-major):
+A model is the file ``models/<name>.py`` (``bench.load_model``), which
+gives its equations to ``forward`` here:
 
-  gcn   h' = sum_f m[i,f] / max(sum_f m[i,f], 1) * (h W)[nbr[i,f]],
-        relu between layers
-  gat   q, k, v = h Wq, h Wk, h Wv;
-        a[i,f,h] = softmax_f over masked-in slots of
-                   <q_h[i], k_h[nbr[i,f]]> / sqrt(d / heads);
-        h'_h[i] = sum_f a[i,f,h] v_h[nbr[i,f]], elu between layers
+  GATHERED    one flag per operand: True where a block reads the
+              operand's rows at its neighbour ids, False where it reads
+              its own rows
+  layer(params, l)                 the weights one layer reads
+  operands(h, p, matmul)           a layer's operands from rows of H
+  block(p, *operand_blocks, mask, matmul)
+                                   a block of output rows
+  activation(x)                    between layers, not after the last
 
 The matmuls run in float32 at HIGHEST precision, as the configurations
 state.  ``matmul="bf16x3"`` is the control: the same reference with
@@ -25,6 +29,7 @@ and sums are elementwise float32 in both.
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Sequence, Tuple
 
 import jax
@@ -32,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 BLOCK_ROWS = 65536
+GATHER_THREADS = 4
 
 
 # ----------------------------------------------------------------------
@@ -45,34 +51,28 @@ def seed_key(seed: int) -> jax.Array:
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-@functools.partial(jax.jit, static_argnames=("model", "n", "d", "layers"))
-def _make(key, *, model: str, n: int, d: int, layers: int):
+@functools.partial(jax.jit, static_argnames=("n", "d", "layers", "per"))
+def _draw(key, *, n: int, d: int, layers: int, per: int):
     kx, kw = jax.random.split(key)
     X = jax.random.normal(kx, (n, d), jnp.float32)
-    per = {"gcn": 1, "gat": 3}[model]
     W = jax.random.normal(kw, (layers, per, d, d), jnp.float32) * d ** -0.5
     return X, W
 
 
-def make_inputs(seed: int, model: str, n: int, d: int, layers: int,
-                heads: int) -> Tuple[np.ndarray, Dict]:
-    """Features (host, as the program keeps them) and weights (device,
-    in the program's parameter layout), all from ``seed``."""
-    X, W = _make(seed_key(seed), model=model, n=n, d=d, layers=layers)
-    if model == "gcn":
-        params = {"w": [W[l, 0] for l in range(layers)]}
-    else:
-        params = {"layers": [{"wq": W[l, 0], "wk": W[l, 1], "wv": W[l, 2]}
-                             for l in range(layers)],
-                  "heads": heads}
-    return np.asarray(X), params
+def draw(seed: int, *, n: int, d: int, layers: int, per: int
+         ) -> Tuple[np.ndarray, jax.Array]:
+    """Features (n, d) on the host and ``per`` (d, d) weights for each
+    of ``layers`` layers, W[l, i], on the device, all from ``seed`` in
+    one jitted call."""
+    X, W = _draw(seed_key(seed), n=n, d=d, layers=layers, per=per)
+    return np.asarray(X), W
 
 
 # ----------------------------------------------------------------------
 # the forward pass
 # ----------------------------------------------------------------------
 
-def _dot(a, b, matmul: str):
+def dot(a, b, matmul: str):
     if matmul == "highest":
         return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
@@ -90,35 +90,6 @@ def _dot(a, b, matmul: str):
     return d(a1, b1) + (d(a1, b2) + d(a2, b1))
 
 
-def _elu(x):
-    return jnp.where(x > 0, x, jnp.expm1(jnp.minimum(x, 0.0)))
-
-
-@functools.partial(jax.jit, static_argnames=("heads",))
-def _gat_block(q, k, v, nbr, mask, *, heads: int):
-    B, F = nbr.shape
-    D = q.shape[1]
-    dh = D // heads
-    kn = k[nbr].reshape(B, F, heads, dh)
-    vn = v[nbr].reshape(B, F, heads, dh)
-    s = (q.reshape(B, 1, heads, dh) * kn).sum(-1) / jnp.sqrt(
-        jnp.float32(dh))                                      # (B, F, h)
-    m = mask[:, :, None]
-    s = jnp.where(m, s, -jnp.inf)
-    smax = jnp.max(s, axis=1, keepdims=True)
-    e = jnp.where(m, jnp.exp(s - jnp.where(jnp.isfinite(smax), smax, 0.0)),
-                  0.0)
-    a = e / jnp.maximum(e.sum(axis=1, keepdims=True), 1e-30)
-    return (a[..., None] * vn).sum(axis=1).reshape(B, D)
-
-
-@jax.jit
-def _gcn_block(hw, nbr, mask):
-    m = mask.astype(jnp.float32)
-    w = m / jnp.maximum(m.sum(axis=1, keepdims=True), 1.0)
-    return (w[..., None] * hw[nbr]).sum(axis=1)
-
-
 def _blocks(n: int, block: int):
     for lo in range(0, n, block):
         yield lo, min(lo + block, n)
@@ -132,40 +103,80 @@ def _pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
     return out
 
 
-def forward(model: str, params: Dict, X: np.ndarray,
+def _by_rows(f, H: np.ndarray, block: int) -> List[np.ndarray]:
+    """``f`` (rows -> a tuple of row-wise results) over H on the host,
+    one block of rows at a time on the device."""
+    n = H.shape[0]
+    outs = None
+    for lo, hi in _blocks(n, block):
+        res = f(jnp.asarray(_pad_rows(H[lo:hi], block)))
+        if outs is None:
+            outs = [np.empty((n,) + r.shape[1:], np.float32) for r in res]
+        for o, r in zip(outs, res):
+            o[lo:hi] = np.asarray(r)[:hi - lo]
+    return outs
+
+
+def _gather(pool: ThreadPoolExecutor, a: np.ndarray, ids: np.ndarray
+            ) -> np.ndarray:
+    """a[ids] on the host, its rows split over the pool's threads."""
+    out = np.empty(ids.shape + a.shape[1:], a.dtype)
+    step = -(-ids.shape[0] // GATHER_THREADS)
+
+    def part(lo):
+        out[lo:lo + step] = a[ids[lo:lo + step]]
+    for f in [pool.submit(part, lo) for lo in range(0, ids.shape[0], step)]:
+        f.result()
+    return out
+
+
+def forward(model, params: Dict, X: np.ndarray,
             graphs: Sequence[Tuple[np.ndarray, np.ndarray]], *,
             matmul: str = "highest", block: int = BLOCK_ROWS
             ) -> List[np.ndarray]:
-    """Every level of an all-node epoch: [X, h1, ..., hL] on the host.
-    Rows are computed in blocks of ``block`` (padded to one shape, so
-    each layer compiles once) to bound the device memory."""
+    """Every level of an all-node epoch of ``model`` (a model file):
+    [X, h1, ..., hL] on the host.
+
+    The device holds a few blocks of ``block`` rows, never a whole
+    level: each layer's operands are computed block by block into host
+    arrays, and each block of target rows gets its neighbours' operand
+    rows gathered on the host before it goes to the device.  The next
+    block's rows are gathered while the device works on this one.
+    Blocks are padded to one shape, so each layer compiles once."""
     n = X.shape[0]
     block = min(block, -(-n // 8) * 8)
     levels = [np.asarray(X, np.float32)]
-    H = jnp.asarray(levels[0])
     L = len(graphs)
-    for l, (nbr, mask) in enumerate(graphs):
-        out = np.empty((n, H.shape[1]), np.float32)
-        if model == "gcn":
-            hw = _dot(H, params["w"][l], matmul)
-        else:
-            p = params["layers"][l]
-            q, k, v = (_dot(H, p[w], matmul) for w in ("wq", "wk", "wv"))
-        for lo, hi in _blocks(n, block):
-            nb = jnp.asarray(_pad_rows(nbr[lo:hi], block))
-            mb = jnp.asarray(_pad_rows(mask[lo:hi], block))
-            if model == "gcn":
-                o = _gcn_block(hw, nb, mb)
-            else:
-                qb = jnp.pad(q[lo:hi], ((0, block - (hi - lo)), (0, 0)))
-                o = _gat_block(qb, k, v, nb, mb,
-                               heads=int(params["heads"]))
-            out[lo:hi] = np.asarray(o)[:hi - lo]
-        if l < L - 1:
-            out = np.asarray((jax.nn.relu if model == "gcn" else _elu)(
-                jnp.asarray(out)))
-        levels.append(out)
-        H = jnp.asarray(out)
+    with ThreadPoolExecutor(1) as ahead, \
+            ThreadPoolExecutor(GATHER_THREADS) as pool:
+        for l, (nbr, mask) in enumerate(graphs):
+            p = model.layer(params, l)
+            ops = _by_rows(lambda h: model.operands(h, p, matmul),
+                           levels[-1], block)
+
+            def rows(lo, hi):
+                """A block's operand rows and mask, on the host."""
+                nb = _pad_rows(nbr[lo:hi], block)
+                return ([_gather(pool, a, nb) if gathered
+                         else _pad_rows(a[lo:hi], block)
+                         for a, gathered in zip(ops, model.GATHERED)],
+                        _pad_rows(mask[lo:hi], block))
+            spans = list(_blocks(n, block))
+            out = None
+            nxt = ahead.submit(rows, *spans[0])
+            for i, (lo, hi) in enumerate(spans):
+                args, mb = nxt.result()
+                if i + 1 < len(spans):
+                    nxt = ahead.submit(rows, *spans[i + 1])
+                o = model.block(p, *map(jnp.asarray, args), jnp.asarray(mb),
+                                matmul)
+                if l < L - 1:
+                    o = model.activation(o)
+                if out is None:
+                    out = np.empty((n, o.shape[1]), np.float32)
+                out[lo:hi] = np.asarray(o)[:hi - lo]
+            del ops
+            levels.append(out)
     return levels
 
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import json
 import os
 import shutil
@@ -95,6 +96,8 @@ class Run:
 
     def __init__(self, cell: bench.Cell, device: Dict):
         self.device = device
+        self.model = bench.load_model(cell)      # the model file
+        self.model_cfg: Dict = {}                # DealConfig.model
         self.unit: str = ""
         self.units = 0
         self.unit_s: Optional[float] = None
@@ -105,7 +108,6 @@ class Run:
         self.red: Optional[Dict] = None          # trace reduction
         self.calls = None                        # work per epoch
         self.graphs = None                       # GraphShape per layer
-        self.model = cell.config["deal"]["model"]
 
     @property
     def peaks(self) -> Optional[Dict]:
@@ -126,6 +128,12 @@ def device_info() -> Dict:
     devs = jax.devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}
+
+
+def _max_rss_bytes() -> int:
+    """This process's peak resident memory on the host (Linux)."""
+    import resource
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
 def memory_peak_bytes(chips: int) -> int:
@@ -161,14 +169,15 @@ def _compare(got: Dict, want, viol: int, limits: Dict) -> Dict[str, Dict]:
     return {k: {"value": v, "limit": limits[k]} for k, v in res.items()}
 
 
-def check(out: Dict, model: str, params, limits: Dict,
+def check(out: Dict, model, params, limits: Dict,
           control: bool = False) -> Tuple[Dict, Optional[Dict]]:
     """Compare what the window produced (a job's ``outputs()``: the
     program's rows of some levels of every node, the layer graphs, the
-    features and the edge list) with the plain reference, each number
-    beside its limit.  With ``control`` the reference itself, its
-    matmuls one precision step lower, stands in the program's place: the
-    first result is then the control's, the second the program's."""
+    features and the edge list) with the plain reference of ``model``
+    (a model file), each number beside its limit.  With ``control`` the
+    reference itself, its matmuls one precision step lower, stands in
+    the program's place: the first result is then the control's, the
+    second the program's."""
     import reference as ref
     viol = sum(ref.graph_violations(nbr, mask, out["src"], out["dst"])
                for nbr, mask in out["graphs"])
@@ -189,7 +198,6 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
     program's place (``control.py``)."""
     import jax
 
-    import reference as ref
     import tracereduce
     import work
     from repro.runtime import enable_compile_cache
@@ -202,8 +210,8 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
     s = build_session(cell, seed)
     r.timings = dict(s.timings)
     m = s.cfg.model
-    X, params = ref.make_inputs(seed, m.name, s.n_nodes, m.d_feature,
-                                m.n_layers, m.heads)
+    r.model_cfg = dataclasses.asdict(m)
+    X, params = r.model.make_inputs(seed, s.n_nodes, r.model_cfg)
     s.X, s.params = X, params
     log(f"[setup] {cell.config_name}: n_nodes={s.n_nodes} "
         f"n_edges={s.graph.n_edges} model={m.name} heads={m.heads} "
@@ -216,7 +224,7 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
     for note in job.warm(clock):
         log(f"[warm] {note}")
     r.graphs = [work.GraphShape.of(lg.nbr, lg.mask) for lg in s.layer_graphs]
-    r.calls = work.epoch_calls(m.name, r.graphs, m.d_feature, m.heads)
+    r.calls = r.model.epoch_calls(r.graphs, r.model_cfg)
     r.compile_setup_s = clock.secs
     compiles0, compile_s0 = clock.count, clock.secs
 
@@ -276,7 +284,10 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
     out = job.outputs()
     del job
     s.close()
-    checks, program = check(out, m.name, params, cell.limits, control)
+    t_ref = time.perf_counter()
+    checks, program = check(out, r.model, params, cell.limits, control)
+    log(f"[reference] {time.perf_counter() - t_ref:.3f} s after the "
+        f"window; process peak RSS {_max_rss_bytes()} B")
     if program is not None:
         result["program_checks"] = program
         for name, c in program.items():

@@ -1,6 +1,9 @@
 """The comparison that decides ``correct`` fails what it must (run by
 path, on the CPU: ``python -m pytest -q benchmarks/chip/test_faults.py``).
 
+Each case runs for every model file under ``models/``, in the first
+cell whose configuration names it.
+
 * The control: a whole run with the reference, its matmuls one
   precision step below the configuration's (bf16x3 for float32 at
   HIGHEST), in the program's place comes out not correct through the
@@ -12,6 +15,7 @@ path, on the CPU: ``python -m pytest -q benchmarks/chip/test_faults.py``).
 """
 from __future__ import annotations
 
+import json
 import sys
 from pathlib import Path
 
@@ -24,12 +28,23 @@ sys.path.insert(0, str(HERE))
 import bench  # noqa: E402
 import control  # noqa: E402
 import run as runmod  # noqa: E402
-from test_rehearsal import SPEC, tiny_copy  # noqa: E402
+from test_rehearsal import ROOT, SPEC, tiny_copy  # noqa: E402
 
 SEED = 2**31 + 977
-CELLS = [w["name"] for w in SPEC["workloads"]]
-EPOCH = [w["name"] for w in SPEC["workloads"]
-         if w["traffic"].startswith("epoch")]
+MODELS = sorted(p.stem for p in (HERE / "models").glob("*.py"))
+
+
+def _cell_of(model: str, traffic: str = "") -> str:
+    """The first cell whose configuration runs ``model`` and whose mix's
+    name starts with ``traffic``."""
+    for w in SPEC["workloads"]:
+        c = next(c for c in SPEC["configs"] if c["name"] == w["config"])
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        if (cfg["deal"]["model"]["name"] == model
+                and w["traffic"].startswith(traffic)):
+            return w["name"]
+    raise LookupError(f"no cell of BENCHMARK.json runs model {model!r} "
+                      f"under a mix {traffic}*")
 
 
 @pytest.fixture(scope="module")
@@ -42,18 +57,19 @@ def run_tiny(root, name):
     return runmod.run(cell, SEED, 0.2, False, root=root)
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_control_fails_the_limit(tiny, name):
-    res = control.control_run(bench.load_cell(name, tiny), SEED, root=tiny)
+@pytest.mark.parametrize("model", MODELS)
+def test_control_fails_the_limit(tiny, model):
+    res = control.control_run(bench.load_cell(_cell_of(model), tiny), SEED,
+                              root=tiny)
     assert not res["correct"]
     c = res["checks"]["max_rel_err"]
     assert c["value"] > c["limit"]
     assert res["checks"]["graph_violations"]["value"] == 0
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_sound_run_passes(tiny, name):
-    assert run_tiny(tiny, name)["correct"]
+@pytest.mark.parametrize("model", MODELS)
+def test_sound_run_passes(tiny, model):
+    assert run_tiny(tiny, _cell_of(model))["correct"]
 
 
 def _alter_first_row(out):
@@ -62,19 +78,19 @@ def _alter_first_row(out):
     return out
 
 
-@pytest.mark.parametrize("name", EPOCH)
-def test_epoch_answer_altered(tiny, name, monkeypatch):
+@pytest.mark.parametrize("model", MODELS)
+def test_epoch_answer_altered(tiny, model, monkeypatch):
     import repro.core.ops as ops
     real = ops.run_model
     monkeypatch.setattr(ops, "run_model",
                         lambda *a, **k: _alter_first_row(real(*a, **k)))
-    res = run_tiny(tiny, name)
+    res = run_tiny(tiny, _cell_of(model, "epoch"))
     assert not res["correct"]
     assert res["checks"]["max_rel_err"]["value"] > 1e-4
 
 
-@pytest.mark.parametrize("name", EPOCH)
-def test_epoch_half_the_rows_left_out(tiny, name, monkeypatch):
+@pytest.mark.parametrize("model", MODELS)
+def test_epoch_half_the_rows_left_out(tiny, model, monkeypatch):
     import repro.core.ops as ops
     real = ops.run_model
 
@@ -83,4 +99,4 @@ def test_epoch_half_the_rows_left_out(tiny, name, monkeypatch):
         H[H.shape[0] // 2:] = 0.0
         return H
     monkeypatch.setattr(ops, "run_model", half)
-    assert not run_tiny(tiny, name)["correct"]
+    assert not run_tiny(tiny, _cell_of(model, "epoch"))["correct"]

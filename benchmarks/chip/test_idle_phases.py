@@ -90,7 +90,7 @@ def test_hand_trace_shares():
     # 10 + 60 + 10 + 10 (shard_args / DevicePutWithSharding inside it);
     # mean_w 45
     # dispatch: forward 10 + 10; gemm 5 + 15 + 20; spmm 5 + 2 + 6
-    # (a plain DevicePut, as GAT's attend issues) + 2 + 20 + 10
+    # (a plain DevicePut, from an op's dispatch) + 2 + 20 + 10
     # fetch: fetch 5 + 15 + 95 + 5 (np.asarray inside it); check 60
     assert got == pytest.approx({"staging": 24.5, "dispatch": 10.5,
                                  "fetch": 18.0})

@@ -6,9 +6,9 @@ run it by path):
 Each cell of ``BENCHMARK.json`` runs at a tiny scale through the
 harness's own functions (Pallas in interpret mode), with and without a
 trace; ``run.py`` itself refuses without a TPU; and a configuration, a
-job kind, a traffic mix and a metric added as new files plus new
-entries, in a copy of the benchmark, are found by name with no edit to
-any existing file.
+job kind, a traffic mix, a metric and a model added as new files plus
+new entries, in a copy of the benchmark, are found by name with no edit
+to any existing file.
 """
 from __future__ import annotations
 
@@ -175,3 +175,100 @@ def test_new_files_are_found_by_name(tmp_path):
     changed = [p for p, b in before.items()
                if p.name != "BENCHMARK.json" and p.read_bytes() != b]
     assert changed == []
+
+
+SAGE = '''"""GraphSAGE with mean aggregation, as the program registers it:
+
+  agg[i] = sum_f m[i,f] / max(sum_f m[i,f], 1) * h[nbr[i,f]]
+  h'[i] = h[i] W_self + agg[i] W_nbr, relu between layers
+"""
+import jax
+import jax.numpy as jnp
+
+import reference as ref
+import work
+
+GATHERED = (False, True)        # h W_self at own rows, h at neighbour ids
+
+
+def make_inputs(seed, n, model):
+    layers = model["n_layers"]
+    X, W = ref.draw(seed, n=n, d=model["d_feature"], layers=layers, per=2)
+    return X, {"layers": [{"w_self": W[l, 0], "w_nbr": W[l, 1]}
+                          for l in range(layers)]}
+
+
+def layer(params, l):
+    return params["layers"][l]
+
+
+def operands(h, p, matmul):
+    return ref.dot(h, p["w_self"], matmul), h
+
+
+def block(p, own, hn, mask, matmul):
+    m = mask.astype(jnp.float32)
+    w = m / jnp.maximum(m.sum(axis=1, keepdims=True), 1.0)
+    agg = (w[..., None] * hn).sum(axis=1)
+    return own + ref.dot(agg, p["w_nbr"], matmul)
+
+
+activation = jax.nn.relu
+
+
+def epoch_calls(graphs, model):
+    d = model["d_feature"]
+    calls = []
+    for g in graphs:
+        calls += [("spmm", work.spmm(g, d))]
+        calls += [("gemm", work.gemm(g.n, d, d))] * 2
+    return calls
+
+
+def epoch_min_bytes(graphs, model):
+    return work.fused_epoch_bytes(graphs, model["d_feature"], weights=2)
+'''
+
+
+def test_new_model_is_a_file(tmp_path):
+    """A model (the program's registered ``sage``) as a new model file,
+    with a new configuration and cell; no existing file is edited.  The
+    cell runs correct, its control does not."""
+    import control
+    root = tiny_copy(tmp_path)
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    bdir = root / SPEC["paths"][0]
+    (bdir / "models" / "sage.py").write_text(SAGE)
+    cfg = json.loads((bdir / "configs" / "gcn-papers100m.json").read_text())
+    cfg["deal"]["model"]["name"] = "sage"
+    (bdir / "configs" / "sage-tiny.json").write_text(json.dumps(cfg))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({
+        "name": "sage-tiny", "source": "https://example.org/sage",
+        "file": f"{SPEC['paths'][0]}/configs/sage-tiny.json",
+        "reduced": [], "why": "a test"})
+    spec["workloads"].append({
+        "name": "sage-tiny.epoch", "config": "sage-tiny", "traffic": "epoch",
+        "chips": 1, "why": "a test"})
+    spec["end_to_end"][0]["workloads"].append("sage-tiny.epoch")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    cell, res = run_cell(root, "sage-tiny.epoch", trace=False)
+    assert bench.load_model(cell).__doc__.startswith("GraphSAGE")
+    assert res["correct"], res["checks"]
+    assert set(res["metrics"]) == {m["name"] for m in cell.end_to_end}
+    low = control.control_run(cell, 2**31 + 11, root=root)
+    assert not low["correct"], low["checks"]
+    assert low["program_checks"]["max_rel_err"]["value"] <= (
+        low["program_checks"]["max_rel_err"]["limit"])
+    changed = [p for p, b in before.items()
+               if p.name != "BENCHMARK.json" and p.read_bytes() != b]
+    assert changed == []
+
+
+def test_missing_model_file_is_named(tmp_path):
+    root = tiny_copy(tmp_path)
+    cell = bench.load_cell(SPEC["workloads"][0]["name"], root)
+    cell.config["deal"]["model"]["name"] = "nosuchmodel"
+    with pytest.raises(ValueError, match="nosuchmodel.py"):
+        bench.load_model(cell)

@@ -8,8 +8,10 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parent))
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE))
 
+import bench  # noqa: E402
 import peaks  # noqa: E402
 import work  # noqa: E402
 
@@ -22,6 +24,11 @@ MASK = np.array([[1, 1], [1, 0], [0, 0], [1, 1]], bool)
 @pytest.fixture
 def g():
     return work.GraphShape.of(NBR, MASK)
+
+
+def model_file(name):
+    return bench._load_module(HERE / "models" / f"{name}.py",
+                              "model_" + name)
 
 
 def test_graph_shape(g):
@@ -49,9 +56,11 @@ def test_gat_attention(g):
 
 
 def test_padded_head_is_not_counted(g):
-    """GAT's 32-wide heads run as four spmm calls, each padded to a
-    128-lane tile by the kernel; the count stays at 32 per head."""
-    calls = work.epoch_calls("gat", [g], d=128, heads=4)
+    """GAT's attend counts as four logical spmm calls of a 32-wide head
+    each, however the kernel pads or fuses them; the count stays at 32
+    per head."""
+    calls = model_file("gat").epoch_calls(
+        [g], {"d_feature": 128, "heads": 4})
     heads = [w for k, w in calls if k == "spmm"]
     assert len(heads) == 4
     assert all(w == work.spmm(g, 32) for w in heads)
@@ -63,7 +72,8 @@ def test_padded_head_is_not_counted(g):
 
 
 def test_epoch_calls_gcn(g):
-    calls = work.epoch_calls("gcn", [g, g], d=4, heads=1)
+    calls = model_file("gcn").epoch_calls(
+        [g, g], {"d_feature": 4, "heads": 1})
     assert [k for k, _ in calls] == ["gemm", "spmm"] * 2
     assert work.epoch_flops(calls) == 2 * (2 * 4 * 4 * 4 + 2 * 5 * 4)
 
@@ -72,7 +82,41 @@ def test_epoch_min_bytes(g):
     # X read + final write, two graphs (ids + mask) and weights, one
     # intermediate written and read back
     want = 4 * 4 * 4 * 2 + 2 * (4 * 5 + 8 + 4 * 4 * 4) + 2 * 4 * 4 * 4
-    assert work.epoch_min_bytes("gcn", [g, g], 4) == want
+    assert model_file("gcn").epoch_min_bytes([g, g],
+                                             {"d_feature": 4}) == want
+
+
+# Three layer graphs of the cells' size (1,736,704 rows, fanout 8), and
+# what each model's epoch ran and moved, as the harness counted them
+# before the counts moved into the model files.
+CELL_GRAPHS = [work.GraphShape(n=1736704, fanout=8, edges=13470000 + 1000 * i,
+                               src_rows=1650000 + 17 * i,
+                               active=1730000 + 3 * i) for i in range(3)]
+GEMM = ("gemm", 56908316672, 1778450432)
+COUNTED = {
+    "gcn": ([GEMM, ("spmm", 3448320000, 1855646080),
+             GEMM, ("spmm", 3448576000, 1855662784),
+             GEMM, ("spmm", 3448832000, 1855679488)], 5538684192),
+    "gat": ([GEMM] * 3 + [("gat_attention", 3448320000, 2020631744)]
+            + [("spmm", 862080000, 555151744)] * 4
+            + [GEMM] * 3 + [("gat_attention", 3448576000, 2020645984)]
+            + [("spmm", 862144000, 555161920)] * 4
+            + [GEMM] * 3 + [("gat_attention", 3448832000, 2020660224)]
+            + [("spmm", 862208000, 555172096)] * 4, 5539077408),
+}
+CELL_MODEL = {"gcn": {"name": "gcn", "n_layers": 3, "d_feature": 128,
+                      "heads": 1},
+              "gat": {"name": "gat", "n_layers": 3, "d_feature": 128,
+                      "heads": 4}}
+
+
+@pytest.mark.parametrize("name", sorted(COUNTED))
+def test_model_counts_at_the_cells_shapes(name):
+    calls, least_bytes = COUNTED[name]
+    m = model_file(name)
+    got = m.epoch_calls(CELL_GRAPHS, CELL_MODEL[name])
+    assert [(k, w.flops, w.bytes) for k, w in got] == calls
+    assert m.epoch_min_bytes(CELL_GRAPHS, CELL_MODEL[name]) == least_bytes
 
 
 def test_least_time_is_the_larger_bound():

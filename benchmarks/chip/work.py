@@ -19,11 +19,15 @@ some edge reads, ``active`` target rows with at least one edge):
                   counted, which can only lower the share); q of the
                   active rows, k of the read rows, ids, mask, and the
                   (n, F, heads) normalized scores written
+
+Which ops one epoch of a model runs is the model file's
+(``models/<name>.py``: ``epoch_calls``, ``epoch_min_bytes``), built
+from these counters.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import numpy as np
 
@@ -84,22 +88,6 @@ def gat_attention(g: GraphShape, d: int, heads: int) -> Work:
                 + F32 * g.n * g.fanout * heads)
 
 
-def epoch_calls(model: str, graphs: Sequence[GraphShape], d: int,
-                heads: int) -> List[Tuple[str, Work]]:
-    """(kernel, work) for every op of one all-node epoch, in order."""
-    calls: List[Tuple[str, Work]] = []
-    for g in graphs:
-        if model == "gcn":
-            calls += [("gemm", gemm(g.n, d, d)), ("spmm", spmm(g, d))]
-        elif model == "gat":
-            calls += [("gemm", gemm(g.n, d, d))] * 3
-            calls.append(("gat_attention", gat_attention(g, d, heads)))
-            calls += [("spmm", spmm(g, d // heads))] * heads
-        else:
-            raise ValueError(f"no work model for {model!r}")
-    return calls
-
-
 def least_s(calls: Sequence[Tuple[str, Work]], kernel: str,
             peak_flops: float, peak_bytes_s: float) -> float:
     """Sum of each call's own least time (calls do not share a bound)."""
@@ -111,17 +99,16 @@ def epoch_flops(calls: Sequence[Tuple[str, Work]]) -> float:
     return sum(w.flops for _, w in calls)
 
 
-def epoch_min_bytes(model: str, graphs: Sequence[GraphShape], d: int
-                    ) -> float:
+def fused_epoch_bytes(graphs: Sequence[GraphShape], d: int, weights: int
+                      ) -> float:
     """The least HBM traffic of one epoch, each layer fused whole: X read
-    once, each layer graph read once, each layer's weights read once,
-    each intermediate embedding written and read back once, the final
-    one written once."""
-    n_w = {"gcn": 1, "gat": 3}[model]
+    once, each layer graph read once, each layer's ``weights`` (d, d)
+    weights read once, each intermediate embedding written and read back
+    once, the final one written once."""
     n = graphs[0].n
     total = F32 * n * d + F32 * n * d
     for l, g in enumerate(graphs):
-        total += _edge_bytes(g) + F32 * n_w * d * d
+        total += _edge_bytes(g) + F32 * weights * d * d
         if l < len(graphs) - 1:
             total += 2 * F32 * n * d
     return total
