@@ -462,6 +462,12 @@ class DealConfig:
             e.append(f"partition.p: must be >= 1, got {pt.p}")
         if pt.m < 1:
             e.append(f"partition.m: must be >= 1, got {pt.m}")
+        elif (ex.name == "dist" and m.heads >= 1
+              and m.heads % pt.m and pt.m % m.heads):
+            e.append(f"model.heads: {m.heads} heads cannot be split over "
+                     f"partition.m: {pt.m} feature partitions (the dist "
+                     "executor gives each model shard whole heads, or a "
+                     "whole share of one): one must divide the other")
 
         if ex.name not in _reg.EXECUTORS:
             e.append(f"executor.name: unknown executor {ex.name!r}; "

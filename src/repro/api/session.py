@@ -62,6 +62,7 @@ class Session:
         self._plan_cache_counters = install_plan_cache_counters()
         self._build_pipeline()
         self._H: Optional[np.ndarray] = None
+        self._dist_bound = None      # (layer graphs, DistIOs) of infer_all
         self._n_epochs = 0           # infer_all epochs run, for span attrs
         self._engine = None
         self._endpoint = None
@@ -146,10 +147,7 @@ class Session:
                 sp.set(model=self.cfg.model.name, epoch=self._n_epochs)
             with obs.span("infer.bind"):
                 if isinstance(ex, DistExecutor):
-                    need_sddmm = any(op.kind == "attn_scores"
-                                     for layer in spec.layers
-                                     for op in layer.ops)
-                    ios = ex.bind(lgs, need_sddmm=need_sddmm)
+                    ios = self._dist_ios(ex, spec, lgs)
                 else:
                     ios = [DenseIO.from_layer_graph(lg) for lg in lgs]
             with obs.span("infer.forward"):
@@ -162,6 +160,19 @@ class Session:
                 sp.set(rows=int(self._H.shape[0]))
         self.timings["infer_s"] = time.perf_counter() - t0
         return self._H
+
+    def _dist_ios(self, ex, spec, lgs):
+        """The mesh bindings (CommPlan and its device arrays), built on
+        the first epoch and kept while the layer graphs are the same
+        objects (the delta engine resamples its own copies)."""
+        bound = self._dist_bound
+        if bound is None or len(bound[0]) != len(lgs) or any(
+                a is not b for a, b in zip(bound[0], lgs)):
+            need_sddmm = any(op.kind == "attn_scores"
+                             for layer in spec.layers for op in layer.ops)
+            bound = (list(lgs), ex.bind(lgs, need_sddmm=need_sddmm))
+            self._dist_bound = bound
+        return bound[1]
 
     # -- online: store + serving engine ---------------------------------
     def serve(self):
@@ -421,7 +432,7 @@ class Session:
         self._closed = True
         self._engine = None
         for name in ("X", "graph", "layer_graphs", "reinfer", "_H",
-                     "src", "dst", "params", "executor"):
+                     "_dist_bound", "src", "dst", "params", "executor"):
             if hasattr(self, name):
                 setattr(self, name, None)
 

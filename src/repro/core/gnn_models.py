@@ -4,8 +4,8 @@ The paper evaluates 3-layer GCN and GAT (4 heads).  Our GAT uses dot-product
 attention (q.k per sampled edge) so that edge scoring exercises the SDDMM
 primitive exactly as §3.4 describes; classic additive GAT decomposes into
 node terms and would never need SDDMM.  Heads are laid out head-major in the
-feature dim so each `model` shard belongs to one head (requires M % heads
-== 0 in the distributed engine).
+feature dim, so the distributed engine gives each `model` shard whole heads
+(heads % M == 0) or a whole share of one (M % heads == 0).
 
 Each model's per-layer math is defined ONCE, as a sequence of declarative
 layer ops (gemm / spmm / attn_scores / edge_softmax / attend / add) over
@@ -74,6 +74,15 @@ def masked_softmax(scores: jax.Array, mask: jax.Array) -> jax.Array:
     s = jnp.where(mask, scores, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return p * mask
+
+
+def edge_softmax(scores: jax.Array, mask: jax.Array) -> jax.Array:
+    """``masked_softmax`` over the fanout axis of (N, F) or per-head
+    (N, F, heads) edge scores; ``mask`` is (N, F)."""
+    if scores.ndim == 2:
+        return masked_softmax(scores, mask)
+    return masked_softmax(scores.transpose(0, 2, 1),
+                          mask[:, None, :]).transpose(0, 2, 1)
 
 
 def gat_head_scores(q, kf, nbr, mask, heads: int):

@@ -40,8 +40,8 @@ from jax.sharding import PartitionSpec as P
 from repro import obs, tuning
 from repro.api.registry import EXECUTORS, register_executor
 from repro.core import primitives as prim
-from repro.core.gnn_models import (LayerSpec, ModelSpec, gat_head_scores,
-                                   masked_softmax, mean_weights)
+from repro.core.gnn_models import (LayerSpec, ModelSpec, edge_softmax,
+                                   gat_head_scores, mean_weights)
 from repro.core.partition import build_plan, build_subset_plan_cached
 from repro.core.sampler import LayerGraph
 from repro.kernels import ops as kops
@@ -234,8 +234,7 @@ class RefExecutor:
         return gat_head_scores(q, k, io.nbr_resolved, io.mask, heads)
 
     def edge_softmax(self, s, io: DenseIO):
-        return masked_softmax(s.transpose(0, 2, 1),
-                              io.mask[:, None, :]).transpose(0, 2, 1)
+        return edge_softmax(s, io.mask)
 
     def attend(self, alpha, v, io: DenseIO, heads: int):
         D = v.shape[-1]
@@ -419,9 +418,14 @@ class DistExecutor:
     plan — per-row reduction order (and hence bitwise output) matches a
     full epoch through this executor.
 
-    GAT note: edge scores use the full-width dot (heads=1 semantics; the
-    psum over `model` assembles the full-D product) — matching the
-    pre-refactor distributed engine.
+    GAT: heads split over ``model`` (``primitives.head_layout``).  With
+    heads % M == 0 each shard scores, normalizes and attends its own
+    whole heads (``dist_gat_attention``, no score collective); with
+    M % heads == 0 a head spans M / heads shards, which psum its partial
+    edge scores.  ``heads == 1`` keeps the one full-width dot over all D
+    lanes (psum over ``model``) and the (N, F) scores of the
+    pre-refactor distributed engine, bit for bit.  Other (heads, M)
+    pairs raise; ``DealConfig.validate`` names them first.
     """
 
     name = "dist"
@@ -440,19 +444,32 @@ class DistExecutor:
         self._gemm = prim.make_gemm(mesh, gemm_variant)
         self._spmm = prim.make_spmm_p(mesh, self.P, spmm_variant, grouped)
         self._sddmm_cache: Dict[int, Callable] = {}
+        self._attn_cache: Dict[Tuple[int, int, bool], Callable] = {}
         self._row_spec = NamedSharding(mesh, P("data", None))
         self._hd_spec = NamedSharding(mesh, P("data", "model"))
+        self._plan_spec = NamedSharding(mesh, P("data", None, None))
         self.plan = None
 
     # -- plumbing -------------------------------------------------------
     def _put(self, x, spec):
-        return jax.device_put(jnp.asarray(x), spec)
+        return jax.device_put(x, spec)
 
     def _sddmm_fn(self, fanout: int) -> Callable:
         if fanout not in self._sddmm_cache:
             self._sddmm_cache[fanout] = prim.make_sddmm_p(
                 self.mesh, self.P, fanout, self.sddmm_variant)
         return self._sddmm_cache[fanout]
+
+    def _attn_fn(self, fanout: int, heads: int, softmax: bool) -> Callable:
+        key = (fanout, heads, softmax)
+        if key not in self._attn_cache:
+            if self.sddmm_variant != "deal":
+                raise ValueError("multi-head attention on the mesh needs "
+                                 "the deal SDDMM (approach (ii))")
+            self._attn_cache[key] = prim.make_gat_attention_p(
+                self.mesh, self.P, fanout, heads, softmax)
+        obs.gauge("dist.attn_heads_local", heads / self.M)
+        return self._attn_cache[key]
 
     def _deal_args(self, dev: Dict[str, Any]) -> Tuple:
         return (dev["send_local"], dev["edge_dst"], dev["edge_slot"],
@@ -472,7 +489,9 @@ class DistExecutor:
             ios = []
             for l, lp in enumerate(self.plan.layers):
                 lg = layer_graphs[l]
-                dev = prim.plan_device_arrays(lp)
+                obs.gauge(f"dist.ring_rows.layer{l}",
+                          int(lp.send_count[:, 1:].sum()))
+                dev = prim.plan_device_arrays(lp, self._plan_spec)
                 ios.append(DistIO(
                     spmm=self._spmm,
                     args=self._plan_args(dev),
@@ -499,15 +518,31 @@ class DistExecutor:
         return io.spmm(H_src, w_edge, *io.args)
 
     def attn_scores(self, q, k, io: DistIO, heads: int):
-        assert self.M % heads == 0, "feature parts must align to heads"
-        scores = io.sddmm(q, k, *io.sddmm_args)
-        D = q.shape[1]                   # full width (global array)
-        return scores / np.sqrt(D)
+        if heads == 1:
+            scores = io.sddmm(q, k, *io.sddmm_args)
+            D = q.shape[1]                   # full width (global array)
+            return scores / np.sqrt(D)
+        fn = self._attn_fn(io.mask_f.shape[1], heads, softmax=False)
+        return fn(q, k, io.mask_f, *io.sddmm_args)
+
+    def attn_scores_softmax(self, q, k, io: DistIO, heads: int):
+        """Scores and edge softmax as one call (the ``run_layer``
+        peephole): one shard_map for heads > 1; for one head, the
+        unfused pair's own ops."""
+        if heads == 1:
+            return self.edge_softmax(self.attn_scores(q, k, io, 1), io)
+        fn = self._attn_fn(io.mask_f.shape[1], heads, softmax=True)
+        return fn(q, k, io.mask_f, *io.sddmm_args)
 
     def edge_softmax(self, s, io: DistIO):
-        return masked_softmax(s, io.mask_f > 0)
+        return edge_softmax(s, io.mask_f > 0)
 
     def attend(self, alpha, v, io: DistIO, heads: int):
+        """The ring SPMM, each exchanged row weighted per head: (N, F,
+        heads) weights go head-major to the model shards that hold each
+        head's lanes (repeated where a head spans shards)."""
+        if alpha.ndim == 3 and alpha.shape[-1] < self.M:
+            alpha = jnp.repeat(alpha, self.M // alpha.shape[-1], axis=-1)
         return io.spmm(v, alpha, *io.args)
 
     # -- row-subset mode (distributed delta refresh) --------------------

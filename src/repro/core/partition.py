@@ -69,8 +69,19 @@ def partition_nodes(n_nodes: int, P: int) -> np.ndarray:
     return (np.arange(P + 1) * (n_nodes // P)).astype(np.int64)
 
 
+def plan_bucket(n: int, floor: int = 8) -> int:
+    """Round a padded plan length up to three significant bits (8, 9,
+    ..., 15, 16, 18, ..., 30, 32, 36, ...): graphs of one size drawn from
+    different seeds land in one bucket, so their plans compile once, and
+    the padding stays under 1/8."""
+    n = max(n, floor)
+    step = 1 << max(0, n.bit_length() - 4)
+    return -(-n // step) * step
+
+
 def build_plan(layer_graphs: List[LayerGraph], P: int, M: int
                ) -> PartitionPlan:
+    obs.add("dist.plan_builds")
     n = layer_graphs[0].n_nodes
     bounds = partition_nodes(n, P)
     n_local = n // P
@@ -110,8 +121,9 @@ def _layer_plan(lg: LayerGraph, bounds: np.ndarray, P: int) -> LayerPlan:
             entries[p][k] = (dst_loc.astype(np.int32),
                              slot.astype(np.int32), pos.astype(np.int32),
                              (ids - bounds[q]).astype(np.int32))
-    R = max(1, max(r.size for row in req for r in row))
-    E = max(1, max(e[0].size for row in entries for e in row))
+    # seed-stable padded lengths (padding slots are masked)
+    R = plan_bucket(max(r.size for row in req for r in row))
+    E = plan_bucket(max(e[0].size for row in entries for e in row))
 
     send_local = np.zeros((P, P, R), np.int32)
     send_count = np.zeros((P, P), np.int32)

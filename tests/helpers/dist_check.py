@@ -347,5 +347,182 @@ def check_tail_onboarding(mesh, g, lgs, X):
         sys.exit(1)
 
 
+# ----------------------------------------------------------------------
+# multi-head GAT on the mesh: one case per check, each reported on its
+# own line ("CASE <name> OK|FAIL <detail>") and none stopping the rest
+# ----------------------------------------------------------------------
+
+def _gat_cfg(heads, p, m, executor="dist", telemetry=False):
+    from repro.api import DealConfig
+    return DealConfig.from_dict({
+        "graph": {"dataset": "rmat", "n_nodes": 256, "avg_degree": 8,
+                  "fanout": 8, "seed": 5},
+        "model": {"name": "gat", "n_layers": 2, "d_feature": 64,
+                  "heads": heads},
+        "partition": {"p": p, "m": m},
+        "executor": {"name": executor, "fallback_to_ref": False},
+        "telemetry": {"enabled": telemetry}})
+
+
+def _infer(cfg):
+    from repro.api import Session
+    with Session.build(cfg) as s:
+        return np.array(s.infer_all())
+
+
+def case_session_vs_ref(heads, p, m):
+    """DealConfig -> Session.infer_all on the mesh against RefExecutor
+    and ``local_gat_infer`` on the same seeded world.  Tolerance 5e-5
+    (max|H| ~2.5): f32 sums in another order, the per-head dot over its
+    lanes (split over shards and psummed where a head spans them) and
+    the attend's ring accumulation over the fanout, against the
+    oracle's einsums; a wrong head split errs by O(1)."""
+    from repro.api import Session
+    got = _infer(_gat_cfg(heads, p, m))
+    ref = _infer(_gat_cfg(heads, p, m, executor="ref"))
+    with Session.build(_gat_cfg(heads, p, m, executor="ref")) as s:
+        local = np.asarray(local_gat_infer(s.layer_graphs[:2], s.X,
+                                           s.params))
+    err = max(np.abs(got - ref).max(), np.abs(got - local).max())
+    return err <= 5e-5, f"max_err={err:.2e} max|H|={np.abs(ref).max():.2f}"
+
+
+def case_heads1_bitwise_parent():
+    """heads=1 keeps the pre-per-head distributed GAT bit for bit: the
+    same Session world through a test-local copy of the earlier
+    full-width score (one dot over all D lanes, psum over ``model``,
+    / sqrt(D)) with the unfused score -> softmax -> attend ops."""
+    from repro.api import Session
+    from repro.core.gnn_models import masked_softmax
+    from repro.core.ops import DistExecutor
+
+    class FullWidthScores(DistExecutor):
+        attn_scores_softmax = None          # no fused peephole
+
+        def attn_scores(self, q, k, io, heads):
+            assert self.M % heads == 0
+            scores = io.sddmm(q, k, *io.sddmm_args)
+            return scores / np.sqrt(q.shape[1])
+
+        def edge_softmax(self, s, io):
+            return masked_softmax(s, io.mask_f > 0)
+
+        def attend(self, alpha, v, io, heads):
+            return io.spmm(v, alpha, *io.args)
+
+    with Session.build(_gat_cfg(1, 2, 2)) as s:
+        got = np.array(s.infer_all())
+        s.executor = FullWidthScores(s.executor.mesh)
+        s._H = s._dist_bound = None
+        want = np.array(s.infer_all())
+    same = bool((got == want).all())
+    return same, f"bitwise={same}"
+
+
+def case_unfused_scores(heads, p, m):
+    """The unfused per-head path (``attn_scores`` -> ``edge_softmax``,
+    kept for specs that read raw scores) against the fused
+    ``dist_gat_attention``.  Tolerance 1e-6 (max|H| ~2.5): the same
+    scores and softmax, inside one program or split over two."""
+    from repro.api import Session
+    from repro.core.ops import DistExecutor
+
+    class Unfused(DistExecutor):
+        attn_scores_softmax = None
+
+    with Session.build(_gat_cfg(heads, p, m)) as s:
+        fused = np.array(s.infer_all())
+        s.executor = Unfused(s.executor.mesh)
+        s._H = s._dist_bound = None
+        got = np.array(s.infer_all())
+    err = np.abs(got - fused).max()
+    return err <= 1e-6, f"max_err={err:.2e}"
+
+
+def case_delta_bitwise(mesh, heads):
+    """The distributed delta refresh (``run_rows``, per-head attention
+    on the row subset) commits the bits of a full epoch through the
+    same executor."""
+    import copy
+
+    from repro.core.ops import DistExecutor
+    from repro.gnnserve import (DeltaReinference, MutationLog,
+                                apply_edge_mutations, store_from_inference)
+    src, dst = rmat_edges(256, 256 * 8, seed=1)
+    g = csr_from_edges(src, dst, 256)
+    lgs = sample_layer_graphs(g, fanout=8, n_layers=2, seed=0)
+    rng = np.random.default_rng(29)
+    X = rng.standard_normal((256, 64), dtype=np.float32)
+    dex = DistExecutor(mesh)
+    params = init_gat(jax.random.PRNGKey(6), [64, 64, 64], heads=heads)
+    ri = DeltaReinference([copy.deepcopy(l) for l in lgs], "gat", params,
+                          executor=dex)
+    store = store_from_inference(X, ri.full_levels(X)[1:], n_shards=4)
+    log = MutationLog()
+    log.add_edges(rng.integers(0, 256, 8), rng.integers(0, 256, 8))
+    fid = rng.choice(256, 3, replace=False)
+    log.update_features(fid, rng.standard_normal((3, 64)).astype(np.float32))
+    batch = log.drain()
+    stats = ri.refresh(store, apply_edge_mutations(g, batch),
+                       batch.feat_ids, batch.feat_rows,
+                       batch.affected_dsts())
+    X2 = X.copy()
+    X2[batch.feat_ids] = batch.feat_rows
+    oracle = DeltaReinference(ri.layer_graphs, "gat", params,
+                              executor=dex).full_levels(X2)
+    exact = all(bool((store.lookup(np.arange(256), lvl) ==
+                      oracle[lvl]).all()) for lvl in (1, 2))
+    partial = 0 < stats["frontier_sizes"][-1] < 256
+    return exact and partial, (f"bitwise={exact} "
+                               f"frontier={stats['frontier_sizes']}")
+
+
+def case_plan_built_once():
+    """With telemetry on, two ``infer_all`` epochs build the CommPlan
+    once and give the same bits."""
+    from repro.api import Session
+    with Session.build(_gat_cfg(4, 2, 2, telemetry=True)) as s:
+        H1 = np.array(s.infer_all())
+        s._H = None
+        H2 = np.array(s.infer_all())
+        m = s.telemetry.metrics.to_dict()
+    builds = m.get("dist.plan_builds")
+    same = bool((H1 == H2).all())
+    return (builds == 1 and same and
+            m.get("dist.attn_heads_local") == 2.0), \
+        f"plan_builds={builds} bitwise={same} " \
+        f"heads_local={m.get('dist.attn_heads_local')}"
+
+
+def gat_main():
+    cases = [
+        ("session_vs_ref/heads4_p2_m2",
+         lambda: case_session_vs_ref(4, 2, 2)),
+        ("session_vs_ref/heads2_p2_m2",
+         lambda: case_session_vs_ref(2, 2, 2)),
+        ("session_vs_ref/heads2_p2_m4",
+         lambda: case_session_vs_ref(2, 2, 4)),
+        ("heads1_bitwise_parent", case_heads1_bitwise_parent),
+        ("unfused_scores/heads4_p2_m2",
+         lambda: case_unfused_scores(4, 2, 2)),
+        ("unfused_scores/heads2_p2_m4",
+         lambda: case_unfused_scores(2, 2, 4)),
+        ("delta_bitwise/heads4",
+         lambda: case_delta_bitwise(make_host_mesh(4, 2), 4)),
+        ("plan_built_once", case_plan_built_once),
+    ]
+    for name, fn in cases:
+        hb.beat(name)
+        try:
+            ok, detail = fn()
+        except Exception as exc:          # reported as this case's fail
+            ok, detail = False, f"{type(exc).__name__}: {exc}"
+        print(f"CASE {name} {'OK' if ok else 'FAIL'} {detail}", flush=True)
+    print("GAT CASES DONE")
+
+
 if __name__ == "__main__":
-    main()
+    if "--gat" in sys.argv:
+        gat_main()
+    else:
+        main()

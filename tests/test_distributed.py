@@ -28,13 +28,13 @@ def _read_heartbeat(path: pathlib.Path):
         return None, "<no heartbeat yet>"
 
 
-def _run_once(script: pathlib.Path, hb: pathlib.Path):
-    """Run the helper, polling its heartbeat.  Returns
+def _run_once(script: pathlib.Path, hb: pathlib.Path, *args: str):
+    """Run the helper (with ``args``), polling its heartbeat.  Returns
     ``(CompletedProcess | None, wedged_stage | None)`` — a wedge (stale
     heartbeat or total-budget blowout) kills the process and reports the
     stage it died in."""
     proc = subprocess.Popen([sys.executable, str(script),
-                             "--heartbeat", str(hb)],
+                             "--heartbeat", str(hb), *args],
                             stdout=subprocess.PIPE,
                             stderr=subprocess.PIPE, text=True)
     start = time.monotonic()
@@ -57,7 +57,8 @@ def _run_once(script: pathlib.Path, hb: pathlib.Path):
             return None, stage
 
 
-def _run_check(script: pathlib.Path, tmp_path) -> subprocess.CompletedProcess:
+def _run_check(script: pathlib.Path, tmp_path,
+               *args: str) -> subprocess.CompletedProcess:
     """One retry on a WEDGE only: 8 forced host devices on a small box
     can deadlock their collectives (threads asleep, ~0 CPU) — an
     environmental hang, observed rarely and never reproducible
@@ -67,7 +68,7 @@ def _run_check(script: pathlib.Path, tmp_path) -> subprocess.CompletedProcess:
     is NOT retried."""
     for attempt in (0, 1):
         hb = tmp_path / f"{script.stem}.heartbeat.{attempt}"
-        res, stage = _run_once(script, hb)
+        res, stage = _run_once(script, hb, *args)
         if res is not None:
             if attempt:
                 print(f"# {script.name}: retry succeeded after a wedge")
@@ -94,3 +95,45 @@ def test_tuned_variants_match_baseline(tmp_path):
     print(res.stdout)
     assert res.returncode == 0, res.stdout + res.stderr[-2000:]
     assert "ALL TUNED CHECKS PASSED" in res.stdout
+
+
+GAT_CASES = ["session_vs_ref/heads4_p2_m2", "session_vs_ref/heads2_p2_m2",
+             "session_vs_ref/heads2_p2_m4", "heads1_bitwise_parent",
+             "unfused_scores/heads4_p2_m2", "unfused_scores/heads2_p2_m4",
+             "delta_bitwise/heads4", "plan_built_once"]
+
+
+@pytest.fixture(scope="module")
+def gat_cases(tmp_path_factory):
+    """Multi-head GAT on the mesh: every case of ``dist_check.py --gat``
+    in one child process, {case: (ok, detail)}."""
+    res = _run_check(HELPER, tmp_path_factory.mktemp("gat"), "--gat")
+    assert res.returncode == 0, res.stdout + res.stderr[-2000:]
+    assert "GAT CASES DONE" in res.stdout, res.stdout
+    out = {}
+    for line in res.stdout.splitlines():
+        if line.startswith("CASE "):
+            _, name, verdict, detail = (line.split(" ", 3) + [""])[:4]
+            out[name] = (verdict == "OK", detail)
+    return out
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case", GAT_CASES)
+def test_dist_gat(gat_cases, case):
+    ok, detail = gat_cases[case]
+    assert ok, f"{case}: {detail}"
+
+
+def test_heads_the_mesh_cannot_split_are_a_config_error():
+    """heads=3 on m=2 fails at Session.build, naming both fields."""
+    from repro.api import DealConfig, Session
+    from repro.api.config import ConfigError
+    cfg = DealConfig.from_dict({
+        "model": {"name": "gat", "d_feature": 48, "heads": 3},
+        "partition": {"p": 2, "m": 2},
+        "executor": {"name": "dist"}})
+    with pytest.raises(ConfigError) as exc:
+        Session.build(cfg)
+    assert "model.heads" in str(exc.value)
+    assert "partition.m" in str(exc.value)

@@ -104,3 +104,35 @@ def test_resample_rows_invalidates_subset_plans(layer_graphs, small_graph):
     resample_rows(small_graph, lgs, rows[:5], seed=9)
     p2 = build_subset_plan_cached(lgs[0], rows, 4)
     assert p2 is not p1
+
+
+def test_plan_bucket_keeps_three_significant_bits():
+    from repro.core.partition import plan_bucket
+    assert [plan_bucket(n) for n in (1, 8, 9, 16, 17, 18, 19)] == \
+        [8, 8, 9, 16, 18, 18, 20]
+    for n in range(1, 5000):
+        b = plan_bucket(n)
+        assert n <= b and (b <= 8 or b - n < b / 8)
+
+
+def test_plan_shapes_do_not_depend_on_the_graph_seed():
+    """Two seeds at one size sample graphs whose raw per-peer request
+    and edge counts differ; the padded plan arrays have one shape, so
+    one compiled program serves both."""
+    from repro.core.graph import csr_from_edges, rmat_edges
+    from repro.core.sampler import sample_layer_graphs
+
+    def plan_and_counts(seed):
+        src, dst = rmat_edges(4096, 4096 * 8, seed=seed)
+        lgs = sample_layer_graphs(csr_from_edges(src, dst, 4096), fanout=8,
+                                  n_layers=2, seed=seed)
+        plan = build_plan(lgs, 2, 2)
+        return plan, [(int(lp.send_count.max()), int(lp.edge_mask.sum(
+            axis=-1).max())) for lp in plan.layers]
+
+    (a, raw_a), (b, raw_b) = plan_and_counts(11), plan_and_counts(12)
+    assert raw_a != raw_b
+    for la, lb in zip(a.layers, b.layers):
+        for name in ("send_local", "edge_dst", "edge_slot", "edge_pos",
+                     "edge_mask", "mirror_src"):
+            assert getattr(la, name).shape == getattr(lb, name).shape

@@ -8,6 +8,10 @@ ogbn-products' D=100, which the kernels pad to a whole lane tile.  This
 catches what interpret mode cannot: loads from HBM refs, scatter-adds on
 carried values, blocks narrower than a lane tile.
 
+The distributed GAT layer's shard_map programs (``core/primitives.py``)
+are compiled on the described 2x2 mesh, p=2 x m=2 as the four-chip cell
+runs them, with their pinned module names.
+
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU runtime at a time, so each pytest
 worker must collect these tests without touching it.  Keep every such
@@ -31,13 +35,17 @@ N, F, HEADS = 4096, 8, 4
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     # libtpu is a pinned requirement: failing to describe the chip is a
     # failure of these tests, never a skip
-    topo = topologies.get_topology_desc(platform="tpu",
+    return topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -153,3 +161,50 @@ def test_kernel_names_are_pinned(case, one_chip, no_persistent_cache):
     assert calls and all(
         re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln) for ln in calls), \
         calls
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+
+def _dist_programs(mesh):
+    """name -> (jitted, arg shapes with specs, collective it must hold)
+    for one dist GAT layer.  The head-major spmm is compiled inside a
+    caller's jit, as the executor's eager call would not name it."""
+    from jax.sharding import PartitionSpec as P
+    from repro.core import primitives as prim
+    R, E = 512, 4096
+    hd = ((N, 128), jnp.float32, P("data", "model"))
+    plan = [((2, 2, R), jnp.int32, P("data", None, None))] + \
+        [((2, 2, E), jnp.int32, P("data", None, None))] * 3 + \
+        [((2, 2, E), jnp.bool_, P("data", None, None))]
+    mask = ((N, F), jnp.float32, P("data", None))
+    alpha = ((N, F, HEADS), jnp.float32, P("data", None, "model"))
+    spmm = prim.make_spmm_p(mesh, 2)
+    return {
+        "dist_gemm": (prim.make_gemm(mesh),
+                      [hd, ((128, 128), jnp.float32, P(None, None))],
+                      "all-to-all"),
+        "dist_gat_attention": (
+            prim.make_gat_attention_p(mesh, 2, F, HEADS), [hd, hd, mask]
+            + plan, "collective-permute"),
+        "dist_spmm.head_major": (jax.jit(lambda h, a, *p: spmm(h, a, *p)),
+                                 [hd, alpha] + plan, "collective-permute"),
+    }
+
+
+@pytest.mark.parametrize("case", ["dist_gemm", "dist_gat_attention",
+                                  "dist_spmm.head_major"])
+def test_dist_gat_layer_compiles_on_a_2x2_mesh(case, mesh_2x2,
+                                               no_persistent_cache):
+    from jax.sharding import NamedSharding
+    fn, shapes, collective = _dist_programs(mesh_2x2)[case]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh_2x2, p))
+            for s, d, p in shapes]
+    text = fn.lower(*args).compile().as_text()
+    assert collective in text
+    if "." not in case:
+        assert re.search(rf"^HloModule jit_{case}\b", text, re.M)
