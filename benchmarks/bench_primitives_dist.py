@@ -1,6 +1,7 @@
 """Figs 16-19 in ONE subprocess (8 host devices): distributed GEMM
 (DEAL vs CAGNET), SPMM (feature- vs graph-exchange), SDDMM (approach i vs
-ii over (P, M) grids), and partitioned-communication + pipelining."""
+ii over (P, M) grids), and the ring row exchange vs a monolithic
+all-gather."""
 from benchmarks.common import run_dist_script
 
 _SCRIPT = r"""
@@ -59,7 +60,9 @@ for name, (g, lgs) in datasets.items():
                        NamedSharding(mesh, P("data", "model")))
     w = jax.device_put(jnp.asarray(mean_weights(lgs[0].mask)),
                        NamedSharding(mesh, P("data", None)))
-    deal_args = (dev["send_local"], dev["edge_dst"], dev["edge_slot"], dev["edge_pos"], dev["edge_mask"])
+    mask_f = jax.device_put(jnp.asarray(lgs[0].mask, jnp.float32),
+                            NamedSharding(mesh, P("data", None)))
+    deal_args = (mask_f, dev["send_local"], dev["slot_src"])
     tf = tmed(prim.make_spmm(mesh, lp, "deal"), H, w, *deal_args)
     tg = tmed(prim.make_spmm(mesh, lp, "graph_exchange"), H, w,
               dev["mirror_src"], dev["edge_dst"], dev["edge_slot"], dev["edge_mask"])
@@ -78,13 +81,15 @@ for (Pg, M) in ((4, 2),) if SMOKE else ((1, 8), (2, 4), (4, 2), (8, 1)):
     sh = NamedSharding(mesh, P("data", "model"))
     q = jax.device_put(jnp.asarray(rng.standard_normal((n, D), dtype=np.float32)), sh)
     k = jax.device_put(jnp.asarray(rng.standard_normal((n, D), dtype=np.float32)), sh)
-    args = (dev["send_local"], dev["edge_dst"], dev["edge_slot"], dev["edge_pos"], dev["edge_mask"])
+    mask_f = jax.device_put(jnp.asarray(lgs[0].mask, jnp.float32),
+                            NamedSharding(mesh, P("data", None)))
+    args = (mask_f, dev["send_local"], dev["slot_src"])
     tii = tmed(prim.make_sddmm(mesh, lp, "deal"), q, k, *args)
     ti = tmed(prim.make_sddmm(mesh, lp, "dup"), q, k, *args)
     print(f"CSV,fig18/sddmm/p{Pg}m{M}/split,{tii*1e6:.1f},speedup_vs_dup={ti/tii:.2f}x")
     print(f"CSV,fig18/sddmm/p{Pg}m{M}/dup,{ti*1e6:.1f},")
 
-# ---------------- Fig 19: grouped + pipelined vs monolithic ----------------
+# ---------------- Fig 19: ring exchange vs monolithic all-gather ----------------
 mesh = make_host_mesh(4, 2)
 for name, (g, lgs) in datasets.items():
     n = g.n_nodes
@@ -94,20 +99,17 @@ for name, (g, lgs) in datasets.items():
                        NamedSharding(mesh, P("data", "model")))
     w = jax.device_put(jnp.asarray(mean_weights(lgs[0].mask)),
                        NamedSharding(mesh, P("data", None)))
-    args = (dev["send_local"], dev["edge_dst"], dev["edge_slot"], dev["edge_pos"], dev["edge_mask"])
+    args = (jax.device_put(jnp.asarray(lgs[0].mask, jnp.float32),
+                           NamedSharding(mesh, P("data", None))),
+            dev["send_local"], dev["slot_src"])
     nbr = jnp.asarray(lgs[0].nbr.reshape(4, n//4, -1))
     msk = jnp.asarray(lgs[0].mask.reshape(4, n//4, -1))
     t_mono = tmed(prim.make_spmm(mesh, lp, "allgather"), H, w, nbr, msk)
-    t_ungr = tmed(prim.make_spmm(mesh, lp, "deal", grouped=False), H, w, *args)
-    t_grp  = tmed(prim.make_spmm(mesh, lp, "deal", grouped=True), H, w, *args)
+    t_deal = tmed(prim.make_spmm(mesh, lp, "deal"), H, w, *args)
     # network bytes per device (what a real 25Gbps/ICI fabric pays):
     deal_B = comm_volume(plan, D)["layer0"]["deal_feature_exchange_B"] / 4
     ag_B = (4 - 1) / 4 * n * (D // 2) * 4        # all-gather of the tile
-    # peak recv-buffer rows: monolithic holds all groups at once
-    peak_mono = n * 1.0
-    peak_grp = lp.max_request
-    print(f"CSV,fig19/spmm/{name}/grouped_pipelined,{t_grp*1e6:.1f},host_speedup_vs_allgather={t_mono/t_grp:.2f}x;net_bytes_ratio={ag_B/max(deal_B,1):.1f}x;peak_rows_ratio={peak_mono/peak_grp:.1f}x")
-    print(f"CSV,fig19/spmm/{name}/ungrouped,{t_ungr*1e6:.1f},speedup_grouped={t_ungr/t_grp:.2f}x")
+    print(f"CSV,fig19/spmm/{name}/deal_ring,{t_deal*1e6:.1f},host_speedup_vs_allgather={t_mono/t_deal:.2f}x;net_bytes_ratio={ag_B/max(deal_B,1):.1f}x")
     print(f"CSV,fig19/spmm/{name}/allgather_monolithic,{t_mono*1e6:.1f},net_bytes={ag_B:.0f}")
 """
 

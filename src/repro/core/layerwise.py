@@ -120,15 +120,14 @@ class DistributedLayerwise:
 
     def __init__(self, mesh, layer_graphs: List[LayerGraph], model: str,
                  params, *, spmm_variant: str = "deal",
-                 gemm_variant: str = "deal", sddmm_variant: str = "deal",
-                 grouped: bool = True):
+                 gemm_variant: str = "deal", sddmm_variant: str = "deal"):
         self.mesh = mesh
         self.model = model
         self.params = params
         self.layer_graphs = layer_graphs
         self.ex = DistExecutor(mesh, spmm_variant=spmm_variant,
                                gemm_variant=gemm_variant,
-                               sddmm_variant=sddmm_variant, grouped=grouped)
+                               sddmm_variant=sddmm_variant)
         self.P = self.ex.P
         self.M = self.ex.M
         self.spec = model_spec(model, params)

@@ -106,7 +106,8 @@ class DistIO:
     """Graph binding for DistExecutor: the jitted collectives plus the
     plan tensors they consume, and the sharded per-row edge weights.
     ``args`` follows the spmm variant's signature; ``sddmm_args`` is
-    always the deal-style 5-tuple the SDDMM collective expects."""
+    always the deal-style ``(mask_f, send_local, slot_src)`` the SDDMM
+    and attention collectives expect."""
     spmm: Callable
     args: Tuple                      # plan arrays, sharded over "data"
     mean_w: Any                      # (N, F) mean weights, row-sharded
@@ -432,7 +433,7 @@ class DistExecutor:
 
     def __init__(self, mesh, *, spmm_variant: str = "deal",
                  gemm_variant: str = "deal", sddmm_variant: str = "deal",
-                 grouped: bool = True, subset_floor: int = 64):
+                 subset_floor: int = 64):
         self.mesh = mesh
         self.P = mesh.shape["data"]
         self.M = mesh.shape["model"]
@@ -442,9 +443,9 @@ class DistExecutor:
         self.spmm_variant = spmm_variant
         self.sddmm_variant = sddmm_variant
         self._gemm = prim.make_gemm(mesh, gemm_variant)
-        self._spmm = prim.make_spmm_p(mesh, self.P, spmm_variant, grouped)
-        self._sddmm_cache: Dict[int, Callable] = {}
-        self._attn_cache: Dict[Tuple[int, int, bool], Callable] = {}
+        self._spmm = prim.make_spmm_p(mesh, self.P, spmm_variant)
+        self._sddmm = prim.make_sddmm_p(mesh, self.P, sddmm_variant)
+        self._attn_cache: Dict[Tuple[int, bool], Callable] = {}
         self._row_spec = NamedSharding(mesh, P("data", None))
         self._hd_spec = NamedSharding(mesh, P("data", "model"))
         self._plan_spec = NamedSharding(mesh, P("data", None, None))
@@ -454,32 +455,23 @@ class DistExecutor:
     def _put(self, x, spec):
         return jax.device_put(x, spec)
 
-    def _sddmm_fn(self, fanout: int) -> Callable:
-        if fanout not in self._sddmm_cache:
-            self._sddmm_cache[fanout] = prim.make_sddmm_p(
-                self.mesh, self.P, fanout, self.sddmm_variant)
-        return self._sddmm_cache[fanout]
-
-    def _attn_fn(self, fanout: int, heads: int, softmax: bool) -> Callable:
-        key = (fanout, heads, softmax)
+    def _attn_fn(self, heads: int, softmax: bool) -> Callable:
+        key = (heads, softmax)
         if key not in self._attn_cache:
             if self.sddmm_variant != "deal":
                 raise ValueError("multi-head attention on the mesh needs "
                                  "the deal SDDMM (approach (ii))")
             self._attn_cache[key] = prim.make_gat_attention_p(
-                self.mesh, self.P, fanout, heads, softmax)
+                self.mesh, self.P, heads, softmax)
         obs.gauge("dist.attn_heads_local", heads / self.M)
         return self._attn_cache[key]
 
-    def _deal_args(self, dev: Dict[str, Any]) -> Tuple:
-        return (dev["send_local"], dev["edge_dst"], dev["edge_slot"],
-                dev["edge_pos"], dev["edge_mask"])
-
-    def _plan_args(self, dev: Dict[str, Any]) -> Tuple:
+    def _plan_args(self, lp, deal: Tuple) -> Tuple:
         if self.spmm_variant == "graph_exchange":
-            return (dev["mirror_src"], dev["edge_dst"], dev["edge_slot"],
-                    dev["edge_mask"])
-        return self._deal_args(dev)
+            return tuple(self._put(getattr(lp, name), self._plan_spec)
+                         for name in ("mirror_src", "edge_dst", "edge_slot",
+                                      "edge_mask"))
+        return deal
 
     # -- full-graph binding ---------------------------------------------
     def bind(self, layer_graphs: Sequence[LayerGraph],
@@ -491,18 +483,20 @@ class DistExecutor:
                 lg = layer_graphs[l]
                 obs.gauge(f"dist.ring_rows.layer{l}",
                           int(lp.send_count[:, 1:].sum()))
-                dev = prim.plan_device_arrays(lp, self._plan_spec)
+                # the share of the dense slot gather that reads real edges
+                obs.gauge(f"dist.slot_fill.layer{l}", float(lg.mask.mean()))
+                mask_f = self._put(lg.mask.astype(np.float32),
+                                   self._row_spec)
+                deal = (mask_f, self._put(lp.send_local, self._plan_spec),
+                        self._put(lp.slot_src, self._plan_spec))
                 ios.append(DistIO(
                     spmm=self._spmm,
-                    args=self._plan_args(dev),
+                    args=self._plan_args(lp, deal),
                     mean_w=self._put(mean_weights(lg.mask),
                                      self._row_spec),
-                    mask_f=self._put(lg.mask.astype(np.float32),
-                                     self._row_spec),
-                    sddmm=self._sddmm_fn(lp.fanout) if need_sddmm
-                    else None,
-                    sddmm_args=self._deal_args(dev) if need_sddmm
-                    else ()))
+                    mask_f=mask_f,
+                    sddmm=self._sddmm if need_sddmm else None,
+                    sddmm_args=deal if need_sddmm else ()))
             if bsp:
                 bsp.set(n_layers=len(ios), P=self.P, M=self.M)
         return ios
@@ -522,8 +516,7 @@ class DistExecutor:
             scores = io.sddmm(q, k, *io.sddmm_args)
             D = q.shape[1]                   # full width (global array)
             return scores / np.sqrt(D)
-        fn = self._attn_fn(io.mask_f.shape[1], heads, softmax=False)
-        return fn(q, k, io.mask_f, *io.sddmm_args)
+        return self._attn_fn(heads, softmax=False)(q, k, *io.sddmm_args)
 
     def attn_scores_softmax(self, q, k, io: DistIO, heads: int):
         """Scores and edge softmax as one call (the ``run_layer``
@@ -531,8 +524,7 @@ class DistExecutor:
         unfused pair's own ops."""
         if heads == 1:
             return self.edge_softmax(self.attn_scores(q, k, io, 1), io)
-        fn = self._attn_fn(io.mask_f.shape[1], heads, softmax=True)
-        return fn(q, k, io.mask_f, *io.sddmm_args)
+        return self._attn_fn(heads, softmax=True)(q, k, *io.sddmm_args)
 
     def edge_softmax(self, s, io: DistIO):
         return edge_softmax(s, io.mask_f > 0)
@@ -570,20 +562,16 @@ class DistExecutor:
             if psp:
                 psp.set(rows=int(rows.size), src_rows=int(sp.n_src_rows),
                         level=level)
-        args = (jnp.asarray(sp.send_local), jnp.asarray(sp.edge_dst),
-                jnp.asarray(sp.edge_slot), jnp.asarray(sp.edge_pos),
-                jnp.asarray(sp.edge_mask))
+        mask = sp.row_mask.reshape(-1, sp.fanout)
+        mask_f = self._put(mask.astype(np.float32), self._row_spec)
+        args = (mask_f, jnp.asarray(sp.send_local), jnp.asarray(sp.slot_src))
         io = DistIO(
             spmm=self._spmm,
             args=args,
             sddmm_args=args,
-            mean_w=self._put(
-                mean_weights(sp.row_mask.reshape(-1, sp.fanout)),
-                self._row_spec),
-            mask_f=self._put(
-                sp.row_mask.reshape(-1, sp.fanout).astype(np.float32),
-                self._row_spec),
-            sddmm=self._sddmm_fn(sp.fanout))
+            mean_w=self._put(mean_weights(mask), self._row_spec),
+            mask_f=mask_f,
+            sddmm=self._sddmm)
         with obs.span("dist.exchange") as xsp:
             src_rows = read_level(level, sp.src_ids.reshape(-1))
             H_src = self._put(src_rows, self._hd_spec)

@@ -11,6 +11,12 @@ the paper's ID exchange, hoisted to the plan.
 Group structure == the paper's partitioned communication (§3.5): group 0 is
 the local tile (Fig 11 "local first"), group k>0 holds the edges whose
 source lives k hops around the data-axis ring.
+
+The Deal primitives read the plan as a dense (row, slot) table: every
+masked-in fanout slot has exactly one source row, in the local tile or in
+one ring buffer, so ``slot_src`` addresses it in the concatenation
+[local tile; recv buffer 1; ...; recv buffer P-1] and a row's aggregate
+is a gather and a sum over its slots, with no scatter.
 """
 from __future__ import annotations
 
@@ -33,13 +39,16 @@ class LayerPlan:
     # receives the rows it requested from peer (p+k)%P.
     send_local: np.ndarray       # (P, P, R) int32, row ids local to sender
     send_count: np.ndarray       # (P, P)   int32 (valid prefix of R)
-    # consuming the received buffer (k=0 consumes H_local directly):
+    # slot (row, f) of device p reads row slot_src[p, row, f] of
+    # [H_local; recv buffers k=1..P-1]: k=0 rows at their local id, step
+    # k at n_local + (k-1)*R + its row in the buffer; masked slots read 0
+    slot_src: np.ndarray         # (P, n_local, F) int32
+    # the graph-exchange baseline's edge lists (k=0 consumes H_local):
     edge_dst: np.ndarray         # (P, P, E) int32 — local dst row
     edge_slot: np.ndarray        # (P, P, E) int32 — fanout slot of the edge
-    edge_pos: np.ndarray         # (P, P, E) int32 — row in the recv buffer
     edge_mask: np.ndarray        # (P, P, E) bool
-    # mirror for the graph-exchange baseline: at step k device q gathers the
-    # per-edge source rows for peer (q-k)%P (duplicates included).
+    # at step k device q gathers the per-edge source rows for peer
+    # (q-k)%P (duplicates included).
     mirror_src: np.ndarray       # (P, P, E) int32 — row local to the sender
 
     @property
@@ -127,18 +136,18 @@ def _layer_plan(lg: LayerGraph, bounds: np.ndarray, P: int) -> LayerPlan:
 
     send_local = np.zeros((P, P, R), np.int32)
     send_count = np.zeros((P, P), np.int32)
+    slot_src = np.zeros((P, n_local, F), np.int32)
     edge_dst = np.zeros((P, P, E), np.int32)
     edge_slot = np.zeros((P, P, E), np.int32)
-    edge_pos = np.zeros((P, P, E), np.int32)
     edge_mask = np.zeros((P, P, E), bool)
     mirror_src = np.zeros((P, P, E), np.int32)
     for p in range(P):
         for k in range(P):
             d, s, pos, src_loc = entries[p][k]
             m = d.size
+            slot_src[p, d, s] = pos + (n_local + (k - 1) * R if k else 0)
             edge_dst[p, k, :m] = d
             edge_slot[p, k, :m] = s
-            edge_pos[p, k, :m] = pos
             edge_mask[p, k, :m] = True
             # sender (p+k)%P ships these rows to p at ring step k:
             sender = (p + k) % P
@@ -147,8 +156,8 @@ def _layer_plan(lg: LayerGraph, bounds: np.ndarray, P: int) -> LayerPlan:
             send_count[sender, k] = r.size
             mirror_src[sender, k, :m] = src_loc
     return LayerPlan(P=P, n_local=n_local, fanout=F, send_local=send_local,
-                     send_count=send_count, edge_dst=edge_dst,
-                     edge_slot=edge_slot, edge_pos=edge_pos,
+                     send_count=send_count, slot_src=slot_src,
+                     edge_dst=edge_dst, edge_slot=edge_slot,
                      edge_mask=edge_mask, mirror_src=mirror_src)
 
 
@@ -171,10 +180,9 @@ class SubsetPlan:
 
     Row space: each partition p computes its own frontier rows, padded to
     a common pow2 bucket ``Rmax``; source rows are each partition's
-    universe of requested ids, padded to ``Umax``.  ``edge_pos[p, 0]``
-    indexes the LOCAL source tile (k == 0 consumes it directly);
-    ``edge_pos[p, k>0]`` indexes the ring-step recv buffer, exactly like
-    ``LayerPlan``.
+    universe of requested ids, padded to ``Umax``.  ``slot_src`` is
+    ``LayerPlan``'s table over these rows: the local source tile (Umax
+    rows) first, then R rows per ring-step recv buffer.
     """
     P: int
     fanout: int
@@ -182,10 +190,7 @@ class SubsetPlan:
     row_mask: np.ndarray      # (P, Rmax, F) bool fanout masks (False on pads)
     src_ids: np.ndarray       # (P, Umax) int64 global source ids per owner
     send_local: np.ndarray    # (P, P, R) int32 positions in sender src tile
-    edge_dst: np.ndarray      # (P, P, E) int32 local target row
-    edge_slot: np.ndarray     # (P, P, E) int32
-    edge_pos: np.ndarray      # (P, P, E) int32
-    edge_mask: np.ndarray     # (P, P, E) bool
+    slot_src: np.ndarray      # (P, Rmax, F) int32, as in LayerPlan
     take: np.ndarray          # indices of real rows in the flat (P*Rmax) out
     n_src_rows: int           # unpadded universe total (work accounting)
 
@@ -249,17 +254,11 @@ def build_subset_plan(lg: LayerGraph, rows: np.ndarray, P: int,
                 uniq_ids, pos = np.unique(ids, return_inverse=True)
                 uniq = np.searchsorted(uni[q], uniq_ids)
             req[p][k] = uniq
-            entries[p][k] = (dst_loc.astype(np.int32),
-                             slot.astype(np.int32), pos.astype(np.int32))
+            entries[p][k] = (dst_loc, slot, pos)
     R = pad_bucket(max(1, max(r.size for row in req for r in row)), floor)
-    E = pad_bucket(max(1, max(e[0].size for row in entries for e in row)),
-                   floor)
 
     send_local = np.zeros((P, P, R), np.int32)
-    edge_dst = np.zeros((P, P, E), np.int32)
-    edge_slot = np.zeros((P, P, E), np.int32)
-    edge_pos = np.zeros((P, P, E), np.int32)
-    edge_mask = np.zeros((P, P, E), bool)
+    slot_src = np.zeros((P, Rmax, F), np.int32)
     row_ids = np.zeros((P, Rmax), np.int64)
     row_mask = np.zeros((P, Rmax, F), bool)
     take = []
@@ -271,17 +270,12 @@ def build_subset_plan(lg: LayerGraph, rows: np.ndarray, P: int,
         take.append(p * Rmax + np.arange(c))
         for k in range(P):
             d, s, pos = entries[p][k]
-            m = d.size
-            edge_dst[p, k, :m] = d
-            edge_slot[p, k, :m] = s
-            edge_pos[p, k, :m] = pos
-            edge_mask[p, k, :m] = True
+            slot_src[p, d, s] = pos + (Umax + (k - 1) * R if k else 0)
             r = req[p][k]
             send_local[(p + k) % P, k, :r.size] = r
     return SubsetPlan(P=P, fanout=F, row_ids=row_ids, row_mask=row_mask,
                       src_ids=src_ids, send_local=send_local,
-                      edge_dst=edge_dst, edge_slot=edge_slot,
-                      edge_pos=edge_pos, edge_mask=edge_mask,
+                      slot_src=slot_src,
                       take=np.concatenate(take) if take else
                       np.empty(0, np.int64),
                       n_src_rows=int(sum(u.size for u in uni)))

@@ -7,6 +7,13 @@ are explicit jax.lax calls so the communication schedule is exactly the
 paper's: ring ppermute of requested feature rows (SPMM), two tiled
 all-to-alls (GEMM), edge-scalar psum (SDDMM approach (ii)).
 
+The Deal consumers read the CommPlan as a dense (row, slot) table
+(``LayerPlan.slot_src``): each masked-in fanout slot has one source row,
+in the local tile or in one ring buffer, so the SPMM, SDDMM and attention
+gather every slot's row once from [local tile; ring buffers 1..P-1] and
+reduce over the fanout in slot order.  No consumer scatters, and a row's
+bits are the same under a full-graph plan and a row-subset plan.
+
 Multi-head attention splits heads over ``model`` (``head_layout``).
 Where each shard owns whole heads (heads % M == 0) the per-head SDDMM,
 the 1/sqrt(dh) scale and the masked edge softmax over the fanout run in
@@ -21,11 +28,11 @@ edge SPMM of GCN / SAGE.
 These primitives are consumed through ``core.ops.DistExecutor`` (the
 distributed backend of the pluggable executor layer); the ``make_*_p``
 factories build jitted shard_map calls keyed only on static geometry
-(P, fanout, variant) so one compiled function serves every layer — and
-every row-subset refresh — with the same shapes.  The edge plans are
-runtime arguments, so full-graph plans (``core.partition.build_plan``)
-and frontier-subset plans (``build_subset_plan``) flow through the same
-compiled collectives.
+(P, variant, heads) so one compiled function serves every layer — and
+every row-subset refresh — with the same shapes.  The plans are runtime
+arguments ``(mask, send_local, slot_src)``, so full-graph plans
+(``core.partition.build_plan``) and frontier-subset plans
+(``build_subset_plan``) flow through the same compiled collectives.
 
 The single-host ``ref_*`` oracles are re-exported from ``kernels.ref``
 — one canonical definition shared with the Pallas kernel tests, so the
@@ -139,7 +146,7 @@ def _ring_bufs(H, send_local, P_: int):
     """Return the list of recv buffers for ring steps k = 1..P-1; buffer
     k-1 holds the rows this device requested from peer (p+k)%P.  All
     ppermutes are issued before any consumer runs — the monolithic
-    (ungrouped) communication schedule."""
+    communication schedule."""
     bufs = []
     for k in range(1, P_):
         rows = jnp.take(H, send_local[k], axis=0)
@@ -148,51 +155,56 @@ def _ring_bufs(H, send_local, P_: int):
     return bufs
 
 
-def _weigh(vals, w, dst, slot, mask):
-    """Edge rows ``vals`` (E, d_loc) times their weights: one per edge
-    for (r_loc, F) weights; for head-major (r_loc, F, c) weights, lane
-    block j of the d_loc lanes times ``w[..., j]``."""
-    if w.ndim == 2:
-        return vals * (w[dst, slot] * mask).astype(jnp.float32)[:, None]
+def _slot_rows(H, send_local, slot_src, P_: int):
+    """For each fanout slot f, the (r, d_loc) f32 rows that the target
+    rows' slot f reads through the plan's slot table from [H; ring
+    buffers 1..P-1]: one gather per slot."""
+    table = jnp.concatenate([H] + _ring_bufs(H, send_local, P_), axis=0)
+    # every id is a row of the table by construction: clip, not fill
+    return [jnp.take(table, slot_src[:, f], axis=0,
+                     mode="clip").astype(jnp.float32)
+            for f in range(slot_src.shape[1])]
+
+
+def _weigh(vals, w):
+    """Rows ``vals`` (..., d_loc) times their weights: (...) ones, or for
+    head-major (..., c) ones, lane block j of the d_loc lanes times
+    ``w[..., j]`` — selected per lane, since splitting the lanes into
+    (c, d_loc / c) would pad each block to a whole lane tile."""
+    if w.ndim < vals.ndim:
+        return vals * w[..., None]
     c = w.shape[-1]
-    we = (w[dst, slot] * mask[:, None]).astype(jnp.float32)     # (E, c)
-    return (vals.reshape(vals.shape[0], c, -1)
-            * we[:, :, None]).reshape(vals.shape)
+    head = jnp.arange(vals.shape[-1]) // (vals.shape[-1] // c)
+    lane_w = w[..., :1]
+    for j in range(1, c):
+        lane_w = jnp.where(head == j, w[..., j:j + 1], lane_w)
+    return vals * lane_w
 
 
-def _accumulate(out, w, buf, dst, slot, pos, mask):
-    vals = jnp.take(buf, pos, axis=0).astype(jnp.float32)
-    return out.at[dst].add(_weigh(vals, w, dst, slot, mask))
+def _masked(w, mask):
+    """f32 edge weights with masked-out slots zeroed: ``mask`` has w's
+    leading axes, and a head-major w one more."""
+    m = mask if w.ndim == mask.ndim else mask[..., None]
+    return (w * m).astype(jnp.float32)
 
 
-def _spmm_deal_local(H, w, send_local, edge_dst, edge_slot, edge_pos,
-                     edge_mask, *, P_: int, grouped: bool = True):
-    """DEAL SPMM: ship only requested unique rows; grouped accumulation.
+def _spmm_deal_local(H, w, mask, send_local, slot_src, *, P_: int):
+    """DEAL SPMM: ship only requested unique rows, then read each output
+    row's F slots through the plan's slot table and add them in slot
+    order — a gather and a sum, no scatter.
 
     H (u_loc, d_loc) source rows; w (r_loc, F) edge weights, or
-    head-major (r_loc, F, c) ones (``_weigh``) — output rows follow w,
-    so a frontier subset (r_loc < u_loc) runs through the same
-    compiled collective as the full graph (r_loc == u_loc).  Plan arrays
-    squeezed to this device: send_local (P, R), edge_* (P, E).
+    head-major (r_loc, F, c) ones (``_weigh``); mask (r_loc, F).  Output
+    rows follow w, so a frontier subset (r_loc < u_loc) runs through the
+    same compiled collective as the full graph (r_loc == u_loc), and a
+    row's bits do not depend on which plan produced it.  Plan arrays
+    squeezed to this device: send_local (P, R), slot_src (r_loc, F).
     """
-    d_loc = H.shape[1]
-    out = jnp.zeros((w.shape[0], d_loc), jnp.float32)
-    # group 0: local tile first (Fig 12c — covers pipeline fill)
-    out = _accumulate(out, w, H, edge_dst[0], edge_slot[0], edge_pos[0],
-                      edge_mask[0])
-    if grouped:
-        for k in range(1, P_):
-            rows = jnp.take(H, send_local[k], axis=0)
-            perm = [(i, (i - k) % P_) for i in range(P_)]
-            buf = jax.lax.ppermute(rows, "data", perm)
-            out = _accumulate(out, w, buf, edge_dst[k], edge_slot[k],
-                              edge_pos[k], edge_mask[k])
-    else:
-        # monolithic: all communication completes before any compute
-        bufs = _ring_bufs(H, send_local, P_)
-        for k in range(1, P_):
-            out = _accumulate(out, w, bufs[k - 1], edge_dst[k],
-                              edge_slot[k], edge_pos[k], edge_mask[k])
+    w = _masked(w, mask)
+    rows = _slot_rows(H, send_local, slot_src, P_)
+    out = _weigh(rows[0], w[:, 0])
+    for f in range(1, len(rows)):
+        out = out + _weigh(rows[f], w[:, f])
     return out.astype(H.dtype)
 
 
@@ -206,6 +218,11 @@ def _spmm_allgather_local(H, w, nbr, mask, *, P_: int):
     return out.astype(H.dtype)
 
 
+def _accumulate(out, w, vals, dst, slot, mask):
+    return out.at[dst].add(_weigh(vals.astype(jnp.float32),
+                                  _masked(w[dst, slot], mask)))
+
+
 def _spmm_graph_exchange_local(H, w, mirror_src, edge_dst, edge_slot,
                                edge_mask, *, P_: int):
     """'Exchange G0' baseline (§3.4): the SOURCE owner gathers per-edge rows
@@ -214,27 +231,27 @@ def _spmm_graph_exchange_local(H, w, mirror_src, edge_dst, edge_slot,
     d_loc = H.shape[1]
     out = jnp.zeros((w.shape[0], d_loc), jnp.float32)
     # k=0: mirror_src == local row ids for the local group
-    out = _accumulate(out, w, H, edge_dst[0], edge_slot[0], mirror_src[0],
-                      edge_mask[0])
+    out = _accumulate(out, w, jnp.take(H, mirror_src[0], axis=0),
+                      edge_dst[0], edge_slot[0], edge_mask[0])
     for k in range(1, P_):
         contrib = jnp.take(H, mirror_src[k], axis=0)       # (E, d_loc) dup!
         perm = [(i, (i - k) % P_) for i in range(P_)]
         buf = jax.lax.ppermute(contrib, "data", perm)
-        out = out.at[edge_dst[k]].add(_weigh(
-            buf.astype(jnp.float32), w, edge_dst[k], edge_slot[k],
-            edge_mask[k]))
+        out = _accumulate(out, w, buf, edge_dst[k], edge_slot[k],
+                          edge_mask[k])
     return out.astype(H.dtype)
 
 
-def _squeeze0(x):
-    return x[0]
-
-
-def make_spmm_p(mesh, P_: int, variant: str = "deal",
-                grouped: bool = True):
-    """Jitted SPMM keyed on static geometry only (P, variant, grouped);
-    the per-layer plan tensors are runtime arguments, so one compiled
+def make_spmm_p(mesh, P_: int, variant: str = "deal"):
+    """Jitted SPMM keyed on static geometry only (P, variant); the
+    per-layer plan tensors are runtime arguments, so one compiled
     function serves every layer and every frontier-subset plan.
+
+    The deal variant takes ``(H, w, mask, send_local, slot_src)``: all
+    ring sends go out first, then one consumer reads the local tile and
+    every ring buffer through the slot table (the paper's grouped,
+    per-step consumer (Fig 12c) would gather the whole table once per
+    ring step).
 
     The deal and graph-exchange variants also take head-major
     (N, F, c) weights, sharded over ``model`` on their last axis (c a
@@ -256,18 +273,17 @@ def make_spmm_p(mesh, P_: int, variant: str = "deal",
             return _spmm_graph_exchange_local(
                 H, w, mirror_src[0], edge_dst[0], edge_slot[0],
                 edge_mask[0], P_=P_)
-        n_plan = 4
+        plan_specs = (plan_spec,) * 4
     else:
-        def fn(H, w, send_local, edge_dst, edge_slot, edge_pos, edge_mask):
-            return _spmm_deal_local(
-                H, w, send_local[0], edge_dst[0], edge_slot[0],
-                edge_pos[0], edge_mask[0], P_=P_, grouped=grouped)
-        n_plan = 5
+        def fn(H, w, mask, send_local, slot_src):
+            return _spmm_deal_local(H, w, mask, send_local[0], slot_src[0],
+                                    P_=P_)
+        plan_specs = (P("data", None), plan_spec, plan_spec)
 
     def program(w_spec):
         return jax.jit(jax.shard_map(
             _named(fn, "dist_spmm"), mesh=mesh,
-            in_specs=(P("data", "model"), w_spec) + (plan_spec,) * n_plan,
+            in_specs=(P("data", "model"), w_spec) + plan_specs,
             out_specs=P("data", "model")))
 
     per_edge = program(P("data", None))
@@ -278,81 +294,65 @@ def make_spmm_p(mesh, P_: int, variant: str = "deal",
     return spmm
 
 
-def make_spmm(mesh, lp: LayerPlan, variant: str = "deal",
-              grouped: bool = True):
-    return make_spmm_p(mesh, lp.P, variant, grouped)
+def make_spmm(mesh, lp: LayerPlan, variant: str = "deal"):
+    return make_spmm_p(mesh, lp.P, variant)
 
 
 # ----------------------------------------------------------------------
 # SDDMM
 # ----------------------------------------------------------------------
 
-def _sddmm_deal_local(q, kf, send_local, edge_dst, edge_slot, edge_pos,
-                      edge_mask, *, P_: int, fanout: int):
+def _slot_scores(q, kf, mask, send_local, slot_src, *, P_: int, c: int):
+    """(r, F, c) dots of each row's own q with the k rows its slots read,
+    over each of the c lane blocks (sliced, not reshaped: see
+    ``_weigh``), zero on masked-out slots."""
+    dh = q.shape[1] // c
+    qf = q.astype(jnp.float32)
+
+    def scores(rows):
+        prod = rows * qf
+        return jnp.stack([prod[:, j * dh:(j + 1) * dh].sum(-1)
+                          for j in range(c)], axis=-1)
+    return jnp.stack([scores(rows) for rows in
+                      _slot_rows(kf, send_local, slot_src, P_)],
+                     axis=1) * mask[..., None]
+
+
+def _sddmm_deal_local(q, kf, mask, send_local, slot_src, *, P_: int):
     """Approach (ii): partial dots over this device's D/M slice, then psum
     the edge SCALARS over `model` (exchange results, not features)."""
-    n_loc = q.shape[0]
-    attn = jnp.zeros((n_loc, fanout), jnp.float32)
-
-    def acc(attn, buf, k):
-        part = (jnp.take(q, edge_dst[k], axis=0).astype(jnp.float32)
-                * jnp.take(buf, edge_pos[k], axis=0).astype(jnp.float32)
-                ).sum(-1)
-        part = part * edge_mask[k]
-        return attn.at[edge_dst[k], edge_slot[k]].add(part)
-
-    attn = acc(attn, kf, 0)
-    for k in range(1, P_):
-        rows = jnp.take(kf, send_local[k], axis=0)
-        perm = [(i, (i - k) % P_) for i in range(P_)]
-        buf = jax.lax.ppermute(rows, "data", perm)
-        attn = acc(attn, buf, k)
-    return jax.lax.psum(attn, "model")
+    attn = _slot_scores(q, kf, mask, send_local, slot_src, P_=P_, c=1)
+    return jax.lax.psum(attn[..., 0], "model")
 
 
-def _sddmm_dup_local(q, kf, send_local, edge_dst, edge_slot, edge_pos,
-                     edge_mask, *, P_: int, fanout: int):
+def _sddmm_dup_local(q, kf, mask, send_local, slot_src, *, P_: int):
     """Approach (i): all-gather the FULL feature columns over `model`
     (duplicate the computation), no result exchange."""
     qf = jax.lax.all_gather(q, "model", axis=1, tiled=True)   # (n_loc, D)
     kff = jax.lax.all_gather(kf, "model", axis=1, tiled=True)
-    n_loc = q.shape[0]
-    attn = jnp.zeros((n_loc, fanout), jnp.float32)
-
-    def acc(attn, buf, k):
-        part = (jnp.take(qf, edge_dst[k], axis=0).astype(jnp.float32)
-                * jnp.take(buf, edge_pos[k], axis=0).astype(jnp.float32)
-                ).sum(-1)
-        return attn.at[edge_dst[k], edge_slot[k]].add(part * edge_mask[k])
-
-    attn = acc(attn, kff, 0)
-    for k in range(1, P_):
-        rows = jnp.take(kff, send_local[k], axis=0)
-        perm = [(i, (i - k) % P_) for i in range(P_)]
-        buf = jax.lax.ppermute(rows, "data", perm)
-        attn = acc(attn, buf, k)
-    return attn
+    return _slot_scores(qf, kff, mask, send_local, slot_src, P_=P_,
+                        c=1)[..., 0]
 
 
-def make_sddmm_p(mesh, P_: int, fanout: int, variant: str = "deal"):
-    """Jitted SDDMM keyed on static geometry only (P, fanout, variant) —
-    see ``make_spmm_p``."""
+def make_sddmm_p(mesh, P_: int, variant: str = "deal"):
+    """Jitted SDDMM ``fn(q, kf, mask, send_local, slot_src)`` -> (N, F)
+    keyed on static geometry only (P, variant) — see ``make_spmm_p``."""
     local = _sddmm_deal_local if variant == "deal" else _sddmm_dup_local
     plan_spec = P("data", None, None)
 
-    def fn(q, kf, send_local, edge_dst, edge_slot, edge_pos, edge_mask):
-        return local(q, kf, send_local[0], edge_dst[0], edge_slot[0],
-                     edge_pos[0], edge_mask[0], P_=P_, fanout=fanout)
+    def fn(q, kf, mask, send_local, slot_src):
+        return local(q, kf, mask, send_local[0], slot_src[0], P_=P_)
     # approach (i) duplicates the computation, so its output is replicated
     # over `model` by construction — not statically inferable (check_vma).
     return jax.jit(jax.shard_map(
         _named(fn, "dist_sddmm"), mesh=mesh,
-        in_specs=(P("data", "model"), P("data", "model")) + (plan_spec,) * 5,
+        in_specs=(P("data", "model"), P("data", "model"), P("data", None),
+                  plan_spec, plan_spec),
         out_specs=P("data", None), check_vma=(variant == "deal")))
 
 
 def make_sddmm(mesh, lp: LayerPlan, variant: str = "deal"):
-    return make_sddmm_p(mesh, lp.P, lp.fanout, variant)
+    return make_sddmm_p(mesh, lp.P, variant)
 
 
 # ----------------------------------------------------------------------
@@ -371,51 +371,35 @@ def head_layout(heads: int, M: int) -> int:
                      f"of {M}: one must divide the other")
 
 
-def _head_scores_local(q, kf, send_local, edge_dst, edge_slot, edge_pos,
-                       edge_mask, *, P_: int, fanout: int, heads: int,
-                       M: int):
+def _head_scores_local(q, kf, mask, send_local, slot_src, *, P_: int,
+                       heads: int, M: int):
     """Per-head dot scores (n_loc, F, c) from this shard's lanes of q
-    and of the exchanged k rows.  With whole heads here (c = heads / M)
-    no collective; a head spanning M / heads shards psums its partial
-    edge scalars over ``model`` (approach (ii)), c = heads."""
-    n_loc, d_loc = q.shape
-    c = head_layout(heads, M)
-    attn = jnp.zeros((n_loc, fanout, c), jnp.float32)
-
-    def acc(attn, buf, k):
-        qe = jnp.take(q, edge_dst[k], axis=0).astype(jnp.float32)
-        ke = jnp.take(buf, edge_pos[k], axis=0).astype(jnp.float32)
-        part = (qe * ke).reshape(-1, c, d_loc // c).sum(-1)
-        part = part * edge_mask[k][:, None]
-        return attn.at[edge_dst[k], edge_slot[k]].add(part)
-
-    attn = acc(attn, kf, 0)
-    for k in range(1, P_):
-        rows = jnp.take(kf, send_local[k], axis=0)
-        perm = [(i, (i - k) % P_) for i in range(P_)]
-        buf = jax.lax.ppermute(rows, "data", perm)
-        attn = acc(attn, buf, k)
+    and of the exchanged k rows, zero on masked-out slots.  With whole
+    heads here (c = heads / M) no collective; a head spanning M / heads
+    shards psums its partial edge scalars over ``model`` (approach
+    (ii)), c = heads."""
+    attn = _slot_scores(q, kf, mask, send_local, slot_src, P_=P_,
+                        c=head_layout(heads, M))
     if heads >= M:
         return attn
+    full = jnp.zeros(attn.shape[:2] + (heads,), jnp.float32)
     head = jax.lax.axis_index("model") // (M // heads)
-    full = jnp.zeros((n_loc, fanout, heads), jnp.float32)
     full = jax.lax.dynamic_update_slice_in_dim(full, attn, head, axis=2)
     return jax.lax.psum(full, "model")
 
 
-def _gat_attention_local(q, kf, mask, *plan, P_: int, fanout: int,
-                         heads: int, M: int, softmax: bool):
+def _gat_attention_local(q, kf, mask, *plan, P_: int, heads: int, M: int,
+                         softmax: bool):
     dh = q.shape[1] * M // heads
-    s = _head_scores_local(q, kf, *plan, P_=P_, fanout=fanout,
-                           heads=heads, M=M) / jnp.sqrt(jnp.float32(dh))
+    s = _head_scores_local(q, kf, mask, *plan, P_=P_, heads=heads,
+                           M=M) / jnp.sqrt(jnp.float32(dh))
     return edge_softmax(s, mask > 0) if softmax else s
 
 
-def make_gat_attention_p(mesh, P_: int, fanout: int, heads: int,
-                         softmax: bool = True):
-    """Jitted per-head attention keyed on static geometry (P, fanout,
-    heads): ``fn(q, kf, mask_f, *deal_plan_args)`` -> (N, F, heads),
-    the scaled scores, normalized over each row's masked-in slots when
+def make_gat_attention_p(mesh, P_: int, heads: int, softmax: bool = True):
+    """Jitted per-head attention keyed on static geometry (P, heads):
+    ``fn(q, kf, mask_f, send_local, slot_src)`` -> (N, F, heads), the
+    scaled scores, normalized over each row's masked-in slots when
     ``softmax``.  Sharded over ``model`` on the heads axis where each
     shard owns whole heads, replicated over it where a head spans
     shards.  Named ``dist_gat_attention`` (``dist_sddmm`` unfused)."""
@@ -423,15 +407,15 @@ def make_gat_attention_p(mesh, P_: int, fanout: int, heads: int,
     head_layout(heads, M)               # raises on a pair it cannot split
     plan_spec = P("data", None, None)
 
-    def fn(q, kf, mask, *plan):
-        return _gat_attention_local(q, kf, mask, *(a[0] for a in plan),
-                                    P_=P_, fanout=fanout, heads=heads,
-                                    M=M, softmax=softmax)
+    def fn(q, kf, mask, send_local, slot_src):
+        return _gat_attention_local(q, kf, mask, send_local[0], slot_src[0],
+                                    P_=P_, heads=heads, M=M,
+                                    softmax=softmax)
     name = "dist_gat_attention" if softmax else "dist_sddmm"
     return jax.jit(jax.shard_map(
         _named(fn, name), mesh=mesh,
         in_specs=(P("data", "model"), P("data", "model"),
-                  P("data", None)) + (plan_spec,) * 5,
+                  P("data", None), plan_spec, plan_spec),
         out_specs=P("data", None, "model" if heads >= M else None)))
 
 
@@ -447,5 +431,5 @@ def plan_device_arrays(lp: LayerPlan, sharding=None) -> Dict[str, Any]:
     put = (jnp.asarray if sharding is None
            else functools.partial(jax.device_put, device=sharding))
     return {name: put(getattr(lp, name))
-            for name in ("send_local", "edge_dst", "edge_slot", "edge_pos",
+            for name in ("send_local", "slot_src", "edge_dst", "edge_slot",
                          "edge_mask", "mirror_src")}

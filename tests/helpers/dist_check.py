@@ -65,8 +65,9 @@ def main():
     ws = jax.device_put(jnp.asarray(w), NamedSharding(mesh, P("data", None)))
     want = prim.ref_spmm(jnp.asarray(X), jnp.asarray(w),
                          jnp.asarray(lgs[0].nbr), jnp.asarray(lgs[0].mask))
-    deal_args = (dev["send_local"], dev["edge_dst"], dev["edge_slot"],
-                 dev["edge_pos"], dev["edge_mask"])
+    mask_f = jax.device_put(jnp.asarray(lgs[0].mask, jnp.float32),
+                            NamedSharding(mesh, P("data", None)))
+    deal_args = (mask_f, dev["send_local"], dev["slot_src"])
     for variant in ("deal", "graph_exchange", "allgather"):
         sp = prim.make_spmm(mesh, lp, variant)
         if variant == "allgather":
@@ -79,10 +80,6 @@ def main():
         else:
             got = sp(Xs, ws, *deal_args)
         check(f"spmm/{variant}", got, want)
-
-    # ungrouped (monolithic comm) variant must also be exact
-    sp_mono = prim.make_spmm(mesh, lp, "deal", grouped=False)
-    check("spmm/deal-ungrouped", sp_mono(Xs, ws, *deal_args), want)
 
     q = rng.standard_normal((N, D), dtype=np.float32)
     qs = jax.device_put(jnp.asarray(q), hd)
@@ -419,6 +416,122 @@ def case_heads1_bitwise_parent():
     return same, f"bitwise={same}"
 
 
+def _edge_lists(lg, lp):
+    """The edge-list form of one layer's plan, as the ring consumers read
+    it before the slot table: per (device p, ring step k) the masked-in
+    edges' target row, slot, row in [H_local | recv buffer k] (the
+    plan's ``send_local`` order) and mask, padded to one length."""
+    P_, n = lp.P, lp.n_local
+    groups = {}
+    for p in range(P_):
+        nbr, mask = lg.nbr[p * n:(p + 1) * n], lg.mask[p * n:(p + 1) * n]
+        for k in range(P_):
+            q = (p + k) % P_
+            d, s = np.nonzero(mask & (nbr // n == q))
+            ids = nbr[d, s] - q * n
+            if k:
+                ids = np.searchsorted(
+                    lp.send_local[q, k, :lp.send_count[q, k]], ids)
+            groups[p, k] = (d, s, ids)
+    E = max(g[0].size for g in groups.values())
+    out = [np.zeros((P_, P_, E), np.int32) for _ in range(3)] + \
+        [np.zeros((P_, P_, E), bool)]
+    for (p, k), g in groups.items():
+        for a, v in zip(out, g + (True,)):
+            a[p, k, :g[0].size] = v
+    return [jnp.asarray(a) for a in out]
+
+
+def _scatter_programs(mesh, P_, heads, fanout):
+    """Test-local copies of the edge-list consumers the slot table
+    replaced: each group's rows scatter-added into their target rows
+    (per-edge and head-major SPMM) and slots (per-head scores, scaled
+    as ``dist_gat_attention`` scales them)."""
+    M = mesh.shape["model"]
+    c = heads // M
+    plan = P("data", None, None)
+
+    def spmm(H, w, send_local, dst, slot, pos, emask):
+        send_local, dst, slot, pos, emask = (
+            a[0] for a in (send_local, dst, slot, pos, emask))
+        out = jnp.zeros((w.shape[0], H.shape[1]), jnp.float32)
+        bufs = [H] + prim._ring_bufs(H, send_local, P_)
+        for k, buf in enumerate(bufs):
+            vals = jnp.take(buf, pos[k], axis=0).astype(jnp.float32)
+            if w.ndim == 2:
+                we = (w[dst[k], slot[k]] * emask[k]).astype(jnp.float32)
+                vals = vals * we[:, None]
+            else:
+                we = (w[dst[k], slot[k]]
+                      * emask[k][:, None]).astype(jnp.float32)
+                vals = (vals.reshape(vals.shape[0], we.shape[1], -1)
+                        * we[:, :, None]).reshape(vals.shape)
+            out = out.at[dst[k]].add(vals)
+        return out.astype(H.dtype)
+
+    def scores(q, kf, send_local, dst, slot, pos, emask):
+        send_local, dst, slot, pos, emask = (
+            a[0] for a in (send_local, dst, slot, pos, emask))
+        n_loc, d_loc = q.shape
+        attn = jnp.zeros((n_loc, fanout, c), jnp.float32)
+        bufs = [kf] + prim._ring_bufs(kf, send_local, P_)
+        for k, buf in enumerate(bufs):
+            qe = jnp.take(q, dst[k], axis=0).astype(jnp.float32)
+            ke = jnp.take(buf, pos[k], axis=0).astype(jnp.float32)
+            part = (qe * ke).reshape(-1, c, d_loc // c).sum(-1)
+            attn = attn.at[dst[k], slot[k]].add(part * emask[k][:, None])
+        return attn / jnp.sqrt(jnp.float32(d_loc // c))
+
+    hd = P("data", "model")
+
+    def prog(fn, w_spec, out_spec):
+        return jax.jit(jax.shard_map(fn, mesh=mesh,
+                                     in_specs=(hd, w_spec) + (plan,) * 5,
+                                     out_specs=out_spec))
+    return (prog(spmm, P("data", None), hd),
+            prog(spmm, P("data", None, "model"), hd),
+            prog(scores, hd, P("data", None, "model")))
+
+
+def case_dense_vs_scatter(p, m, heads=4):
+    """The slot-table SPMM (per-edge and head-major weights) and per-head
+    scores against test-local copies of the edge-list scatter form they
+    replaced, on one sampled layer.  Tolerance 1e-5 (values O(1-10)):
+    the same f32 products, summed over each row's slots in slot order
+    instead of ring-group order, differ by a few ulps; a wrong row,
+    slot or head errs by O(1)."""
+    N, D, F = 256, 64, 8
+    mesh = make_host_mesh(p, m)
+    src, dst = rmat_edges(N, N * 8, seed=1)
+    lg = sample_layer_graphs(csr_from_edges(src, dst, N), fanout=F,
+                             n_layers=1, seed=0)[0]
+    lp = build_plan([lg], p, m).layers[0]
+    dev = prim.plan_device_arrays(lp)
+    edges = [dev["send_local"]] + _edge_lists(lg, lp)
+    rng = np.random.default_rng(31)
+    put = lambda x, *spec: jax.device_put(  # noqa: E731
+        jnp.asarray(x), NamedSharding(mesh, P(*spec)))
+    H = put(rng.standard_normal((N, D), dtype=np.float32), "data", "model")
+    q = put(rng.standard_normal((N, D), dtype=np.float32), "data", "model")
+    w = put(mean_weights(lg.mask), "data", None)
+    alpha = put(rng.random((N, F, heads), dtype=np.float32),
+                "data", None, "model")
+    deal = (put(lg.mask.astype(np.float32), "data", None),
+            dev["send_local"], dev["slot_src"])
+    spmm = prim.make_spmm_p(mesh, p)
+    old_spmm, old_spmm_hm, old_scores = _scatter_programs(mesh, p, heads,
+                                                          F)
+    attn = prim.make_gat_attention_p(mesh, p, heads, softmax=False)
+    pairs = {"spmm": (spmm(H, w, *deal), old_spmm(H, w, *edges)),
+             "spmm_head_major": (spmm(H, alpha, *deal),
+                                 old_spmm_hm(H, alpha, *edges)),
+             "scores": (attn(q, H, *deal), old_scores(q, H, *edges))}
+    errs = {k: float(np.abs(np.asarray(a) - np.asarray(b)).max())
+            for k, (a, b) in pairs.items()}
+    return max(errs.values()) <= 1e-5, " ".join(
+        f"{k}={v:.2e}" for k, v in errs.items())
+
+
 def case_unfused_scores(heads, p, m):
     """The unfused per-head path (``attn_scores`` -> ``edge_softmax``,
     kept for specs that read raw scores) against the fused
@@ -494,6 +607,18 @@ def case_plan_built_once():
         f"heads_local={m.get('dist.attn_heads_local')}"
 
 
+def case_slot_fill_reported():
+    """``bind`` reports each layer's masked-in share of the slot table
+    (the dense gather's real edges) as ``dist.slot_fill.layer<l>``."""
+    from repro.api import Session
+    with Session.build(_gat_cfg(4, 2, 2, telemetry=True)) as s:
+        s.infer_all()
+        m = s.telemetry.metrics.to_dict()
+        want = [float(lg.mask.mean()) for lg in s.layer_graphs[:2]]
+    got = [m.get(f"dist.slot_fill.layer{l}") for l in range(2)]
+    return got == want and 0 < min(want) <= 1, f"got={got} want={want}"
+
+
 def gat_main():
     cases = [
         ("session_vs_ref/heads4_p2_m2",
@@ -510,6 +635,10 @@ def gat_main():
         ("delta_bitwise/heads4",
          lambda: case_delta_bitwise(make_host_mesh(4, 2), 4)),
         ("plan_built_once", case_plan_built_once),
+        ("dense_vs_scatter/p2_m2", lambda: case_dense_vs_scatter(2, 2)),
+        ("dense_vs_scatter/p4_m2", lambda: case_dense_vs_scatter(4, 2)),
+        ("dense_vs_scatter/p2_m4", lambda: case_dense_vs_scatter(2, 4)),
+        ("slot_fill_reported", case_slot_fill_reported),
     ]
     for name, fn in cases:
         hb.beat(name)

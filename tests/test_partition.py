@@ -23,25 +23,46 @@ def test_edge_coverage(P, M, layer_graphs):
         assert np.array_equal(covered, lg.mask)
 
 
+def _full_tables(lg, P):
+    """(slot_src, mask, target ids, real-row flags, global ids of [local
+    tile; ring buffers]) per partition of ``build_plan``."""
+    lp = build_plan([lg], P, 1).layers[0]
+    n = lp.n_local
+    for p in range(P):
+        bufs = [lp.send_local[(p + k) % P, k] + (p + k) % P * n
+                for k in range(1, P)]
+        yield (lp.slot_src[p], lg.mask[p * n:(p + 1) * n],
+               np.arange(p * n, (p + 1) * n), np.ones(n, bool),
+               np.concatenate([np.arange(p * n, (p + 1) * n)] + bufs))
+
+
+def _subset_tables(lg, P):
+    """The same for a ``build_subset_plan`` over every third row."""
+    from repro.core.partition import build_subset_plan
+    sp = build_subset_plan(lg, np.arange(0, lg.n_nodes, 3), P)
+    Rmax = sp.row_ids.shape[1]
+    for p in range(P):
+        bufs = [sp.src_ids[(p + k) % P][sp.send_local[(p + k) % P, k]]
+                for k in range(1, P)]
+        yield (sp.slot_src[p], sp.row_mask[p], sp.row_ids[p],
+               np.isin(p * Rmax + np.arange(Rmax), sp.take),
+               np.concatenate([sp.src_ids[p]] + bufs))
+
+
+@pytest.mark.parametrize("tables", [_full_tables, _subset_tables],
+                         ids=["build_plan", "build_subset_plan"])
 @pytest.mark.parametrize("P", [2, 4])
-def test_recv_buffer_resolves_to_right_rows(P, layer_graphs):
-    """edge_pos into the (sent) request buffer must reproduce the global
-    neighbor id."""
-    plan = build_plan(layer_graphs, P, 1)
-    n_local = plan.layers[0].n_local
-    for li, lp in enumerate(plan.layers):
-        lg = layer_graphs[li]
-        for p in range(P):
-            for k in range(1, P):
-                q = (p + k) % P
-                # rows sender q ships to p at step k:
-                cnt = lp.send_count[q, k]
-                buf_global = lp.send_local[q, k][:cnt] + q * n_local
-                m = lp.edge_mask[p, k]
-                got = buf_global[lp.edge_pos[p, k][m]]
-                want = lg.nbr[lp.edge_dst[p, k][m] + p * n_local,
-                              lp.edge_slot[p, k][m]]
-                assert np.array_equal(got, want)
+def test_recv_buffer_resolves_to_right_rows(P, tables, layer_graphs):
+    """Every masked-in slot of ``slot_src`` addresses, in [local tile;
+    ring buffers 1..P-1] as ``send_local`` fills them, the row of global
+    node ``nbr[row, slot]``; every other slot reads row 0 and is masked
+    (as the graph masks it, and wholly on a pad row)."""
+    for lg in layer_graphs:
+        for slot_src, mask, ids, real, table in tables(lg, P):
+            assert np.array_equal(mask[real], lg.mask[ids[real]])
+            assert not mask[~real].any()
+            assert np.array_equal(table[slot_src][mask], lg.nbr[ids][mask])
+            assert not slot_src[~mask].any()
 
 
 def test_unique_rows_fewer_than_edges(layer_graphs):
@@ -79,7 +100,7 @@ def test_subset_plan_cache_hits_and_invalidation(layer_graphs):
     # cached plan is the real plan
     fresh = build_subset_plan(lg, rows, 4)
     np.testing.assert_array_equal(p1.row_ids, fresh.row_ids)
-    np.testing.assert_array_equal(p1.edge_pos, fresh.edge_pos)
+    np.testing.assert_array_equal(p1.slot_src, fresh.slot_src)
     np.testing.assert_array_equal(p1.send_local, fresh.send_local)
 
     # different frontier or geometry -> different cache slot
@@ -133,6 +154,6 @@ def test_plan_shapes_do_not_depend_on_the_graph_seed():
     (a, raw_a), (b, raw_b) = plan_and_counts(11), plan_and_counts(12)
     assert raw_a != raw_b
     for la, lb in zip(a.layers, b.layers):
-        for name in ("send_local", "edge_dst", "edge_slot", "edge_pos",
+        for name in ("send_local", "slot_src", "edge_dst", "edge_slot",
                      "edge_mask", "mirror_src"):
             assert getattr(la, name).shape == getattr(lb, name).shape
