@@ -5,11 +5,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro.core.gnn_models import init_gat, init_gcn
+from repro.core.gnn_models import init_gat, init_gcn, model_spec
 from repro.core.graph import csr_from_edges, planted_partition
-from repro.core.layerwise import local_gat_infer, local_gcn_infer
+from repro.core.ops import RefExecutor, run_model
 from repro.core.sampler import sample_layer_graphs
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
+
+
+def _engine(model):
+    """Deal's all-node forward pass of ``model`` on the jnp oracle."""
+    ex = RefExecutor()
+
+    def infer(lgs, X, params):
+        spec = model_spec(model, params)
+        return run_model(ex, spec, ex.bind(lgs, spec), X)
+    return infer
 
 
 def _accuracy(H, labels, train_mask):
@@ -56,9 +66,8 @@ def run(smoke: bool = False):
     dims = [n_comm, 32, n_comm]
 
     for model, engine, init_fn in (
-            ("gcn", local_gcn_infer, init_gcn),
-            ("gat", lambda l, x, p: local_gat_infer(l, x, p),
-             lambda k, d: init_gat(k, d, heads=4))):
+            ("gcn", _engine("gcn"), init_gcn),
+            ("gat", _engine("gat"), lambda k, d: init_gat(k, d, heads=4))):
         params, loss = _train(engine, init_fn, full, X, labels, train_mask,
                               dims, steps=steps)
         acc_full = _accuracy(engine(full, X, params), labels, train_mask)

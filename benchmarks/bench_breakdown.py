@@ -22,14 +22,16 @@ def run(smoke: bool = False):
             lambda: sample_layer_graphs(g, fanout=8, n_layers=3, seed=0),
             iters=1)
         t_par, plan = time_host(lambda: build_plan(lgs, 4, 2), iters=1)
-        from repro.core.gnn_models import init_gcn
-        from repro.core.layerwise import local_gcn_infer
+        from repro.core.gnn_models import init_gcn, model_spec
+        from repro.core.ops import RefExecutor, run_model
         import jax
         X = np.random.default_rng(0).standard_normal((n, D),
                                                      dtype=np.float32)
         params = init_gcn(jax.random.PRNGKey(0), [D, D, D, D])
+        ex, spec = RefExecutor(), model_spec("gcn", params)
         t_inf, _ = time_host(
-            lambda: np.asarray(local_gcn_infer(lgs, X, params)), iters=1)
+            lambda: np.asarray(run_model(ex, spec, ex.bind(lgs, spec), X)),
+            iters=1)
         total = t_con + t_sam + t_par + t_inf
         emit(f"fig3a/breakdown/{name}", total * 1e6,
              f"construct={t_con/total:.0%};sample={t_sam/total:.0%};"

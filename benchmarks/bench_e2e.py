@@ -18,8 +18,8 @@ _DATASETS = ("ogbn-products", "social-spammer", "ogbn-papers100M")
 _DIST_SCRIPT = r"""
 import numpy as np, jax, time
 from repro.core.graph import csr_from_edges, make_dataset, truncate_to_multiple
-from repro.core.gnn_models import init_gat, init_gcn
-from repro.core.layerwise import DistributedLayerwise, LOCAL_ENGINES
+from repro.core.gnn_models import init_gat, init_gcn, model_spec
+from repro.core.ops import DistExecutor, RefExecutor, run_model
 from repro.core.sampler import sample_layer_graphs
 from repro.launch.mesh import make_host_mesh
 
@@ -37,15 +37,18 @@ for name in datasets:
     for model, init in (("gcn", init_gcn),
                         ("gat", lambda k, d: init_gat(k, d, heads=1))):
         params = init(jax.random.PRNGKey(0), [D, D, D, D])
-        eng = DistributedLayerwise(mesh, lgs, model, params)
-        jax.block_until_ready(eng.infer(X))
+        spec = model_spec(model, params)
+        ex = DistExecutor(mesh)
+        ios = ex.bind(lgs, spec)
+        jax.block_until_ready(run_model(ex, spec, ios, X))
         ts = []
         for _ in range(1 if SMOKE else 3):
             t0 = time.perf_counter()
-            out = jax.block_until_ready(eng.infer(X))
+            out = jax.block_until_ready(run_model(ex, spec, ios, X))
             ts.append(time.perf_counter() - t0)
         t = sorted(ts)[len(ts) // 2]
-        want = np.asarray(LOCAL_ENGINES[model](lgs, X, params))
+        ref = RefExecutor()
+        want = np.asarray(run_model(ref, spec, ref.bind(lgs, spec), X))
         err = float(np.abs(np.asarray(out) - want).max())
         assert err < 5e-4, (model, name, err)
         print(f"CSV,fig14/e2e_{model}/{name}/deal_dist,{t*1e6:.1f},"
@@ -53,12 +56,20 @@ for name in datasets:
 """
 
 
-def _err_vs_ref(engine, lgs, X, params, got, executor, tag):
+def _epoch(executor, model, lgs, X, params):
+    """One all-node forward pass through a registered executor."""
+    from repro.core.gnn_models import model_spec
+    from repro.core.ops import get_executor, run_model
+    ex, spec = get_executor(executor), model_spec(model, params)
+    return np.asarray(run_model(ex, spec, ex.bind(lgs, spec), X))
+
+
+def _err_vs_ref(model, lgs, X, params, got, executor, tag):
     """Non-ref executors must land within tolerance of the jnp oracle;
     return the derived-column suffix recording how close they came."""
     if executor == "ref":
         return ""
-    want = np.asarray(engine(lgs, X, params))
+    want = _epoch("ref", model, lgs, X, params)
     e = float(np.abs(got - want).max())
     assert e < 5e-4, (tag, e)
     return f";max_err_vs_ref={e:.2e}"
@@ -72,8 +83,7 @@ def run(smoke: bool = False, executor: str = "ref"):
     import jax
 
     from repro.core.gnn_models import init_gat, init_gcn
-    from repro.core.layerwise import (ego_batched_gcn_infer, local_gat_infer,
-                                      local_gcn_infer)
+    from repro.core.layerwise import ego_batched_gcn_infer
     suffix = "" if executor == "ref" else f"_{executor}"
     scale = 0.05 if smoke else 0.5
     iters = 1 if smoke else 3
@@ -87,11 +97,8 @@ def run(smoke: bool = False, executor: str = "ref"):
 
         pg = init_gcn(jax.random.PRNGKey(0), [D, D, D, D])
         t_deal, got = time_host(
-            lambda: np.asarray(local_gcn_infer(lgs, X, pg,
-                                               executor=executor)),
-            iters=iters)
-        err = _err_vs_ref(local_gcn_infer, lgs, X, pg, got, executor,
-                          (name, "gcn"))
+            lambda: _epoch(executor, "gcn", lgs, X, pg), iters=iters)
+        err = _err_vs_ref("gcn", lgs, X, pg, got, executor, (name, "gcn"))
         # paper: memory caps the baseline batch at ~6% of nodes
         bs = max(64, int(0.06 * n))
         t_ego, (out, work) = time_host(
@@ -105,28 +112,10 @@ def run(smoke: bool = False, executor: str = "ref"):
 
         pa = init_gat(jax.random.PRNGKey(1), [D, D, D, D], heads=4)
         t_gat, got = time_host(
-            lambda: np.asarray(local_gat_infer(lgs, X, pa,
-                                               executor=executor)),
-            iters=iters)
-        err = _err_vs_ref(local_gat_infer, lgs, X, pa, got, executor,
-                          (name, "gat"))
+            lambda: _epoch(executor, "gat", lgs, X, pa), iters=iters)
+        err = _err_vs_ref("gat", lgs, X, pa, got, executor, (name, "gat"))
         # GAT baseline modeled by GCN row-redundancy ratio (same frontiers,
-        # more primitives per row — see EXPERIMENTS.md).  On non-ref
-        # backends the modeled baseline additionally runs the SAME
-        # backend with the kernel fusions off (per-head scoring + a
-        # separate softmax pass — the standard ego-batched pipeline), so
-        # modeled_speedup = ratio x t_unfused/t_fused shows what the
-        # fused attention path buys on top of the row-redundancy win.
-        ratio = work / (3 * n)
-        modeled = ratio
-        if executor != "ref":
-            from repro.core.ops import get_executor
-            unfused = get_executor(executor, fused_attention=False,
-                                   fused_gather=False)
-            t_unf, _ = time_host(
-                lambda: np.asarray(local_gat_infer(lgs, X, pa,
-                                                   executor=unfused)),
-                iters=iters)
-            modeled = ratio * t_unf / t_gat
+        # more primitives per row — see EXPERIMENTS.md)
+        modeled = work / (3 * n)
         emit(f"fig14/e2e_gat/{name}/deal{suffix}", t_gat * 1e6,
              f"modeled_speedup={modeled:.2f}x{err}")

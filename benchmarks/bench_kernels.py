@@ -1,4 +1,4 @@
-"""Kernel-level fused-vs-unfused microbenchmarks + the autotuner driver.
+"""Kernel-level fused-vs-unfused microbenchmarks.
 
 For each fusion the tentpole added, time the fused kernel against the
 exact unfused pipeline it replaces (same blocks, same dtypes, same
@@ -12,13 +12,10 @@ the fusion buys:
                  sddmm calls + stack/scale + masked softmax (the (N, F)
                  score round-trip).
 
-Before timing, ``tuning.ensure_tuned`` resolves the block sizes for
-every (kernel, shape-bucket) this bench touches — searching the
-candidate grid on a table miss (or under ``REPRO_TUNING=autotune``) and
-persisting winners to ``configs/tuned_blocks.json``, the same table
-``PallasExecutor(block_table="default")`` consults.  Off-TPU the kernels
-run in interpret mode, so absolute numbers are emulation speed; the
-fused-vs-unfused ratio and the tuned winners are still the artifact.
+Every kernel runs at its default blocks (row block ``auto_block_n``,
+128-lane feature block), the blocks ``PallasExecutor`` passes.  Off-TPU
+the kernels run in interpret mode, so absolute numbers are emulation
+speed; the fused-vs-unfused ratio is still the artifact.
 """
 import numpy as np
 
@@ -37,26 +34,20 @@ def _world(rng, N, U, D, F, dtype):
     return h, table, w, nbr, mask
 
 
-def _bench_gather_spmm(table_blocks, N, D, F, iters, timer_repeats):
+def _bench_gather_spmm(N, D, F, iters):
     import jax.numpy as jnp
 
-    from repro import tuning
     from repro.kernels import ops as kops
 
     rng = np.random.default_rng(0)
     h, table, w, nbr, mask = _world(rng, N, N, D, F, jnp.float32)
 
-    def make_call(blocks):
-        return lambda: kops.gather_spmm(h, table, w, nbr, mask,
-                                        use_kernel=True, **blocks)
-
-    blocks = tuning.ensure_tuned(table_blocks, "gather_spmm", make_call,
-                                 N=N, D=D, repeats=timer_repeats)
-    fused = make_call(blocks)
+    def fused():
+        return kops.gather_spmm(h, table, w, nbr, mask, use_kernel=True)
 
     def unfused():
         return kops.spmm(jnp.take(h, table, axis=0), w, nbr, mask,
-                         use_kernel=True, **blocks)
+                         use_kernel=True)
 
     from repro import obs
     with obs.span("kernels.gather_spmm") as sp:
@@ -64,18 +55,15 @@ def _bench_gather_spmm(table_blocks, N, D, F, iters, timer_repeats):
         t_u = time_fn(unfused, iters=iters)
         if sp:
             sp.set(n=N, fused_us=t_f * 1e6, unfused_us=t_u * 1e6)
-    blk = ";".join(f"{k}={v}" for k, v in sorted(blocks.items()))
     emit(f"kernels/gather_spmm/n{N}", t_f * 1e6,
-         f"unfused_us={t_u * 1e6:.1f};speedup={t_u / t_f:.2f}x;{blk}")
+         f"unfused_us={t_u * 1e6:.1f};speedup={t_u / t_f:.2f}x")
     np.testing.assert_array_equal(np.asarray(fused()),
                                   np.asarray(unfused()))
 
 
-def _bench_gat_attention(table_blocks, N, D, F, heads, iters,
-                         timer_repeats):
+def _bench_gat_attention(N, D, F, heads, iters):
     import jax.numpy as jnp
 
-    from repro import tuning
     from repro.core.gnn_models import masked_softmax
     from repro.kernels import ops as kops
 
@@ -84,20 +72,16 @@ def _bench_gat_attention(table_blocks, N, D, F, heads, iters,
     k = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
     dh = D // heads
 
-    def make_call(blocks):
-        return lambda: kops.gat_attention(q, k, nbr, mask, heads=heads,
-                                          use_kernel=True, **blocks)
-
-    blocks = tuning.ensure_tuned(table_blocks, "gat_attention", make_call,
-                                 N=N, D=dh, repeats=timer_repeats)
-    fused = make_call(blocks)
+    def fused():
+        return kops.gat_attention(q, k, nbr, mask, heads=heads,
+                                  use_kernel=True)
 
     def unfused():
         # the pre-fusion pipeline: one sddmm kernel per head, stack,
         # scale, then a separate masked-softmax pass over the scores
         per_head = [kops.sddmm(q[:, h * dh:(h + 1) * dh],
                                k[:, h * dh:(h + 1) * dh], nbr, mask,
-                               use_kernel=True, **blocks)
+                               use_kernel=True)
                     for h in range(heads)]
         s = jnp.stack(per_head, axis=-1) / jnp.sqrt(jnp.float32(dh))
         alpha = masked_softmax(s.transpose(0, 2, 1),
@@ -111,25 +95,19 @@ def _bench_gat_attention(table_blocks, N, D, F, heads, iters,
         if sp:
             sp.set(n=N, heads=heads, fused_us=t_f * 1e6,
                    unfused_us=t_u * 1e6)
-    blk = ";".join(f"{k}={v}" for k, v in sorted(blocks.items()))
     emit(f"kernels/gat_attention/n{N}/h{heads}", t_f * 1e6,
-         f"unfused_us={t_u * 1e6:.1f};speedup={t_u / t_f:.2f}x;{blk}")
+         f"unfused_us={t_u * 1e6:.1f};speedup={t_u / t_f:.2f}x")
     np.testing.assert_allclose(np.asarray(fused()), np.asarray(unfused()),
                                atol=2e-5, rtol=3e-3)
 
 
 def run(smoke: bool = False):
-    from repro import tuning
-    table = tuning.BlockTable.load()        # configs/tuned_blocks.json
     iters = 1 if smoke else 3
-    repeats = 1 if smoke else 3
     heads = 4
     shapes = [(256, 64)] if smoke else [(256, 64), (1024, 128)]
     for N, D in shapes:
-        _bench_gather_spmm(table, N, D, FANOUT, iters, repeats)
-        _bench_gat_attention(table, N, D, FANOUT, heads, iters, repeats)
-    emit("kernels/tuned_table", len(table.entries),
-         f"path={table.path.name};keys={len(table.entries)}")
+        _bench_gather_spmm(N, D, FANOUT, iters)
+        _bench_gat_attention(N, D, FANOUT, heads, iters)
 
 
 if __name__ == "__main__":

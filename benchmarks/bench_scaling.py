@@ -7,10 +7,22 @@ SMOKE = @SMOKE@
 import numpy as np, jax, jax.numpy as jnp, time
 from repro.core.graph import (csr_from_edges, rmat_edges, make_dataset,
                               truncate_to_multiple)
-from repro.core.gnn_models import init_gcn
-from repro.core.layerwise import DistributedLayerwise
+from repro.core.gnn_models import init_gcn, model_spec
+from repro.core.ops import DistExecutor, run_model
 from repro.core.sampler import sample_layer_graphs
 from repro.launch.mesh import make_host_mesh
+
+def epoch_s(mesh, lgs, X, params):
+    # median of 3 warm GCN epochs on the mesh, seconds
+    ex, spec = DistExecutor(mesh), model_spec("gcn", params)
+    ios = ex.bind(lgs, spec)
+    jax.block_until_ready(run_model(ex, spec, ios, X))
+    ts = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run_model(ex, spec, ios, X))
+        ts.append(time.perf_counter() - t0)
+    return sorted(ts)[1]
 
 def bench(n, e, Pg, M, seed=0, name=""):
     src, dst = rmat_edges(n, e, seed=seed)
@@ -20,13 +32,7 @@ def bench(n, e, Pg, M, seed=0, name=""):
     D = 64
     X = np.random.default_rng(0).standard_normal((n, D), dtype=np.float32)
     params = init_gcn(jax.random.PRNGKey(0), [D, D, D, D])
-    eng = DistributedLayerwise(mesh, lgs, "gcn", params)
-    jax.block_until_ready(eng.infer(X))
-    ts = []
-    for _ in range(3):
-        t0 = time.perf_counter(); jax.block_until_ready(eng.infer(X))
-        ts.append(time.perf_counter() - t0)
-    t = sorted(ts)[1]
+    t = epoch_s(mesh, lgs, X, params)
     eps = g.n_edges / t / (Pg * M)
     print(f"CSV,fig15/{name},{t*1e6:.1f},edges_per_s_per_dev={eps:.0f};edges={g.n_edges}")
 
@@ -46,14 +52,7 @@ for name in ("ogbn-products",) if SMOKE else ("ogbn-products",
     X = np.random.default_rng(0).standard_normal((n, D), dtype=np.float32)
     params = init_gcn(jax.random.PRNGKey(0), [D, D, D, D])
     for Pg in (2,) if SMOKE else (2, 4, 8):
-        mesh = make_host_mesh(Pg, 1)
-        eng = DistributedLayerwise(mesh, lgs, "gcn", params)
-        jax.block_until_ready(eng.infer(X))
-        ts = []
-        for _ in range(3):
-            t0 = time.perf_counter(); jax.block_until_ready(eng.infer(X))
-            ts.append(time.perf_counter() - t0)
-        t = sorted(ts)[1]
+        t = epoch_s(make_host_mesh(Pg, 1), lgs, X, params)
         print(f"CSV,fig15/strong/{name}/p{Pg},{t*1e6:.1f},edges={g.n_edges}")
 """
 

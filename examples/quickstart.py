@@ -8,9 +8,9 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 
-from repro.core.gnn_models import init_gcn
+from repro.core.gnn_models import init_gcn, model_spec
 from repro.core.graph import csr_from_edges, rmat_edges
-from repro.core.layerwise import local_gcn_infer
+from repro.core.ops import get_executor, run_model
 from repro.core.sampler import sample_layer_graphs
 from repro.kernels import ops
 
@@ -27,7 +27,8 @@ print(f"sampled {len(lgs)} layer graphs, fanout {lgs[0].fanout}")
 # 3. a 3-layer GCN, inferred for every node in one layer-by-layer pass
 X = np.random.default_rng(0).standard_normal((1024, 64), dtype=np.float32)
 params = init_gcn(jax.random.PRNGKey(0), [64, 64, 64, 32])
-H = local_gcn_infer(lgs, X, params)
+ex, spec = get_executor("ref"), model_spec("gcn", params)
+H = run_model(ex, spec, ex.bind(lgs, spec), X)
 print(f"embeddings for all nodes: {H.shape}, finite={bool(np.isfinite(np.asarray(H)).all())}")
 
 # 4. the Pallas SPMM kernel (TPU target, interpret-validated on CPU)

@@ -79,26 +79,11 @@ class PartitionSpec:
 @dataclasses.dataclass
 class ExecutorSpec:
     """Backend selection + the construction/validation logic that used
-    to be copy-pasted across every launcher.
-
-    ``fused_gather`` / ``block_table`` are first-class pallas kernel
-    knobs (the fused gather+spmm path and the autotuned block-size
-    table source — "default" = ``configs/tuned_blocks.json``); left at
-    None they are omitted entirely, so executors that don't take them
-    never see them."""
+    to be copy-pasted across every launcher.  Each executor has one
+    path: kernels and routes are not configured here."""
     name: str = "ref"               # a registered executor
     fallback_to_ref: bool = True    # dist on a trivial (p*m <= 1) mesh
     options: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    fused_gather: Optional[bool] = None
-    block_table: Optional[str] = None
-
-    def _options(self) -> Dict[str, Any]:
-        opts = dict(self.options)
-        if self.fused_gather is not None:
-            opts.setdefault("fused_gather", self.fused_gather)
-        if self.block_table is not None:
-            opts.setdefault("block_table", self.block_table)
-        return opts
 
     def build(self, partition: Optional[PartitionSpec] = None, *,
               n_nodes: Optional[int] = None):
@@ -114,7 +99,7 @@ class ExecutorSpec:
                 f"registered: {', '.join(_reg.EXECUTORS.names())}")
         from repro.core.ops import get_executor
         if self.name != "dist":
-            return get_executor(self.name, **self._options())
+            return get_executor(self.name, **self.options)
 
         part = partition or PartitionSpec()
         p, m = part.p, part.m
@@ -475,14 +460,6 @@ class DealConfig:
         if not isinstance(ex.options, dict):
             e.append("executor.options: must be a dict, got "
                      f"{type(ex.options).__name__}")
-        if ex.fused_gather is not None and not isinstance(
-                ex.fused_gather, bool):
-            e.append("executor.fused_gather: must be a bool or None, "
-                     f"got {ex.fused_gather!r}")
-        if ex.block_table is not None and not isinstance(
-                ex.block_table, str):
-            e.append("executor.block_table: must be a str or None, "
-                     f"got {ex.block_table!r}")
 
         if st.n_shards < 1:
             e.append(f"store.n_shards: must be >= 1, got {st.n_shards}")

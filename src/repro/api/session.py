@@ -20,7 +20,7 @@ worlds — which is what makes the deprecation shims in the launchers
 exactly equivalent to the code they replaced.
 
 ``infer_all`` runs the canonical full-graph path (``run_model`` over
-the bound executor — op-for-op the pre-API launcher computation);
+the executor's own ``bind`` — the one forward driver every caller uses);
 ``serve`` builds its store from ``DeltaReinference.full_levels`` (the
 delta engine's level layout), exactly as the serving launcher always
 did.
@@ -60,9 +60,9 @@ class Session:
         # THIS session's hits/misses, not every session in the process
         from repro.core.partition import install_plan_cache_counters
         self._plan_cache_counters = install_plan_cache_counters()
-        self._build_pipeline()
+        with obs.span("session.build"):
+            self._build_pipeline()
         self._H: Optional[np.ndarray] = None
-        self._dist_bound = None      # (layer graphs, DistIOs) of infer_all
         self._n_epochs = 0           # infer_all epochs run, for span attrs
         self._engine = None
         self._endpoint = None
@@ -128,15 +128,15 @@ class Session:
     # -- offline: all-node inference ------------------------------------
     def infer_all(self) -> np.ndarray:
         """One full layer-by-layer epoch for ALL nodes through the bound
-        executor.  Cached; bitwise-identical to the pre-API launcher
-        path (same spec interpreter, same graph bindings)."""
+        executor: ``run_model`` over ``executor.bind`` (the executor owns
+        its graph bindings; the mesh keeps its CommPlan while the layer
+        graphs are the same objects).  Cached until ``_H`` is reset."""
         self._check_open()
         if self._H is not None:
             return self._H
         from repro.core.gnn_models import model_spec
-        from repro.core.ops import DenseIO, DistExecutor, run_model
+        from repro.core.ops import run_model
         spec = model_spec(self.cfg.model.name, self.params)
-        lgs = self.layer_graphs[:len(spec.layers)]
         ex = self.executor
         t0 = time.perf_counter()
         self._n_epochs += 1
@@ -146,10 +146,7 @@ class Session:
             if sp:
                 sp.set(model=self.cfg.model.name, epoch=self._n_epochs)
             with obs.span("infer.bind"):
-                if isinstance(ex, DistExecutor):
-                    ios = self._dist_ios(ex, spec, lgs)
-                else:
-                    ios = [DenseIO.from_layer_graph(lg) for lg in lgs]
+                ios = ex.bind(self.layer_graphs, spec)
             with obs.span("infer.forward"):
                 H = run_model(ex, spec, ios, self.X)
             with obs.span("infer.fetch"):
@@ -160,19 +157,6 @@ class Session:
                 sp.set(rows=int(self._H.shape[0]))
         self.timings["infer_s"] = time.perf_counter() - t0
         return self._H
-
-    def _dist_ios(self, ex, spec, lgs):
-        """The mesh bindings (CommPlan and its device arrays), built on
-        the first epoch and kept while the layer graphs are the same
-        objects (the delta engine resamples its own copies)."""
-        bound = self._dist_bound
-        if bound is None or len(bound[0]) != len(lgs) or any(
-                a is not b for a, b in zip(bound[0], lgs)):
-            need_sddmm = any(op.kind == "attn_scores"
-                             for layer in spec.layers for op in layer.ops)
-            bound = (list(lgs), ex.bind(lgs, need_sddmm=need_sddmm))
-            self._dist_bound = bound
-        return bound[1]
 
     # -- online: store + serving engine ---------------------------------
     def serve(self):
@@ -432,7 +416,7 @@ class Session:
         self._closed = True
         self._engine = None
         for name in ("X", "graph", "layer_graphs", "reinfer", "_H",
-                     "_dist_bound", "src", "dst", "params", "executor"):
+                     "src", "dst", "params", "executor"):
             if hasattr(self, name):
                 setattr(self, name, None)
 

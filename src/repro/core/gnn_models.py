@@ -8,7 +8,7 @@ feature dim, so the distributed engine gives each `model` shard whole heads
 (heads % M == 0) or a whole share of one (M % heads == 0).
 
 Each model's per-layer math is defined ONCE, as a sequence of declarative
-layer ops (gemm / spmm / attn_scores / edge_softmax / attend / add) over
+layer ops (gemm / spmm / attn_scores_softmax / attend / add) over
 two input slots — ``h_tgt`` (rows being produced) and ``h_src`` (rows
 being aggregated from; identical to ``h_tgt`` in full-graph inference,
 the gathered universe in row-subset delta refresh).  ``core.ops``
@@ -107,7 +107,7 @@ def gat_head_scores(q, kf, nbr, mask, heads: int):
 class LayerOp:
     """One declarative op inside a layer program.
 
-    kind     gemm | spmm | add | attn_scores | edge_softmax | attend
+    kind     gemm | spmm | add | attn_scores_softmax | attend
     out      env slot written
     src      env slots read ("h_tgt"/"h_src" are the layer inputs)
     param    weight matrix (gemm only)
@@ -169,8 +169,7 @@ def _gat_spec(params: Dict[str, Any]) -> ModelSpec:
         LayerOp("gemm", "q", ("h_tgt",), p["wq"]),
         LayerOp("gemm", "k", ("h_src",), p["wk"]),
         LayerOp("gemm", "v", ("h_src",), p["wv"]),
-        LayerOp("attn_scores", "s", ("q", "k")),
-        LayerOp("edge_softmax", "alpha", ("s",)),
+        LayerOp("attn_scores_softmax", "alpha", ("q", "k")),
         LayerOp("attend", "h", ("alpha", "v")),
     )) for p in params["layers"]]
     return ModelSpec("gat", layers, heads=int(params.get("heads", 1)),

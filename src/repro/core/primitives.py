@@ -25,8 +25,10 @@ head-major weights (r_loc, F, c): lane block j of a shard takes
 ``w[..., j]``; with plain (r_loc, F) weights it is the one-weight-per-
 edge SPMM of GCN / SAGE.
 
-These primitives are consumed through ``core.ops.DistExecutor`` (the
-distributed backend of the pluggable executor layer); the ``make_*_p``
+The Deal primitives are consumed through ``core.ops.DistExecutor`` (the
+distributed backend of the executor layer); the baseline variants serve
+the paper's comparisons only (``benchmarks/bench_primitives_dist.py``
+and the distributed tests).  The ``make_*_p``
 factories build jitted shard_map calls keyed only on static geometry
 (P, variant, heads) so one compiled function serves every layer — and
 every row-subset refresh — with the same shapes.  The plans are runtime
@@ -388,32 +390,29 @@ def _head_scores_local(q, kf, mask, send_local, slot_src, *, P_: int,
     return jax.lax.psum(full, "model")
 
 
-def _gat_attention_local(q, kf, mask, *plan, P_: int, heads: int, M: int,
-                         softmax: bool):
+def _gat_attention_local(q, kf, mask, *plan, P_: int, heads: int, M: int):
     dh = q.shape[1] * M // heads
     s = _head_scores_local(q, kf, mask, *plan, P_=P_, heads=heads,
                            M=M) / jnp.sqrt(jnp.float32(dh))
-    return edge_softmax(s, mask > 0) if softmax else s
+    return edge_softmax(s, mask > 0)
 
 
-def make_gat_attention_p(mesh, P_: int, heads: int, softmax: bool = True):
+def make_gat_attention_p(mesh, P_: int, heads: int):
     """Jitted per-head attention keyed on static geometry (P, heads):
     ``fn(q, kf, mask_f, send_local, slot_src)`` -> (N, F, heads), the
-    scaled scores, normalized over each row's masked-in slots when
-    ``softmax``.  Sharded over ``model`` on the heads axis where each
-    shard owns whole heads, replicated over it where a head spans
-    shards.  Named ``dist_gat_attention`` (``dist_sddmm`` unfused)."""
+    scaled scores normalized over each row's masked-in slots.  Sharded
+    over ``model`` on the heads axis where each shard owns whole heads,
+    replicated over it where a head spans shards.  Named
+    ``dist_gat_attention``."""
     M = mesh.shape["model"]
     head_layout(heads, M)               # raises on a pair it cannot split
     plan_spec = P("data", None, None)
 
     def fn(q, kf, mask, send_local, slot_src):
         return _gat_attention_local(q, kf, mask, send_local[0], slot_src[0],
-                                    P_=P_, heads=heads, M=M,
-                                    softmax=softmax)
-    name = "dist_gat_attention" if softmax else "dist_sddmm"
+                                    P_=P_, heads=heads, M=M)
     return jax.jit(jax.shard_map(
-        _named(fn, name), mesh=mesh,
+        _named(fn, "dist_gat_attention"), mesh=mesh,
         in_specs=(P("data", "model"), P("data", "model"),
                   P("data", None), plan_spec, plan_spec),
         out_specs=P("data", None, "model" if heads >= M else None)))

@@ -4,7 +4,7 @@ Architecture overview
 =====================
 
 The offline pipeline (graph -> layer-wise sampling -> partition ->
-``DistributedLayerwise``) produces embeddings for ALL nodes.  gnnserve
+``run_model``) produces embeddings for ALL nodes.  gnnserve
 turns that batch artifact into an online service that stays fresh as the
 graph mutates, without re-running full epochs:
 

@@ -1,8 +1,8 @@
 """Pallas TPU kernel: fused SDDMM + masked edge softmax (GAT attention).
 
-The unfused GAT spec runs one SDDMM kernel call PER HEAD (a Python loop
-round-tripping each (N, F) score slice through HBM), stacks the slices,
-scales, and then runs a separate masked-softmax op.  This kernel
+It is ``PallasExecutor``'s ``attn_scores_softmax``, the GAT spec's one
+attention op.  Where per-head SDDMM calls would round-trip each (N, F)
+score slice through HBM before a separate masked softmax, this kernel
 produces the normalized attention alpha (N, F, heads) in ONE pass per
 node block: gather each edge's k row once, compute ALL heads' scaled
 dot scores in VMEM, and normalize over the fanout axis before anything
@@ -15,8 +15,8 @@ A head's dot is a lane reduction of q * k_f over that head's lanes (the
 others masked to 0.0, which adds exactly).
 The kernel writes head-major (heads, N, F) tiles; the wrapper transposes
 to (N, F, heads).  The math is op-for-op ``ref.gat_attention_ref`` (same
-f32 dots, same /sqrt(dh), same -1e30 masked fill, same softmax), so
-fused and unfused paths verify against the same oracle.
+f32 dots, same /sqrt(dh), same -1e30 masked fill, same softmax), the
+same math as ``RefExecutor.attn_scores_softmax``.
 """
 from __future__ import annotations
 

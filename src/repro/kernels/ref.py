@@ -71,8 +71,8 @@ def gat_attention_ref(q, k, nbr, mask, heads: int):
 
     Matches ``gnn_models.gat_head_scores`` -> ``masked_softmax``
     op-for-op (same f32 dot, same /sqrt(dh), same -1e30 fill, same
-    softmax), so the fused Pallas kernel and the unfused two-op spec
-    path verify against the same math.
+    softmax), so the Pallas kernel and ``RefExecutor`` verify against
+    the same math.
     """
     N, D = q.shape
     dh = D // heads

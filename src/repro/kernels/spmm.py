@@ -37,8 +37,8 @@ def auto_block_n(n: int) -> int:
 
     The kernels grid over n // block_n, so block_n must divide n; callers
     pad n to a multiple of 8 first (f32 sublane tile), which this floors
-    to.  Shared by spmm / sddmm / gather_spmm / gat_attention as the
-    default when no tuned block table overrides it.
+    to.  The row block of spmm / sddmm / gather_spmm / gat_attention
+    when the caller passes none (``PallasExecutor`` never does).
     """
     for bn in (64, 32, 16, 8):
         if n % bn == 0:

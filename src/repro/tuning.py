@@ -1,8 +1,7 @@
-"""Perf-iteration flags + the Pallas block-size autotuner.
+"""Perf-iteration flags for the LM stack.
 
-Flags (EXPERIMENTS.md §Perf): each hillclimb is a named flag so baseline
-vs optimized lower from the SAME code path; the dry-run runs twice and
-records both:
+Each hillclimb is a named flag so baseline vs optimized lower from the
+SAME code path; the dry-run runs twice and records both:
 
   REPRO_TUNING=mla_cache_rep,moe_ep,cp_decode python -m repro.launch.dryrun ...
 
@@ -17,40 +16,11 @@ records both:
                  (m, l, acc) psum over the KV shards instead of
                  all-gathering the cache (DEAL SPMM's "ship the small
                  partials" applied to attention).
-  autotune       force the block-size search to re-run even when
-                 ``configs/tuned_blocks.json`` already has an entry for
-                 the (kernel, backend, dtype, shape-bucket) key.
-
-Autotuner: the Pallas kernels in ``kernels/`` take ``block_n``/``block_d``
-tile sizes whose best values depend on shape, dtype and backend.  A
-``BlockTable`` maps ``(kernel, backend, dtype, shape-bucket)`` keys to the
-winning blocks; ``ensure_tuned`` times the candidate grid for a key once
-and persists the winner to ``configs/tuned_blocks.json``, which
-``PallasExecutor(block_table="default")`` consults at bind time.  Block
-sizes only change the grid decomposition, never the per-row accumulation
-order, so tuned vs untuned outputs are bitwise identical — the table is a
-pure perf knob.
 """
 from __future__ import annotations
 
-import json
 import os
-import time
-from pathlib import Path
-from typing import Any, Callable, Dict, Optional, Set
-
-DEFAULT_TABLE_PATH = (Path(__file__).resolve().parents[2]
-                      / "configs" / "tuned_blocks.json")
-
-# candidate tile grids per kernel — small on purpose: the search is
-# O(grid) kernel compilations per (shape-bucket, dtype, backend) key
-KERNEL_GRIDS: Dict[str, Dict[str, tuple]] = {
-    "spmm": {"block_n": (8, 16, 32, 64), "block_d": (128, 256)},
-    "gather_spmm": {"block_n": (8, 16, 32, 64), "block_d": (128, 256)},
-    "sddmm": {"block_n": (8, 16, 32, 64)},
-    "gat_attention": {"block_n": (8, 16, 32, 64)},
-    "flash_attention": {"block_q": (64, 128), "block_k": (64, 128)},
-}
+from typing import Set
 
 
 def flags() -> Set[str]:
@@ -59,185 +29,3 @@ def flags() -> Set[str]:
 
 def on(name: str) -> bool:
     return name in flags()
-
-
-def autotune_forced() -> bool:
-    """REPRO_TUNING=autotune invalidates persisted winners."""
-    return on("autotune")
-
-
-def shape_bucket(n: int) -> int:
-    """Pow2 shape bucket (floor 8) — one table entry serves every shape
-    that pads to the same power of two, matching the pow2 padding the
-    executors/benches already use for compile-cache reuse."""
-    b = 8
-    while b < n:
-        b *= 2
-    return b
-
-
-def _backend() -> str:
-    import jax
-    return jax.default_backend()
-
-
-def table_key(kernel: str, backend: str, dtype: str, N: int,
-              D: int) -> str:
-    return (f"{kernel}/{backend}/{dtype}"
-            f"/n{shape_bucket(N)}/d{shape_bucket(D)}")
-
-
-class BlockTable:
-    """Persisted (kernel, backend, dtype, shape-bucket) -> blocks map.
-
-    JSON format (``configs/tuned_blocks.json``)::
-
-        {"spmm/cpu/float32/n4096/d128":
-             {"block_n": 32, "block_d": 128, "us": 512.3}, ...}
-
-    ``us`` is the winning median time — informational, ignored by
-    lookup.  Unknown keys simply miss (callers fall back to the
-    ``auto_block_n`` defaults), so stale tables degrade gracefully.
-    """
-
-    def __init__(self, entries: Optional[Dict[str, Dict]] = None,
-                 path: Optional[os.PathLike] = None):
-        self.entries: Dict[str, Dict] = dict(entries or {})
-        self.path = Path(path) if path is not None else DEFAULT_TABLE_PATH
-
-    @classmethod
-    def load(cls, path: Optional[os.PathLike] = None) -> "BlockTable":
-        p = Path(path) if path is not None else DEFAULT_TABLE_PATH
-        entries: Dict[str, Dict] = {}
-        if p.exists():
-            entries = json.loads(p.read_text())
-        return cls(entries, path=p)
-
-    def save(self, path: Optional[os.PathLike] = None) -> Path:
-        p = Path(path) if path is not None else self.path
-        p.parent.mkdir(parents=True, exist_ok=True)
-        p.write_text(json.dumps(self.entries, indent=1, sort_keys=True)
-                     + "\n")
-        return p
-
-    def lookup(self, kernel: str, *, N: int, D: int = 128,
-               dtype: str = "float32",
-               backend: Optional[str] = None) -> Optional[Dict]:
-        key = table_key(kernel, backend or _backend(), dtype, N, D)
-        got = self.entries.get(key)
-        if got is None:
-            return None
-        return {k: v for k, v in got.items() if k.startswith("block_")}
-
-    def put(self, kernel: str, *, N: int, D: int = 128,
-            dtype: str = "float32", blocks: Dict[str, int],
-            us: Optional[float] = None,
-            backend: Optional[str] = None) -> str:
-        key = table_key(kernel, backend or _backend(), dtype, N, D)
-        entry = dict(blocks)
-        if us is not None:
-            entry["us"] = round(float(us), 1)
-        self.entries[key] = entry
-        return key
-
-
-def resolve_block_table(spec) -> Optional[BlockTable]:
-    """ExecutorSpec ``block_table`` knob -> a BlockTable (or None).
-
-    None/"none" -> no table (auto blocks only); "default" -> the
-    persistent repo table (empty when the file is missing); any other
-    string -> that JSON path; a BlockTable instance passes through.
-    """
-    if spec is None or spec == "none":
-        return None
-    if isinstance(spec, BlockTable):
-        return spec
-    if spec == "default":
-        return BlockTable.load()
-    return BlockTable.load(spec)
-
-
-def candidates(kernel: str, N: int, D: Optional[int] = None):
-    """Candidate block dicts for one kernel, pruned to blocks that can
-    tile the pow2-padded row bucket (and, when ``D`` is given, feature
-    widths that divide D — falling back to ``block_d=D`` for narrow
-    features none of the stock widths tile)."""
-    grid = KERNEL_GRIDS[kernel]
-    names = list(grid)
-    combos = [{}]
-    for name in names:
-        combos = [dict(c, **{name: v}) for c in combos
-                  for v in grid[name]]
-    if "block_n" in grid:
-        bucket = shape_bucket(N)
-        combos = [c for c in combos if bucket % c["block_n"] == 0]
-    if D is not None and "block_d" in grid:
-        viable = [c for c in combos if D % c["block_d"] == 0]
-        if not viable:
-            seen: Dict[tuple, Dict] = {}
-            for c in combos:
-                c = dict(c, block_d=D)
-                seen[tuple(sorted(c.items()))] = c
-            viable = list(seen.values())
-        combos = viable
-    return combos
-
-
-def _default_timer(fn: Callable[[], Any], repeats: int = 3) -> float:
-    """Median wall-clock seconds of ``fn()`` (fn must block on its
-    result, e.g. via jax.block_until_ready)."""
-    ts = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        fn()
-        ts.append(time.perf_counter() - t0)
-    ts.sort()
-    return ts[len(ts) // 2]
-
-
-def autotune_op(table: BlockTable, kernel: str, make_call: Callable,
-                *, N: int, D: int = 128, dtype: str = "float32",
-                timer: Optional[Callable] = None,
-                repeats: int = 3) -> Dict[str, int]:
-    """Time every candidate block combo and record the winner.
-
-    ``make_call(blocks) -> zero-arg callable`` builds the kernel
-    invocation for one combo; combos whose warmup call raises are
-    skipped (e.g. a tile too large for the shape).  ``timer(fn,
-    repeats) -> seconds`` is injectable so tests can search without
-    timing real kernels.
-    """
-    timer = timer or _default_timer
-    best_t, best_blocks = None, None
-    for blocks in candidates(kernel, N, D):
-        fn = make_call(blocks)
-        try:
-            fn()                                     # warmup / compile
-        except Exception:
-            continue
-        t = timer(fn, repeats)
-        if best_t is None or t < best_t:
-            best_t, best_blocks = t, blocks
-    if best_blocks is None:
-        raise ValueError(f"no viable block candidates for {kernel} "
-                         f"(N={N}, D={D})")
-    table.put(kernel, N=N, D=D, dtype=dtype, blocks=best_blocks,
-              us=best_t * 1e6)
-    return best_blocks
-
-
-def ensure_tuned(table: BlockTable, kernel: str, make_call: Callable,
-                 *, N: int, D: int = 128, dtype: str = "float32",
-                 timer: Optional[Callable] = None,
-                 repeats: int = 3) -> Dict[str, int]:
-    """Return the tuned blocks for a key, searching (and persisting to
-    the table's path) only on a miss — or always when
-    ``REPRO_TUNING=autotune`` forces a re-search."""
-    if not autotune_forced():
-        got = table.lookup(kernel, N=N, D=D, dtype=dtype)
-        if got:
-            return got
-    blocks = autotune_op(table, kernel, make_call, N=N, D=D, dtype=dtype,
-                         timer=timer, repeats=repeats)
-    table.save()
-    return blocks

@@ -20,15 +20,20 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core import primitives as prim  # noqa: E402
-from repro.core.gnn_models import (init_gat, init_gcn,  # noqa: E402
-                                   init_sage, mean_weights)
+from repro.core.gnn_models import (edge_softmax, init_gat,  # noqa: E402
+                                   init_gcn, init_sage, mean_weights,
+                                   model_spec)
 from repro.core.graph import csr_from_edges, rmat_edges  # noqa: E402
-from repro.core.layerwise import (DistributedLayerwise,  # noqa: E402
-                                  local_gat_infer, local_gcn_infer,
-                                  local_sage_infer)
+from repro.core.ops import DistExecutor, RefExecutor, run_model  # noqa: E402
 from repro.core.partition import build_plan  # noqa: E402
 from repro.core.sampler import sample_layer_graphs  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
+
+
+def epoch(ex, model, params, lgs, X):
+    """One all-node forward pass of ``model`` through ``ex``."""
+    spec = model_spec(model, params)
+    return run_model(ex, spec, ex.bind(lgs, spec), X)
 
 
 def check(name, got, want, atol=2e-5):
@@ -90,23 +95,13 @@ def main():
         sd = prim.make_sddmm(mesh, lp, variant)
         check(f"sddmm/{variant}", sd(qs, Xs, *deal_args), want_e, 2e-4)
 
-    pg = init_gcn(jax.random.PRNGKey(0), [D, 64, 32])
-    eng = DistributedLayerwise(mesh, lgs, "gcn", pg)
-    check("engine/gcn", eng.infer(X), local_gcn_infer(lgs, X, pg), 5e-5)
-
-    pa = init_gat(jax.random.PRNGKey(1), [D, 64, 32], heads=1)
-    eng2 = DistributedLayerwise(mesh, lgs, "gat", pa)
-    check("engine/gat", eng2.infer(X), local_gat_infer(lgs, X, pa), 5e-5)
-    # sddmm must keep its deal-style plan args even when the spmm
-    # variant changes (regression: gat + graph_exchange)
-    eng2b = DistributedLayerwise(mesh, lgs, "gat", pa,
-                                 spmm_variant="graph_exchange")
-    check("engine/gat-graph_exchange", eng2b.infer(X),
-          local_gat_infer(lgs, X, pa), 5e-5)
-
-    ps = init_sage(jax.random.PRNGKey(2), [D, 64, 32])
-    eng3 = DistributedLayerwise(mesh, lgs, "sage", ps)
-    check("engine/sage", eng3.infer(X), local_sage_infer(lgs, X, ps), 5e-5)
+    dex, ref = DistExecutor(mesh), RefExecutor()
+    for model, params in (
+            ("gcn", init_gcn(jax.random.PRNGKey(0), [D, 64, 32])),
+            ("gat", init_gat(jax.random.PRNGKey(1), [D, 64, 32], heads=1)),
+            ("sage", init_sage(jax.random.PRNGKey(2), [D, 64, 32]))):
+        check(f"engine/{model}", epoch(dex, model, params, lgs, X),
+              epoch(ref, model, params, lgs, X), 5e-5)
 
     check_dist_delta(mesh, g, lgs, X)
     check_evict_equivalence(mesh, g, lgs, X)
@@ -123,7 +118,6 @@ def check_dist_delta(mesh, g, lgs, X):
     """
     import copy
 
-    from repro.core.ops import DistExecutor
     from repro.gnnserve import (DeltaReinference, MutationLog,
                                 apply_edge_mutations, store_from_inference)
 
@@ -182,7 +176,6 @@ def check_evict_equivalence(mesh, g, lgs, X):
     """
     import copy
 
-    from repro.core.ops import DistExecutor
     from repro.gnnserve import (DeltaReinference, MutationLog,
                                 apply_edge_mutations, attach_recompute,
                                 store_from_inference)
@@ -250,7 +243,6 @@ def check_chunked_refresh(mesh, g, lgs, X):
     produced a row's bits."""
     import copy
 
-    from repro.core.ops import DistExecutor
     from repro.gnnserve import (DeltaReinference, MutationLog,
                                 apply_edge_mutations, store_from_inference)
 
@@ -297,7 +289,6 @@ def check_tail_onboarding(mesh, g, lgs, X):
     (``full_epoch`` is the oracle AND the fold)."""
     import copy
 
-    from repro.core.ops import DistExecutor
     from repro.gnnserve import (DeltaReinference, EmbeddingServeEngine,
                                 store_from_inference)
 
@@ -368,18 +359,19 @@ def _infer(cfg):
 
 
 def case_session_vs_ref(heads, p, m):
-    """DealConfig -> Session.infer_all on the mesh against RefExecutor
-    and ``local_gat_infer`` on the same seeded world.  Tolerance 5e-5
-    (max|H| ~2.5): f32 sums in another order, the per-head dot over its
-    lanes (split over shards and psummed where a head spans them) and
-    the attend's ring accumulation over the fanout, against the
-    oracle's einsums; a wrong head split errs by O(1)."""
+    """DealConfig -> Session.infer_all on the mesh against RefExecutor,
+    in a Session and by ``run_model`` alone, on the same seeded world.
+    Tolerance 5e-5 (max|H| ~2.5): f32 sums in another order, the
+    per-head dot over its lanes (split over shards and psummed where a
+    head spans them) and the attend's ring accumulation over the
+    fanout, against the oracle's einsums; a wrong head split errs by
+    O(1)."""
     from repro.api import Session
     got = _infer(_gat_cfg(heads, p, m))
     ref = _infer(_gat_cfg(heads, p, m, executor="ref"))
     with Session.build(_gat_cfg(heads, p, m, executor="ref")) as s:
-        local = np.asarray(local_gat_infer(s.layer_graphs[:2], s.X,
-                                           s.params))
+        local = np.asarray(epoch(RefExecutor(), "gat", s.params,
+                                 s.layer_graphs, s.X))
     err = max(np.abs(got - ref).max(), np.abs(got - local).max())
     return err <= 5e-5, f"max_err={err:.2e} max|H|={np.abs(ref).max():.2f}"
 
@@ -388,29 +380,23 @@ def case_heads1_bitwise_parent():
     """heads=1 keeps the pre-per-head distributed GAT bit for bit: the
     same Session world through a test-local copy of the earlier
     full-width score (one dot over all D lanes, psum over ``model``,
-    / sqrt(D)) with the unfused score -> softmax -> attend ops."""
+    / sqrt(D)), then the masked softmax and the plain ring attend."""
     from repro.api import Session
     from repro.core.gnn_models import masked_softmax
-    from repro.core.ops import DistExecutor
 
     class FullWidthScores(DistExecutor):
-        attn_scores_softmax = None          # no fused peephole
-
-        def attn_scores(self, q, k, io, heads):
+        def attn_scores_softmax(self, q, k, io, heads):
             assert self.M % heads == 0
-            scores = io.sddmm(q, k, *io.sddmm_args)
-            return scores / np.sqrt(q.shape[1])
-
-        def edge_softmax(self, s, io):
-            return masked_softmax(s, io.mask_f > 0)
+            scores = self._sddmm(q, k, *io.args) / np.sqrt(q.shape[1])
+            return masked_softmax(scores, io.mask_f > 0)
 
         def attend(self, alpha, v, io, heads):
-            return io.spmm(v, alpha, *io.args)
+            return self._spmm(v, alpha, *io.args)
 
     with Session.build(_gat_cfg(1, 2, 2)) as s:
         got = np.array(s.infer_all())
         s.executor = FullWidthScores(s.executor.mesh)
-        s._H = s._dist_bound = None
+        s._H = None
         want = np.array(s.infer_all())
     same = bool((got == want).all())
     return same, f"bitwise={same}"
@@ -495,11 +481,12 @@ def _scatter_programs(mesh, P_, heads, fanout):
 
 def case_dense_vs_scatter(p, m, heads=4):
     """The slot-table SPMM (per-edge and head-major weights) and per-head
-    scores against test-local copies of the edge-list scatter form they
-    replaced, on one sampled layer.  Tolerance 1e-5 (values O(1-10)):
-    the same f32 products, summed over each row's slots in slot order
-    instead of ring-group order, differ by a few ulps; a wrong row,
-    slot or head errs by O(1)."""
+    attention against test-local copies of the edge-list scatter form
+    they replaced (its scores through the same edge softmax), on one
+    sampled layer.  Tolerance 1e-5 (values O(1-10)): the same f32
+    products, summed over each row's slots in slot order instead of
+    ring-group order, differ by a few ulps; a wrong row, slot or head
+    errs by O(1)."""
     N, D, F = 256, 64, 8
     mesh = make_host_mesh(p, m)
     src, dst = rmat_edges(N, N * 8, seed=1)
@@ -521,35 +508,17 @@ def case_dense_vs_scatter(p, m, heads=4):
     spmm = prim.make_spmm_p(mesh, p)
     old_spmm, old_spmm_hm, old_scores = _scatter_programs(mesh, p, heads,
                                                           F)
-    attn = prim.make_gat_attention_p(mesh, p, heads, softmax=False)
+    attn = prim.make_gat_attention_p(mesh, p, heads)
+    mask = jnp.asarray(lg.mask)
     pairs = {"spmm": (spmm(H, w, *deal), old_spmm(H, w, *edges)),
              "spmm_head_major": (spmm(H, alpha, *deal),
                                  old_spmm_hm(H, alpha, *edges)),
-             "scores": (attn(q, H, *deal), old_scores(q, H, *edges))}
+             "attention": (attn(q, H, *deal),
+                           edge_softmax(old_scores(q, H, *edges), mask))}
     errs = {k: float(np.abs(np.asarray(a) - np.asarray(b)).max())
             for k, (a, b) in pairs.items()}
     return max(errs.values()) <= 1e-5, " ".join(
         f"{k}={v:.2e}" for k, v in errs.items())
-
-
-def case_unfused_scores(heads, p, m):
-    """The unfused per-head path (``attn_scores`` -> ``edge_softmax``,
-    kept for specs that read raw scores) against the fused
-    ``dist_gat_attention``.  Tolerance 1e-6 (max|H| ~2.5): the same
-    scores and softmax, inside one program or split over two."""
-    from repro.api import Session
-    from repro.core.ops import DistExecutor
-
-    class Unfused(DistExecutor):
-        attn_scores_softmax = None
-
-    with Session.build(_gat_cfg(heads, p, m)) as s:
-        fused = np.array(s.infer_all())
-        s.executor = Unfused(s.executor.mesh)
-        s._H = s._dist_bound = None
-        got = np.array(s.infer_all())
-    err = np.abs(got - fused).max()
-    return err <= 1e-6, f"max_err={err:.2e}"
 
 
 def case_delta_bitwise(mesh, heads):
@@ -558,7 +527,6 @@ def case_delta_bitwise(mesh, heads):
     same executor."""
     import copy
 
-    from repro.core.ops import DistExecutor
     from repro.gnnserve import (DeltaReinference, MutationLog,
                                 apply_edge_mutations, store_from_inference)
     src, dst = rmat_edges(256, 256 * 8, seed=1)
@@ -628,10 +596,6 @@ def gat_main():
         ("session_vs_ref/heads2_p2_m4",
          lambda: case_session_vs_ref(2, 2, 4)),
         ("heads1_bitwise_parent", case_heads1_bitwise_parent),
-        ("unfused_scores/heads4_p2_m2",
-         lambda: case_unfused_scores(4, 2, 2)),
-        ("unfused_scores/heads2_p2_m4",
-         lambda: case_unfused_scores(2, 2, 4)),
         ("delta_bitwise/heads4",
          lambda: case_delta_bitwise(make_host_mesh(4, 2), 4)),
         ("plan_built_once", case_plan_built_once),

@@ -85,13 +85,19 @@ def test_validation_names_every_bad_field():
     assert "ref" in msg and "pallas" in msg and "dist" in msg
 
 
-def test_from_dict_rejects_unknown_fields_by_name():
+@pytest.mark.parametrize("section,field,value", [
+    ("store", "budget_mb", 3),
+    # kernel and route choices are not config: the retired knobs are
+    # rejected by name like any typo
+    ("executor", "fused_gather", True),
+    ("executor", "block_table", "default")])
+def test_from_dict_rejects_unknown_fields_by_name(section, field, value):
     d = SMALL.to_dict()
-    d["store"]["budget_mb"] = 3
+    d[section][field] = value
     d["grph"] = {}
     with pytest.raises(ConfigError) as ei:
         DealConfig.from_dict(d)
-    assert "store.budget_mb" in str(ei.value)
+    assert f"{section}.{field}" in str(ei.value)
     assert "grph" in str(ei.value)
     # a non-dict section is named too, not a raw TypeError
     with pytest.raises(ConfigError) as ei:
@@ -207,9 +213,9 @@ def _legacy_infer(model, executor, *, p=2, m=1, fanout=4, n_layers=2,
     """The pre-API body of launch/infer_gnn.run, verbatim wiring."""
     import jax
 
-    from repro.core.gnn_models import init_gat, init_gcn
+    from repro.core.gnn_models import init_gat, init_gcn, model_spec
     from repro.core.graph import csr_from_edges_distributed, make_dataset
-    from repro.core.layerwise import LOCAL_ENGINES
+    from repro.core.ops import get_executor, run_model
     from repro.core.sampler import sample_layer_graphs
     src, dst, n = make_dataset("ogbn-products", seed=seed, scale=SCALE)
     g, _ = csr_from_edges_distributed(src, dst, n, n_workers=p)
@@ -221,8 +227,8 @@ def _legacy_infer(model, executor, *, p=2, m=1, fanout=4, n_layers=2,
     key = jax.random.PRNGKey(seed)
     params = (init_gcn(key, dims) if model == "gcn"
               else init_gat(key, dims, heads=1))
-    return np.asarray(LOCAL_ENGINES[model](lgs, X, params,
-                                           executor=executor))
+    ex, spec = get_executor(executor), model_spec(model, params)
+    return np.asarray(run_model(ex, spec, ex.bind(lgs, spec), X))
 
 
 @pytest.mark.parametrize("executor", ["ref", "pallas"])

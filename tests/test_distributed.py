@@ -99,7 +99,6 @@ def test_tuned_variants_match_baseline(tmp_path):
 
 GAT_CASES = ["session_vs_ref/heads4_p2_m2", "session_vs_ref/heads2_p2_m2",
              "session_vs_ref/heads2_p2_m4", "heads1_bitwise_parent",
-             "unfused_scores/heads4_p2_m2", "unfused_scores/heads2_p2_m4",
              "delta_bitwise/heads4", "plan_built_once",
              "dense_vs_scatter/p2_m2", "dense_vs_scatter/p4_m2",
              "dense_vs_scatter/p2_m4", "slot_fill_reported"]

@@ -6,10 +6,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.gnn_models import init_gat, init_gcn, init_sage
+from repro.core.gnn_models import init_gat, init_gcn, init_sage, model_spec
 from repro.core.graph import csr_from_edges, rmat_edges
-from repro.core.layerwise import LOCAL_ENGINES
-from repro.core.ops import DistExecutor
+from repro.core.ops import DistExecutor, RefExecutor, run_model
 from repro.core.sampler import sample_layer_graphs
 from repro.gnnserve import (DeltaReinference, EmbeddingServeEngine,
                             EmbeddingStore, MutationLog, Query,
@@ -148,7 +147,8 @@ def test_delta_refresh_bitwise_matches_full(world, model):
     ri = DeltaReinference([copy.deepcopy(l) for l in lgs], model, params)
     levels = ri.full_levels(X)
     # sanity: full_levels agrees bitwise with the existing local engine
-    want = np.asarray(LOCAL_ENGINES[model](lgs, X, params))
+    ex, spec = RefExecutor(), model_spec(model, params)
+    want = np.asarray(run_model(ex, spec, ex.bind(lgs, spec), X))
     np.testing.assert_array_equal(levels[-1], want)
 
     store = store_from_inference(X, levels[1:], n_shards=4)

@@ -4,7 +4,7 @@ Plus the fused-kernel acceptance tests (gather+SPMM, SDDMM+softmax):
 random-data sweeps at the standard tolerances AND strict <5e-7 f32
 checks on mantissa-quantized inputs, where every reduction is exact in
 any association order — so kernel-vs-oracle differences must be ZERO,
-not merely small.  And the block autotuner round-trip."""
+not merely small."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -248,45 +248,41 @@ def test_executor_fused_gather_non_aligned_strict(rng):
 
 
 def test_executor_fused_gather_matches_unfused(rng):
-    """fused_gather=False resolves the table eagerly; both routes must
-    produce identical bits."""
-    from repro.core.ops import PallasExecutor
+    """PallasExecutor's one gather route: ``gather_spmm`` over the
+    table gives the bits of ``spmm`` over the same ids resolved."""
+    from repro.core.ops import DenseIO, PallasExecutor
     R, U, D, F = 50, 61, 32, 8
     io = _dense_io(rng, R, U, F)
+    resolved = DenseIO(np.asarray(io.nbr_resolved), io.mask_np)
+    assert resolved.table is None
     h = jnp.asarray(rng.standard_normal((U, D)).astype(np.float32))
-    fused = PallasExecutor(use_kernel=True, fused_gather=True)
-    unfused = PallasExecutor(use_kernel=True, fused_gather=False)
+    ex = PallasExecutor(use_kernel=True)
     np.testing.assert_array_equal(
-        np.asarray(fused.spmm(h, io.mean_w, io)),
-        np.asarray(unfused.spmm(h, io.mean_w, io)))
+        np.asarray(ex.spmm(h, io.mean_w, io)),
+        np.asarray(ex.spmm(h, resolved.mean_w, resolved)))
 
 
 @pytest.mark.parametrize("N,D,heads", [(50, 32, 4), (64, 64, 1)])
 def test_executor_fused_attention_layer(N, D, heads, rng):
-    """A full GAT layer through ``run_layer``: the peephole must fire on
-    the fused executor, agree tightly with the unfused kernel path, and
-    match the jnp oracle within the standard tolerance."""
+    """A full GAT layer through ``run_layer``: the one
+    ``attn_scores_softmax`` op runs the fused kernel on the Pallas
+    executor and matches the jnp oracle's scores-then-softmax within the
+    standard tolerance."""
     import jax
 
     from repro.core.gnn_models import init_gat, model_spec
-    from repro.core.ops import (DenseIO, PallasExecutor, RefExecutor,
-                                run_layer)
+    from repro.core.ops import PallasExecutor, RefExecutor, run_layer
     F = 6
     spec = model_spec("gat", init_gat(jax.random.PRNGKey(0), [D, D],
                                       heads=heads))
     io = _dense_io(rng, N, N, F, table=False)
     H = jnp.asarray(rng.standard_normal((N, D)).astype(np.float32))
 
-    fused_ex = PallasExecutor(use_kernel=True, fused_attention=True)
-    unfused_ex = PallasExecutor(use_kernel=True, fused_attention=False)
-    assert fused_ex.attn_scores_softmax is not None
-    assert unfused_ex.attn_scores_softmax is None
-
     layer = spec.layers[0]
-    got = np.asarray(run_layer(fused_ex, layer, io, H, H, heads))
-    unfused = np.asarray(run_layer(unfused_ex, layer, io, H, H, heads))
+    assert [op.kind for op in layer.ops].count("attn_scores_softmax") == 1
+    got = np.asarray(run_layer(PallasExecutor(use_kernel=True), layer, io,
+                               H, H, heads))
     want = np.asarray(run_layer(RefExecutor(), layer, io, H, H, heads))
-    np.testing.assert_allclose(got, unfused, atol=1e-6, rtol=1e-6)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=3e-3)
 
 
@@ -294,13 +290,9 @@ def _per_head_attend(ex, alpha, v, io, heads):
     """The per-head attend this repo ran before the fused call: one
     kernel call per head over its column slice, then a concatenate."""
     dh = v.shape[-1] // heads
-    fused = ex.fused_gather and io.table is not None
-    nbr = io.nbr if fused else io.nbr_resolved
-    table = io.table if fused else None
     return jnp.concatenate(
-        [ex._spmm_kernel(v[:, k * dh:(k + 1) * dh], alpha[..., k], nbr,
-                         io.mask, table=table) for k in range(heads)],
-        axis=-1)
+        [ex.spmm(v[:, k * dh:(k + 1) * dh], alpha[..., k], io)
+         for k in range(heads)], axis=-1)
 
 
 @pytest.mark.parametrize("table", [False, True])
@@ -340,80 +332,9 @@ def test_executor_attend_one_kernel_call(rng):
     assert tel.metrics.counter("pallas.attend_kernel_calls").value == calls
 
 
-# ----------------------------------------------------------------------
-# block-size autotuner
-# ----------------------------------------------------------------------
-
-def test_autotune_roundtrip(tmp_path, monkeypatch):
-    """ensure_tuned searches the candidate grid once (injected timer),
-    persists the winner, serves later calls from the file, and re-runs
-    only under REPRO_TUNING=autotune."""
-    from repro import tuning
-    monkeypatch.delenv("REPRO_TUNING", raising=False)
-    path = tmp_path / "blocks.json"
-    table = tuning.BlockTable(path=path)
-    current, seen = {}, []
-
-    def make_call(blocks):
-        def fn():
-            current.clear()
-            current.update(blocks)
-        return fn
-
-    def timer(fn, repeats):
-        fn()
-        seen.append(dict(current))
-        return abs(current["block_n"] - 32) + 1.0   # 32 always wins
-
-    blocks = tuning.ensure_tuned(table, "sddmm", make_call, N=100,
-                                 timer=timer)
-    assert blocks == {"block_n": 32}
-    assert path.exists() and seen     # searched and persisted
-    # every block_n candidate that tiles the n128 bucket was tried
-    assert sorted(c["block_n"] for c in seen) == [8, 16, 32, 64]
-
-    # a fresh load serves the whole shape bucket without re-searching
-    t2 = tuning.BlockTable.load(path)
-    n_calls = len(seen)
-    assert tuning.ensure_tuned(t2, "sddmm", make_call, N=100,
-                               timer=timer) == {"block_n": 32}
-    assert tuning.ensure_tuned(t2, "sddmm", make_call, N=128,
-                               timer=timer) == {"block_n": 32}
-    assert len(seen) == n_calls
-    got = t2.lookup("sddmm", N=100)
-    assert got == {"block_n": 32}     # the `us` field stays out of lookup
-
-    # forcing invalidates the persisted winner
-    monkeypatch.setenv("REPRO_TUNING", "autotune")
-    assert tuning.autotune_forced()
-    tuning.ensure_tuned(t2, "sddmm", make_call, N=100, timer=timer)
-    assert len(seen) > n_calls
-
-
-def test_executor_consults_block_table(rng):
-    """A bound BlockTable overrides the constructor blocks at bind time,
-    and tuned vs default blocks are bitwise-identical (block sizes never
-    change the per-row accumulation order)."""
-    from repro import tuning
-    from repro.core.ops import PallasExecutor
-    N, U, D, F = 64, 64, 128, 8
-    tb = tuning.BlockTable()
-    tb.put("gather_spmm", N=N, D=D, blocks={"block_n": 16, "block_d": 128})
-    ex = PallasExecutor(use_kernel=True, block_table=tb)
-    assert ex._pick_blocks("gather_spmm", N, D, jnp.float32) == (16, 128)
-    assert ex._pick_blocks("spmm", N, D, jnp.float32) == (None, 128)
-
-    io = _dense_io(rng, N, U, F)
-    h = jnp.asarray(rng.standard_normal((U, D)).astype(np.float32))
-    got = np.asarray(ex.spmm(h, io.mean_w, io))
-    base = np.asarray(PallasExecutor(use_kernel=True).spmm(h, io.mean_w,
-                                                           io))
-    np.testing.assert_array_equal(got, base)
-
-
 def test_auto_block_n_defaults():
-    """The satellite fix: sddmm no longer hard-defaults to block_n=8 —
-    both kernels take the largest divisor <= 64 of the row count."""
+    """Every kernel's row block: the largest divisor <= 64 of the row
+    count (PallasExecutor pads rows to 8 first)."""
     from repro.kernels.spmm import auto_block_n
     assert auto_block_n(256) == 64
     assert auto_block_n(24) == 8

@@ -4,9 +4,14 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.gnn_models import init_gat, init_gcn, init_sage
-from repro.core.layerwise import (LOCAL_ENGINES, ego_batched_gcn_infer,
-                                  local_gcn_infer)
+from repro.core.gnn_models import init_gat, init_gcn, init_sage, model_spec
+from repro.core.layerwise import ego_batched_gcn_infer
+from repro.core.ops import RefExecutor, run_model
+
+
+def _epoch(model, lgs, X, params):
+    ex, spec = RefExecutor(), model_spec(model, params)
+    return run_model(ex, spec, ex.bind(lgs, spec), X)
 
 
 @pytest.fixture(scope="module")
@@ -19,7 +24,7 @@ def feats(layer_graphs):
 def test_ego_batched_matches_layerwise(layer_graphs, feats):
     params = init_gcn(jax.random.PRNGKey(0), [32, 32, 16])
     lgs = layer_graphs[:2]
-    want = np.asarray(local_gcn_infer(lgs, feats, params))
+    want = np.asarray(_epoch("gcn", lgs, feats, params))
     got, work = ego_batched_gcn_infer(lgs, feats, params, batch_size=64)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
 
@@ -42,7 +47,7 @@ def test_local_engines_finite(model, layer_graphs, feats):
     params = {"gcn": init_gcn(key, dims),
               "gat": init_gat(key, dims, heads=4),
               "sage": init_sage(key, dims)}[model]
-    H = LOCAL_ENGINES[model](layer_graphs[:2], feats, params)
+    H = _epoch(model, layer_graphs[:2], feats, params)
     assert H.shape == (layer_graphs[0].n_nodes, 16)
     assert np.isfinite(np.asarray(H)).all()
 

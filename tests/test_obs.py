@@ -295,7 +295,14 @@ def test_session_stats_surfaces_plan_cache_and_frontiers():
 
 
 def test_session_dump_trace_is_valid_and_covering(tmp_path):
-    with Session.build(_small_cfg(telemetry=True)) as s:
+    """Spans cover the traced window.  On the fake clock every clock
+    read is one step, so coverage counts the pipeline's phases that sit
+    outside every span, not host time: a warm process runs this window
+    in ~15 ms, where one host stall between spans weighs tens of
+    percent."""
+    cfg = _small_cfg(telemetry=True)
+    cfg.telemetry.clock = "fake"
+    with Session.build(cfg) as s:
         s.infer_all()
         s.serve()
         doc = s.dump_trace(tmp_path / "trace.json")

@@ -19,7 +19,6 @@ import pytest
 from repro.core.gnn_models import (init_gat, init_gcn, init_sage,
                                    mean_weights, model_spec)
 from repro.core.graph import csr_from_edges, rmat_edges
-from repro.core.layerwise import LOCAL_ENGINES
 from repro.core.ops import (DenseIO, PallasExecutor, RefExecutor,
                             get_executor, run_model)
 from repro.core.sampler import sample_layer_graphs
@@ -73,9 +72,10 @@ def test_pallas_matches_ref(world, model, dtype):
     lgs, X = world
     params = _params(model)
     Xd = jnp.asarray(X, dtype)
-    want = np.asarray(LOCAL_ENGINES[model](lgs, Xd, params), np.float32)
-    got = np.asarray(LOCAL_ENGINES[model](lgs, Xd, params,
-                                          executor="pallas"), np.float32)
+    spec = model_spec(model, params)
+    want, got = (np.asarray(run_model(ex, spec, ex.bind(lgs, spec), Xd),
+                            np.float32)
+                 for ex in (RefExecutor(), PallasExecutor()))
     np.testing.assert_allclose(got, want, atol=ATOL[dtype], rtol=3e-2)
 
 
@@ -88,8 +88,28 @@ def test_spec_single_definition(model):
     kinds = [op.kind for op in spec.layers[0].ops]
     assert kinds == {"gcn": ["gemm", "spmm"],
                      "sage": ["spmm", "gemm", "gemm", "add"],
-                     "gat": ["gemm", "gemm", "gemm", "attn_scores",
-                             "edge_softmax", "attend"]}[model]
+                     "gat": ["gemm", "gemm", "gemm",
+                             "attn_scores_softmax", "attend"]}[model]
+
+
+@pytest.mark.parametrize("executor", ["ref", "pallas"])
+def test_bind_then_run_model_is_the_session_epoch(executor):
+    """``run_model(ex, spec, ex.bind(lgs, spec), X)`` is the one forward
+    driver: outside a Session it gives ``Session.infer_all``'s bits."""
+    from repro.api import DealConfig, Session
+    cfg = DealConfig.from_dict({
+        "graph": {"dataset": "rmat", "n_nodes": 200, "avg_degree": 8,
+                  "fanout": 6, "seed": 4},
+        "model": {"name": "gat", "n_layers": 2, "d_feature": 32,
+                  "heads": 4},
+        "executor": {"name": executor}})
+    with Session.build(cfg) as s:
+        want = s.infer_all().copy()
+        ex = s.executor
+        spec = model_spec("gat", s.params)
+        got = np.asarray(run_model(ex, spec, ex.bind(s.layer_graphs, spec),
+                                   s.X))
+    np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("model", ["gcn", "gat"])
