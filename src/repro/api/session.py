@@ -130,7 +130,9 @@ class Session:
         """One full layer-by-layer epoch for ALL nodes through the bound
         executor: ``run_model`` over ``executor.bind`` (the executor owns
         its graph bindings; the mesh keeps its CommPlan while the layer
-        graphs are the same objects).  Cached until ``_H`` is reset."""
+        graphs are the same objects), then ``executor.fetch`` copies H
+        to the host (the mesh in whole rows).  Cached until ``_H`` is
+        reset."""
         self._check_open()
         if self._H is not None:
             return self._H
@@ -150,7 +152,7 @@ class Session:
             with obs.span("infer.forward"):
                 H = run_model(ex, spec, ios, self.X)
             with obs.span("infer.fetch"):
-                self._H = np.asarray(H)
+                self._H = ex.fetch(H)
             with obs.span("infer.check"):
                 assert not np.isnan(self._H).any()
             if sp:

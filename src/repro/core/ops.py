@@ -199,6 +199,10 @@ class RefExecutor:
     def prepare(self, X):
         return jnp.asarray(X)
 
+    def fetch(self, H) -> np.ndarray:
+        """``run_model``'s result on the host: one device-to-host copy."""
+        return np.asarray(H)
+
     def gemm(self, H, W):
         return prim.ref_gemm(H, jnp.asarray(W))
 
@@ -334,6 +338,7 @@ class DistExecutor:
         self._spmm = prim.make_spmm_p(mesh, self.P)
         self._sddmm = prim.make_sddmm_p(mesh, self.P)
         self._attn_cache: Dict[int, Callable] = {}
+        self._rows = prim.make_rows(mesh)
         self._row_spec = NamedSharding(mesh, P("data", None))
         self._hd_spec = NamedSharding(mesh, P("data", "model"))
         self._plan_spec = NamedSharding(mesh, P("data", None, None))
@@ -389,6 +394,17 @@ class DistExecutor:
     # -- executor primitives --------------------------------------------
     def prepare(self, X):
         return self._put(X, self._hd_spec)
+
+    def fetch(self, H) -> np.ndarray:
+        """``run_model``'s result on the host, copied in whole rows.  The
+        primitives leave H in (data, model) column shards, which the
+        host would write into a fresh array half-row by half-row (most
+        of the copy's time on the chip); ``dist_rows`` first relays H on
+        the device into P*M blocks of whole rows, so the host writes P*M
+        contiguous blocks."""
+        H = self._rows(H)
+        obs.add("dist.fetch_relayouts")
+        return np.asarray(H)
 
     def gemm(self, H, W):
         return self._gemm(H, jnp.asarray(W))

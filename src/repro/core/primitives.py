@@ -57,8 +57,9 @@ from repro.kernels.ref import sddmm_ref as ref_sddmm
 from repro.kernels.ref import spmm_ref as ref_spmm
 
 __all__ = [
-    "head_layout", "make_gemm", "make_gat_attention_p", "make_spmm",
-    "make_spmm_p", "make_sddmm", "make_sddmm_p", "plan_device_arrays",
+    "head_layout", "make_gemm", "make_gat_attention_p", "make_rows",
+    "make_spmm", "make_spmm_p", "make_sddmm", "make_sddmm_p",
+    "plan_device_arrays",
     "ref_gemm", "ref_spmm", "ref_sddmm",
 ]
 
@@ -416,6 +417,28 @@ def make_gat_attention_p(mesh, P_: int, heads: int):
         in_specs=(P("data", "model"), P("data", "model"),
                   P("data", None), plan_spec, plan_spec),
         out_specs=P("data", None, "model" if heads >= M else None)))
+
+
+# ----------------------------------------------------------------------
+# whole rows for the host copy
+# ----------------------------------------------------------------------
+
+def _rows_local(H):
+    """(n/P, D/M) column shard -> (n/(P*M), D) whole rows: each device
+    sends the i-th of M row slices of its shard to ``model`` peer i and
+    lays the M column pieces it receives side by side."""
+    return jax.lax.all_to_all(H, "model", 0, 1, tiled=True)
+
+
+def make_rows(mesh):
+    """Jitted relayout of a (data, model) column-sharded (n, D) array
+    into P*M blocks of whole rows, ``P(("data", "model"), None)``: no
+    replica, and each block one contiguous run of rows.  No arithmetic:
+    the values are the input's bits.  Named ``dist_rows``."""
+    return jax.jit(jax.shard_map(
+        _named(_rows_local, "dist_rows"), mesh=mesh,
+        in_specs=(P("data", "model"),),
+        out_specs=P(("data", "model"), None)))
 
 
 # ----------------------------------------------------------------------

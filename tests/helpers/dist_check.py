@@ -587,6 +587,32 @@ def case_slot_fill_reported():
     return got == want and 0 < min(want) <= 1, f"got={got} want={want}"
 
 
+def case_fetch_whole_rows(p, m):
+    """``Session.infer_all`` copies the mesh's H in whole rows: its bits
+    are those of ``run_model``'s sharded H (columns split over ``model``
+    where m > 1, rows over ``data`` either way), the relayout runs once
+    per epoch, and the relaid H is p*m blocks of whole rows."""
+    from repro.api import Session
+    with Session.build(_gat_cfg(4, p, m, telemetry=True)) as s:
+        s.infer_all()
+        s._H = None
+        got = np.array(s.infer_all())
+        ex = s.executor
+        spec = model_spec("gat", s.params)
+        H = run_model(ex, spec, ex.bind(s.layer_graphs, spec), s.X)
+        mets = s.telemetry.metrics.to_dict()
+    shard = H.sharding.shard_shape(H.shape)
+    split = shard[0] < H.shape[0] and (shard[1] < H.shape[1]) == (m > 1)
+    rows = ex._rows(H)
+    whole = rows.sharding.shard_shape(rows.shape) == (
+        H.shape[0] // (p * m), H.shape[1])
+    same = bool((got == np.asarray(H)).all())
+    relayouts = mets.get("dist.fetch_relayouts")
+    return (same and split and whole and relayouts == 2), \
+        f"bitwise={same} H_shard={shard} whole={whole} " \
+        f"relayouts={relayouts}"
+
+
 def gat_main():
     cases = [
         ("session_vs_ref/heads4_p2_m2",
@@ -603,6 +629,8 @@ def gat_main():
         ("dense_vs_scatter/p4_m2", lambda: case_dense_vs_scatter(4, 2)),
         ("dense_vs_scatter/p2_m4", lambda: case_dense_vs_scatter(2, 4)),
         ("slot_fill_reported", case_slot_fill_reported),
+        ("fetch_whole_rows/p2_m2", lambda: case_fetch_whole_rows(2, 2)),
+        ("fetch_whole_rows/p2_m1", lambda: case_fetch_whole_rows(2, 1)),
     ]
     for name, fn in cases:
         hb.beat(name)

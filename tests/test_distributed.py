@@ -101,7 +101,8 @@ GAT_CASES = ["session_vs_ref/heads4_p2_m2", "session_vs_ref/heads2_p2_m2",
              "session_vs_ref/heads2_p2_m4", "heads1_bitwise_parent",
              "delta_bitwise/heads4", "plan_built_once",
              "dense_vs_scatter/p2_m2", "dense_vs_scatter/p4_m2",
-             "dense_vs_scatter/p2_m4", "slot_fill_reported"]
+             "dense_vs_scatter/p2_m4", "slot_fill_reported",
+             "fetch_whole_rows/p2_m2", "fetch_whole_rows/p2_m1"]
 
 
 @pytest.fixture(scope="module")

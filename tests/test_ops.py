@@ -112,6 +112,30 @@ def test_bind_then_run_model_is_the_session_epoch(executor):
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("executor", ["ref", "pallas"])
+def test_fetch_on_one_device_is_np_asarray(executor):
+    """One device holds H in whole rows already: ``Session.infer_all``'s
+    fetch is ``np.asarray(H)`` and never counts a relayout."""
+    from repro.api import DealConfig, Session
+    cfg = DealConfig.from_dict({
+        "graph": {"dataset": "rmat", "n_nodes": 200, "avg_degree": 8,
+                  "fanout": 6, "seed": 4},
+        "model": {"name": "gat", "n_layers": 2, "d_feature": 32,
+                  "heads": 4},
+        "executor": {"name": executor},
+        "telemetry": {"enabled": True}})
+    with Session.build(cfg) as s:
+        got = s.infer_all()
+        ex = s.executor
+        spec = model_spec("gat", s.params)
+        H = run_model(ex, spec, ex.bind(s.layer_graphs, spec), s.X)
+        fetched = ex.fetch(H)
+        m = s.telemetry.metrics.to_dict()
+    np.testing.assert_array_equal(fetched, np.asarray(H))
+    np.testing.assert_array_equal(got, fetched)
+    assert "dist.fetch_relayouts" not in m
+
+
 @pytest.mark.parametrize("model", ["gcn", "gat"])
 def test_delta_refresh_pallas_bitwise(world, model):
     """Delta refresh through the pallas executor == full epoch through

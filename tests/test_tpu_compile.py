@@ -10,7 +10,8 @@ carried values, blocks narrower than a lane tile.
 
 The distributed GAT layer's shard_map programs (``core/primitives.py``)
 are compiled on the described 2x2 mesh, p=2 x m=2 as the four-chip cell
-runs them, with their pinned module names.
+runs them, with their pinned module names, and so is ``dist_rows``, the
+relayout of the epoch's H into whole rows before its host copy.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU runtime at a time, so each pytest
@@ -193,11 +194,12 @@ def _dist_programs(mesh):
             "collective-permute"),
         "dist_spmm.head_major": (jax.jit(lambda h, a, *p: spmm(h, a, *p)),
                                  [hd, alpha] + plan, "collective-permute"),
+        "dist_rows": (prim.make_rows(mesh), [hd], "all-to-all"),
     }
 
 
 @pytest.mark.parametrize("case", ["dist_gemm", "dist_gat_attention",
-                                  "dist_spmm.head_major"])
+                                  "dist_spmm.head_major", "dist_rows"])
 def test_dist_gat_layer_compiles_on_a_2x2_mesh(case, mesh_2x2,
                                                no_persistent_cache):
     from jax.sharding import NamedSharding
@@ -208,7 +210,13 @@ def test_dist_gat_layer_compiles_on_a_2x2_mesh(case, mesh_2x2,
     assert collective in text
     if "." not in case:
         assert re.search(rf"^HloModule jit_{case}\b", text, re.M)
-    if case != "dist_gemm":
+    if case == "dist_rows":
+        # each device ends with a quarter of H's rows at full width, and
+        # the relayout before the host copy stays on the device
+        assert re.search(rf"->f32\[{N // 4},128\]", text), case
+        assert not re.search(r"\b(send|recv|infeed|outfeed)\(|"
+                             r"is_host_transfer", text), case
+    elif case != "dist_gemm":
         # the ring consumers read the plan's dense slot table: a scatter
         # with repeated row ids would serialise on the chip
         assert not re.search(r"\bscatter\(", text), case
